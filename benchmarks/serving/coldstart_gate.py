@@ -13,8 +13,8 @@ processes:
    overload gate's ``chain_fused`` / ``staged_reduce`` request shapes: fused
    deferred chains + staged one-op programs — the signatures a serving host
    actually compiles), then ``executor_save_warmup`` records the manifest +
-   artifacts into the cache dir (and ``HEAT_TPU_COMPILE_CACHE`` points JAX's
-   own persistent cache there too).
+   artifacts into the cache dir (and ``JAX_COMPILATION_CACHE_DIR`` places
+   JAX's own persistent cache there too).
 2. **cold boot** — a fresh process with NO cache measures, per workload, its
    FIRST request's latency and then the steady-state p99 over the remaining
    requests.
@@ -42,9 +42,9 @@ Standalone::
 
 import json
 import os
+import shutil
 import subprocess
 import sys
-import tempfile
 import time
 
 sys.path.insert(0, os.path.join(os.path.dirname(os.path.abspath(__file__)), "..", ".."))
@@ -55,6 +55,11 @@ from benchmarks.serving.harness import _bootstrap, _percentile_ms  # noqa: E402
 FIRST_REQUEST_MULTIPLE = 2.0
 #: absolute floor (ms): sub-millisecond steady states are not gated on noise
 FLOOR_MS = 50.0
+#: the gate's cache dir: one fixed path (JAX's cache keys include the
+#: directory), wiped at the start of every run so "cold" stays cold
+DEFAULT_CACHE = os.path.join(
+    os.path.dirname(os.path.abspath(__file__)), "..", "out", "coldstart_cache"
+)
 #: steady-state sample count per workload (p99 over these)
 STEADY_REQUESTS_SMOKE = 24
 STEADY_REQUESTS_FULL = 64
@@ -115,10 +120,15 @@ def _spawn_child(mode, cache, smoke, devices, extra_env=None):
     honest way to measure a boot."""
     env = dict(os.environ)
     env.pop("HEAT_TPU_EXEC_CACHE", None)
-    env.pop("HEAT_TPU_COMPILE_CACHE", None)
+    # JAX's persistent cache is placed from outside: record/warm share one
+    # dir (persisting every program — the CPU programs here compile in
+    # milliseconds), the cold boot gets its own empty one
+    env["JAX_COMPILATION_CACHE_DIR"] = os.path.join(cache, "xla-cold")
     if mode in ("record", "warm"):
         env["HEAT_TPU_EXEC_CACHE"] = cache
-        env["HEAT_TPU_COMPILE_CACHE"] = os.path.join(cache, "xla")
+        env["JAX_COMPILATION_CACHE_DIR"] = os.path.join(cache, "xla")
+        env["JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS"] = "0"
+        env["JAX_PERSISTENT_CACHE_MIN_ENTRY_SIZE_BYTES"] = "-1"
     env.update(extra_env or {})
     cmd = [
         sys.executable, os.path.abspath(__file__), "--child", "--mode", mode,
@@ -212,7 +222,10 @@ def evaluate(cold, warm, emit=print):
 
 
 def run_gate(devices, smoke=True, poison=False, cache=None, emit=print):
-    cache = cache or tempfile.mkdtemp(prefix="ht-coldstart-cache-")
+    if cache is None:
+        cache = os.path.abspath(DEFAULT_CACHE)
+        shutil.rmtree(cache, ignore_errors=True)
+    os.makedirs(cache, exist_ok=True)
     emit(json.dumps({"info": "coldstart gate: recording warm signatures",
                      "cache": cache}))
     recorded, _ = _spawn_child("record", cache, smoke, devices)
@@ -267,7 +280,8 @@ def main(argv=None):
                         help="truncate one cached artifact before the warm "
                         "boot (the CI cache-poisoning step)")
     parser.add_argument("--cache", default=None,
-                        help="cache dir (default: a fresh temp dir)")
+                        help="cache dir (default: benchmarks/out/"
+                        "coldstart_cache, wiped first)")
     parser.add_argument("--child", action="store_true")
     parser.add_argument("--mode", choices=("record", "cold", "warm"),
                         default="cold")

@@ -84,7 +84,6 @@ def main(argv=None) -> int:
     if package_root is None:
         package_root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     repo_root = os.path.dirname(os.path.abspath(package_root))
-    extra_files = [os.path.join(repo_root, "_diag_bootstrap.py")]
 
     # ---- incremental cache: serve a byte-identical tree without re-running
     cache_path = args.cache or cache_mod.default_path(package_root)
@@ -95,7 +94,7 @@ def main(argv=None) -> int:
     want_cache = not args.no_cache and not args.dump_lockgraph
     if want_cache:
         code_hash = cache_mod.code_fingerprint()
-        hashes = cache_mod.module_hashes(package_root, extra_files)
+        hashes = cache_mod.module_hashes(package_root)
         cached = cache_mod.load(cache_path)
         findings = cache_mod.lookup(cached, package_root, code_hash, hashes)
         if findings is not None:

@@ -30,7 +30,7 @@ from __future__ import annotations
 import hashlib
 import json
 import os
-from typing import Dict, List, Optional, Sequence, Tuple
+from typing import Dict, List, Optional, Tuple
 
 from .engine import Finding
 
@@ -62,11 +62,9 @@ def code_fingerprint() -> str:
     return h.hexdigest()
 
 
-def module_hashes(package_root: str,
-                  extra_files: Sequence[str] = ()) -> Dict[str, str]:
+def module_hashes(package_root: str) -> Dict[str, str]:
     """Repo-relative path -> content hash for every file the engine scans
-    (mirrors ``Universe``'s discovery: the package's ``.py`` tree plus the
-    configured extra files)."""
+    (mirrors ``Universe``'s discovery: the package's ``.py`` tree)."""
     package_root = os.path.abspath(package_root)
     repo_root = os.path.dirname(package_root)
     out: Dict[str, str] = {}
@@ -77,11 +75,6 @@ def module_hashes(package_root: str,
                 path = os.path.join(dirpath, fn)
                 rel = os.path.relpath(path, repo_root).replace(os.sep, "/")
                 out[rel] = _sha256_file(path)
-    for path in extra_files:
-        path = os.path.abspath(path)
-        if os.path.exists(path):
-            rel = os.path.relpath(path, repo_root).replace(os.sep, "/")
-            out[rel] = _sha256_file(path)
     return out
 
 

@@ -16,7 +16,7 @@ from __future__ import annotations
 import ast
 import os
 import sys
-from typing import Dict, Iterable, List, Optional, Sequence, Set, Tuple
+from typing import Dict, Iterable, List, Optional, Set, Tuple
 
 # ---------------------------------------------------------------------------
 # findings
@@ -233,10 +233,10 @@ def _is_container_ctor(node: ast.expr) -> bool:
 
 
 class Universe:
-    """Every parsed module of the package (plus the configured extra files),
-    with cross-module call resolution and the traced-body set."""
+    """Every parsed module of the package, with cross-module call resolution
+    and the traced-body set."""
 
-    def __init__(self, package_root: str, extra_files: Sequence[str] = ()):
+    def __init__(self, package_root: str):
         self.package_root = os.path.abspath(package_root)
         self.repo_root = os.path.dirname(self.package_root)
         self.modules: Dict[str, ModuleIndex] = {}
@@ -246,12 +246,6 @@ class Universe:
             if name.endswith(".__init__"):
                 name = name[: -len(".__init__")]
             self._load(name, path, rel)
-        for path in extra_files:
-            path = os.path.abspath(path)
-            if not os.path.exists(path):
-                continue
-            rel = os.path.relpath(path, self.repo_root).replace(os.sep, "/")
-            self._load(os.path.basename(path)[:-3], path, rel)
         self.traced: Dict[str, Set[ast.AST]] = {}
         self._build_traced_sets()
 
@@ -414,8 +408,7 @@ def is_stdlib(module: Optional[str]) -> bool:
 # orchestration
 
 
-def run_analysis(package_root: Optional[str] = None,
-                 extra_files: Optional[Sequence[str]] = None) -> Tuple[List[Finding], "object"]:
+def run_analysis(package_root: Optional[str] = None) -> Tuple[List[Finding], "object"]:
     """Run every rule family over the package. Returns ``(findings, universe)``
     — findings are pragma-filtered and sorted, with pragma misuse (missing
     reason, unknown rule, unused pragma) appended as findings of their own."""
@@ -423,10 +416,7 @@ def run_analysis(package_root: Optional[str] = None,
 
     if package_root is None:
         package_root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    if extra_files is None:
-        repo_root = os.path.dirname(os.path.abspath(package_root))
-        extra_files = [os.path.join(repo_root, "_diag_bootstrap.py")]
-    uni = Universe(package_root, extra_files)
+    uni = Universe(package_root)
     raw: List[Finding] = []
     for rule_fn in rules.RULE_RUNNERS:
         raw.extend(rule_fn(uni))

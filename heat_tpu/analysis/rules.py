@@ -122,10 +122,10 @@ RULES = {
         "--dump-lockgraph); scheduler-sharding work must keep it a DAG."
     ),
     "import-nonstdlib": (
-        "diagnostics/profiler/resilience/_scheduler/_diag_bootstrap (and "
+        "diagnostics/profiler/resilience/_scheduler/telemetry (and "
         "heat_tpu.analysis itself) import only the stdlib at module level, "
-        "so the driver entry points can load them by file path before "
-        "touching the JAX backend. Heavy imports belong inside functions. "
+        "so jax-free tooling can load them by file path. Heavy imports "
+        "belong inside functions. "
         "tests/test_analysis.py proves the same contract dynamically."
     ),
     "silent-except": (

@@ -1,9 +1,8 @@
 """Import-contract rules.
 
-The driver entry points (``bench.py``, ``__graft_entry__.py``) load the
-telemetry stack by file path *before* deciding whether touching the JAX
-backend is safe, so ``diagnostics`` / ``profiler`` / ``resilience`` /
-``_scheduler`` / ``_diag_bootstrap`` commit (in their module docstrings) to
+JAX-free tooling (the telemetry merge, the ops exporter/parser, this checker)
+loads the telemetry stack by file path, so ``diagnostics`` / ``profiler`` /
+``resilience`` / ``_scheduler`` commit (in their module docstrings) to
 importing only the stdlib at module level. ``import-nonstdlib`` enforces that
 statically; ``tests/test_analysis.py`` proves it dynamically with a
 ``sys.meta_path`` hook. Relative imports *within* the stdlib-only set are
@@ -31,7 +30,6 @@ STDLIB_ONLY: Set[str] = {
     "heat_tpu.core.ops",  # exporter/parser must run jax-free; executor lazily
     "heat_tpu.core.forensics",  # record store reads shards jax-free too
     "heat_tpu.analysis",  # the checker polices itself: it must stay light
-    "_diag_bootstrap",
 }
 _ANALYSIS_PREFIX = "heat_tpu.analysis"
 

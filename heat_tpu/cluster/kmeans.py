@@ -89,11 +89,14 @@ class KMeans(_KCluster):
                     comm.psum(sse, axis_name=axis),
                 )
 
+            # check_vma off: a pallas_call's out_shape carries no varying-axes
+            # annotation, which the check demands inside shard_map
             return jax.shard_map(
                 body,
                 mesh=comm.mesh,
                 in_specs=(P(axis, None), P()),
                 out_specs=(P(axis), P(), P(), P()),
+                check_vma=False,
             )(xv, centers)
 
         return sharded

@@ -37,17 +37,20 @@ module closes that gap with two cooperating layers (ISSUE 15):
    the PUBLIC dispatch path, the executor's signature table ends up keyed
    exactly as live traffic will key it — warmed programs are replay hits
    from the first request.  Each replayed compile either loads its artifact
-   (layer 1) or recompiles; with ``HEAT_TPU_COMPILE_CACHE`` (below) even
-   the recompiles hit XLA's disk cache.  ``ht.executor_save_warmup(path)``
+   (layer 1) or recompiles; the recompiles hit JAX's persistent
+   compilation cache (below).  ``ht.executor_save_warmup(path)``
    records the manifest (and artifacts) from a warm process.
 
-Satellite knob: ``HEAT_TPU_COMPILE_CACHE=<dir>`` enables **JAX's own
-persistent compilation cache** (``jax_compilation_cache_dir`` +
-zero-threshold persistence knobs) so XLA-level recompiles are cached across
-processes even for signatures this module cannot describe portably.  Both
-knobs are memoised at import; :func:`reload` (called from
-``ht.reload_env_knobs`` / ``clear_executor_cache``) is the documented
-re-read point for in-process flips.
+**JAX's own persistent compilation cache** is always on and is placed once,
+at package import (:func:`_place_jax_cache`): where
+``JAX_COMPILATION_CACHE_DIR`` is set the operator has placed it and no code
+touches ``jax_compilation_cache_dir``; otherwise it lives at
+:data:`JAX_CACHE_DIR`, one fixed path inside the checkout.  The directory is
+part of JAX's cache key, so it is never a temp name, a pid or a time.  JAX's
+own size/time thresholds decide what persists.  ``HEAT_TPU_EXEC_CACHE`` is
+memoised at import; :func:`reload` (called from ``ht.reload_env_knobs`` /
+``clear_executor_cache``) is the documented re-read point for in-process
+flips.
 
 Observability: ``executor.aot_load`` / ``executor.cache_reject`` /
 ``warmup.replayed`` / ``warmup.failed`` diagnostics counters, fallback
@@ -71,15 +74,12 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
+from jax.experimental import serialize_executable as _se
+
 from . import diagnostics, resilience
 
-try:
-    from jax.experimental import serialize_executable as _se
-except ImportError:  # pragma: no cover - older/newer jax without AOT serde
-    _se = None
-
 __all__ = [
-    "CompileCacheCorrupt", "armed", "cache_dir", "reload",
+    "CompileCacheCorrupt", "JAX_CACHE_DIR", "armed", "cache_dir", "reload",
     "load_program", "executor_save_warmup", "executor_warmup",
 ]
 
@@ -104,34 +104,28 @@ _lock = threading.Lock()
 _dir: Optional[str] = None
 _index: Optional[Dict[str, Any]] = None   # fingerprint -> entry (lazy-loaded)
 _index_rejected = False                   # corrupt index: stop retrying reads
-_jax_cache_applied = object()             # sentinel: never applied yet
+
+#: where JAX's persistent compilation cache lives unless the operator placed it
+#: with ``JAX_COMPILATION_CACHE_DIR``: ``<checkout>/.jax_cache`` (gitignored)
+JAX_CACHE_DIR = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__)))),
+    ".jax_cache",
+)
 
 
-def _apply_jax_cache_locked() -> None:
-    """Apply the ``HEAT_TPU_COMPILE_CACHE`` satellite knob: point JAX's own
-    persistent compilation cache at the directory (with the zero-threshold
-    persistence knobs CPU backends need) so XLA-level recompiles are cached
-    across processes.  Idempotent; only touches jax.config on a change."""
-    global _jax_cache_applied
-    d = os.environ.get("HEAT_TPU_COMPILE_CACHE") or None
-    prev = _jax_cache_applied
-    if d == prev:
-        return
-    _jax_cache_applied = d
-    if d is None:
-        if isinstance(prev, str):
-            jax.config.update("jax_compilation_cache_dir", None)
-        return  # knob was never set: leave jax's own defaults untouched
-    jax.config.update("jax_compilation_cache_dir", d)
-    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
-    jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
+def _place_jax_cache() -> None:
+    """Place JAX's persistent compilation cache (module docstring): nothing is
+    set in code where ``JAX_COMPILATION_CACHE_DIR`` is set, :data:`JAX_CACHE_DIR`
+    otherwise."""
+    if not os.environ.get("JAX_COMPILATION_CACHE_DIR"):
+        jax.config.update("jax_compilation_cache_dir", JAX_CACHE_DIR)
 
 
 def reload() -> None:
-    """Re-read ``HEAT_TPU_EXEC_CACHE`` / ``HEAT_TPU_COMPILE_CACHE`` from the
-    environment (the documented re-read point — wired into
-    ``ht.reload_env_knobs``).  Changing the cache directory drops the
-    in-memory index so the next lookup reads the new location."""
+    """Re-read ``HEAT_TPU_EXEC_CACHE`` from the environment (the documented
+    re-read point — wired into ``ht.reload_env_knobs``).  Changing the cache
+    directory drops the in-memory index so the next lookup reads the new
+    location."""
     global _dir, _index, _index_rejected
     with _lock:
         new = os.environ.get("HEAT_TPU_EXEC_CACHE") or None
@@ -139,7 +133,6 @@ def reload() -> None:
             _dir = new
             _index = None
             _index_rejected = False
-        _apply_jax_cache_locked()
 
 
 def armed() -> bool:
@@ -256,7 +249,7 @@ def load_program(prog) -> Optional[Any]:
     (miss / unsupported / typed-rejected corruption — the caller jit-builds
     as usual).  Called by ``_Program.__call__`` under the executor lock on
     the FIRST call of the plain variant only; replays never touch this."""
-    if _dir is None or _se is None:
+    if _dir is None:
         return None
     spec = prog.spec
     if spec is None:
@@ -306,7 +299,7 @@ def load_program(prog) -> Optional[Any]:
         # (XLA CPU cannot relocate jit fusion symbols across processes;
         # version/topology skew does the same on device backends): not
         # corruption — recorded as its own kind, recompiled via the normal
-        # build (which the HEAT_TPU_COMPILE_CACHE disk cache accelerates)
+        # build (which JAX's persistent compilation cache accelerates)
         diagnostics.record_resilience_event(
             "executor.compile_cache", "artifact-incompatible",
             f"{type(exc).__name__}: {exc} (fingerprint {fp[:12]})",
@@ -374,7 +367,7 @@ def executor_save_warmup(path: Optional[str] = None, top: int = DEFAULT_TOP,
         if prior and prior.get("blob"):
             entry["blob"] = prior["blob"]  # artifact already on disk
             entry["nbytes"] = prior.get("nbytes")
-        elif aot and _se is not None and prog._plain is not None \
+        elif aot and prog._plain is not None \
                 and prog.arg_specs is not None and not prog.aot_loaded:
             try:
                 compiled = prog._plain.lower(*prog.arg_specs).compile()
@@ -623,4 +616,5 @@ def _aot_load_count() -> int:
 
 # memoise the knobs at import (a fresh process needs nothing extra; in-process
 # flips re-read through reload(), wired into ht.reload_env_knobs)
+_place_jax_cache()
 reload()

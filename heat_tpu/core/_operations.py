@@ -28,7 +28,7 @@ import jax.numpy as jnp
 
 from . import _executor, _result_cache, diagnostics, profiler, sanitation, types
 from .communication import get_comm
-from .devices import get_device
+from .devices import get_device, promoted_dtype, require_device_dtype
 from .dndarray import DNDarray
 from .stride_tricks import broadcast_shapes, sanitize_axis
 
@@ -169,6 +169,11 @@ def _ensure_dndarray(x, device=None, comm=None) -> DNDarray:
 
     if isinstance(x, DNDarray):
         return x
+    if type(x) is complex:
+        # a Python complex is weakly typed: its wrapper (shape/split bookkeeping
+        # only — the op itself takes the scalar) must not be a complex128 array,
+        # which a TPU refuses (devices.require_device_dtype)
+        x = np.complex64(x)
     return factories.array(x, device=device, comm=comm)
 
 
@@ -200,51 +205,23 @@ def handle_out(res: DNDarray, out: Optional[DNDarray], proto: DNDarray) -> DNDar
     return out
 
 
-def _on_accelerator(value) -> bool:
-    """True when any of the array's committed devices is a non-CPU device.
-    (``array.device`` returns a NamedSharding for mesh-committed arrays, so a
-    ``.platform`` check on it silently passes — use the device set instead.)"""
-    try:
-        # the known failure modes: tracers/np values without .devices()
-        # (AttributeError), deleted or uncommitted buffers (RuntimeError) —
-        # anything else (KeyboardInterrupt-class included) must propagate
-        return any(d.platform != "cpu" for d in value.devices())
-    except (AttributeError, RuntimeError, TypeError) as exc:
-        if diagnostics._enabled:
-            diagnostics.record_fallback(
-                "dispatch.on_accelerator", f"{type(exc).__name__}: {exc}"
-            )
-        return True  # unknown placement: moving is the safe choice
-
-
 def _safe_astype(value, jax_dtype):
-    """``value.astype(jax_dtype)`` that first moves the value to host when the
-    target dtype can't live on the accelerator (an on-device cast to complex is
-    itself the poisoning op — devices.accelerator_capabilities)."""
-    from .devices import complex_needs_host, cpu_fallback_device
-
-    if complex_needs_host(jax_dtype) and _on_accelerator(value):
-        value = jax.device_put(value, cpu_fallback_device())
+    """``value.astype(jax_dtype)``, refusing a target dtype the device cannot hold
+    (``devices.require_device_dtype``)."""
+    require_device_dtype(jax_dtype)
     return value.astype(jax_dtype)
 
 
-def _complex_host_route(*vals):
-    """When an op's result type is complex and the accelerator can't hold complex
-    values (devices.accelerator_capabilities — one failed attempt poisons the
-    process), move the inputs to host CPU and run there. This also makes mixed
-    host-complex × accelerator-real operands computable (eager jax refuses
-    differently-committed inputs). Returns ``(vals, context_manager)``."""
-    from contextlib import nullcontext
-
-    from .devices import complex_needs_host, cpu_fallback_device
-
-    if not complex_needs_host(*vals):
-        return vals, nullcontext()
-    cpu = cpu_fallback_device()
-    moved = tuple(
-        jax.device_put(v, cpu) if isinstance(v, jax.Array) else v for v in vals
-    )
-    return moved, jax.default_device(cpu)
+def _complex_operands(*vals):
+    """Operands of an eagerly dispatched op, checked against what the device can
+    hold (``devices.require_device_dtype`` on the promoted result type). Python
+    complex scalars of a complex64 result are narrowed on the host: jnp would pass
+    them into the program as (weak) complex128 scalars, which a TPU cannot compile."""
+    rt = promoted_dtype(*vals)
+    require_device_dtype(rt)
+    if rt == np.complex64:
+        return tuple(np.complex64(v) if isinstance(v, complex) else v for v in vals)
+    return vals
 
 
 def _out_split_binary(out_shape: Tuple[int, ...], *operands: DNDarray) -> Optional[int]:
@@ -377,7 +354,7 @@ def _binary_jit(
     if op is _executor.UNSUPPORTED or kwsig is _executor.UNSUPPORTED:
         return NotImplemented
     if out is not None and jnp.issubdtype(out.dtype.jax_type(), jnp.complexfloating):
-        return NotImplemented  # _safe_astype may host-route complex targets
+        return NotImplemented  # complex targets take the eager path (_safe_astype checks them)
     nd = len(out_shape)
     phys_shape = comm.padded_shape(out_shape, out_split)
 
@@ -573,7 +550,7 @@ def _local_jit(operation, x, out, fn_kwargs):
             return _executor.UNSUPPORTED
         rshape = tuple(probe.shape)
         if jnp.issubdtype(probe.dtype, jnp.complexfloating):
-            return _executor.UNSUPPORTED  # comm.shard may host-route complex values
+            return _executor.UNSUPPORTED  # complex results take the eager path (comm.shard checks them)
         if has_out:
             if rshape != tuple(gshape):
                 return _executor.UNSUPPORTED
@@ -890,9 +867,8 @@ def binary_op(
     (reference ``__binary_op`` ``_operations.py:22``)."""
     fn_kwargs = fn_kwargs or {}
     if np.isscalar(t1) and np.isscalar(t2) and out is None and where is None:
-        (t1r, t2r), ctx = _complex_host_route(t1, t2)
-        with ctx:
-            res = operation(jnp.asarray(t1r), jnp.asarray(t2r), **fn_kwargs)
+        t1r, t2r = _complex_operands(t1, t2)
+        res = operation(jnp.asarray(t1r), jnp.asarray(t2r), **fn_kwargs)
         from . import factories
 
         return factories.array(res)
@@ -957,21 +933,14 @@ def binary_op(
     # promote: scalars stay weakly typed so jnp's promotion matches numpy/heat
     x1 = a.larray if not np.isscalar(t1) else t1
     x2 = b.larray if not np.isscalar(t2) else t2
-    (x1, x2), ctx = _complex_host_route(x1, x2)
-    with ctx:
-        result = operation(x1, x2, **fn_kwargs)
+    x1, x2 = _complex_operands(x1, x2)
+    result = operation(x1, x2, **fn_kwargs)
 
-        if where is not None:
-            w = where.larray if isinstance(where, DNDarray) else jnp.asarray(where)
-            if out is not None:
-                (w, result, base), ctx2 = _complex_host_route(w, result, out.larray)
-            else:
-                (w, result), ctx2 = _complex_host_route(w, result)
-                base = None
-            with ctx2:
-                if base is None:
-                    base = jnp.zeros(out_shape, result.dtype)
-                result = jnp.where(w, result, base)
+    if where is not None:
+        w = where.larray if isinstance(where, DNDarray) else jnp.asarray(where)
+        base = out.larray if out is not None else jnp.zeros(out_shape, result.dtype)
+        require_device_dtype(result, base)
+        result = jnp.where(w, result, base)
 
     if out is not None:
         sanitation.sanitize_out(out, out_shape, out_split, device)

@@ -37,6 +37,7 @@ import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec
 
 from . import diagnostics, forensics, profiler, resilience, supervision, telemetry
+from .devices import require_device_dtype
 
 
 def _guarded(site, fn, *args, **kwargs):
@@ -147,7 +148,6 @@ __all__ = [
     "use_comm",
     "sanitize_comm",
     "initialize",
-    "compat_shard_map",
 ]
 
 # The default mesh axis name carried by every split DNDarray dimension.
@@ -166,32 +166,6 @@ def _payload_bytes(x) -> int:
     for s in shape:
         size *= int(s)
     return size * np.dtype(dtype).itemsize
-
-
-try:  # jax >= 0.6: top-level export, replication check spelled check_vma=
-    _shard_map_impl = jax.shard_map
-    _SHARD_MAP_CHECK_KW = "check_vma"
-except AttributeError:  # pragma: no cover - jax 0.4.x: experimental home, check_rep=
-    from jax.experimental.shard_map import shard_map as _shard_map_impl
-
-    _SHARD_MAP_CHECK_KW = "check_rep"
-
-
-def compat_shard_map(fn, mesh, in_specs, out_specs, check: bool = False):
-    """``shard_map`` across the jax versions this repo supports.
-
-    ``jax.shard_map`` only exists from jax 0.6 (with the replication check
-    spelled ``check_vma=``); on 0.4.x the implementation lives in
-    ``jax.experimental.shard_map`` and the same switch is ``check_rep=``.
-    Explicit-collective program bodies (the comm-plan ring/reduce-scatter
-    matmuls, the all_to_all resplit) go through this resolver so one spelling
-    traces on both. ``check=False`` (the default) also sidesteps the 0.4.x
-    requirement to ``pcast`` replicated outputs, which has no stable spelling
-    across versions."""
-    return _shard_map_impl(
-        fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-        **{_SHARD_MAP_CHECK_KW: check},
-    )
 
 
 class Communication:
@@ -409,18 +383,7 @@ class MeshCommunication(Communication):
         is idempotent on physical values.
         """
         if jnp.issubdtype(getattr(array, "dtype", None), jnp.complexfloating):
-            from .devices import complex_needs_host, cpu_fallback_device
-
-            if (
-                complex_needs_host(array.dtype)
-                and self._devices
-                and self._devices[0].platform != "cpu"
-            ):
-                # the accelerator cannot hold complex values (see
-                # devices.accelerator_capabilities); complex arrays live on host CPU,
-                # un-sharded — on such systems the accelerator mesh is the wrong home
-                # for this dtype and the split is metadata only
-                return jax.device_put(array, cpu_fallback_device())
+            require_device_dtype(array.dtype)
         if diagnostics._enabled:
             # counts every layout REQUEST with its logical payload: an operand
             # that already matches the target (the early return below) costs no

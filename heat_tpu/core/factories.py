@@ -17,7 +17,7 @@ import jax.numpy as jnp
 
 from . import types
 from .communication import Communication, sanitize_comm
-from .devices import Device, sanitize_device
+from .devices import Device, require_device_dtype, sanitize_device
 from .dndarray import DNDarray
 from .stride_tricks import sanitize_axis, sanitize_shape
 
@@ -42,20 +42,6 @@ __all__ = [
 ]
 
 
-def _complex_to_host(value, target_dtype=None):
-    """When the accelerator can't hold complex values (the failed attempt poisons
-    the process — see devices.accelerator_capabilities), values that are or are
-    about to become complex move to the host CPU. All factory paths converge here
-    through ``_wrap``."""
-    from ._operations import _on_accelerator
-    from .devices import complex_needs_host, cpu_fallback_device
-
-    if complex_needs_host(target_dtype if target_dtype is not None else value):
-        if not isinstance(value, jax.Array) or _on_accelerator(value):
-            return jax.device_put(value, cpu_fallback_device())
-    return value
-
-
 def _wrap(
     value: jax.Array,
     dtype: Optional[Type[types.datatype]],
@@ -69,9 +55,7 @@ def _wrap(
     if dtype is not None:
         dtype = types.canonical_heat_type(dtype)
         if value.dtype != np.dtype(dtype.jax_type()):
-            # an accelerator-resident cast to complex would run on-device:
-            # move to host first when the accelerator can't hold complex
-            value = _complex_to_host(value, target_dtype=np.dtype(dtype.jax_type()))
+            require_device_dtype(np.dtype(dtype.jax_type()))
             value = value.astype(dtype.jax_type())
     else:
         dtype = types.canonical_heat_type(value.dtype)
@@ -92,8 +76,6 @@ def arange(*args, dtype=None, split=None, device=None, comm=None) -> DNDarray:
         start, stop, step = args
     else:
         raise TypeError(f"function takes minimum one and at most 3 positional arguments ({num_args} given)")
-    from .devices import complex_creation_ctx
-
     if dtype is None:
         # match the reference: all-int args → int32, otherwise default float
         if all(isinstance(a, (int, np.integer)) for a in (start, stop, step)):
@@ -102,8 +84,8 @@ def arange(*args, dtype=None, split=None, device=None, comm=None) -> DNDarray:
             value = jnp.arange(start, stop, step, dtype=jnp.float32)
     else:
         jt = types.canonical_heat_type(dtype).jax_type()
-        with complex_creation_ctx(np.dtype(jt)):
-            value = jnp.arange(start, stop, step, dtype=jt)
+        require_device_dtype(np.dtype(jt))
+        value = jnp.arange(start, stop, step, dtype=jt)
     return _wrap(value, dtype, split, device, comm)
 
 
@@ -150,15 +132,14 @@ def array(
             ):
                 # python floats default to the framework float type (f32), like torch/heat
                 np_value = np_value.astype(np.float32)
-            from .devices import complex_needs_host, cpu_fallback_device
-
-            if complex_needs_host(np_value.dtype):
-                # the accelerator can't even materialize complex values (and the
-                # failed attempt poisons the process); create on host CPU —
-                # comm.shard keeps this dtype there
-                value = jax.device_put(np_value, cpu_fallback_device())
-            else:
-                value = jnp.asarray(np_value)
+            if dtype is not None and np.iscomplexobj(np_value):
+                target = np.dtype(types.canonical_heat_type(dtype).jax_type())
+                if np.issubdtype(target, np.complexfloating):
+                    # cast on the host: a complex128 value must never reach a
+                    # TPU program (devices.require_device_dtype)
+                    np_value = np_value.astype(target)
+            require_device_dtype(np_value.dtype)
+            value = jnp.asarray(np_value)
 
     while value.ndim < ndmin:
         value = value[jnp.newaxis]
@@ -220,12 +201,8 @@ def __factory(shape, dtype, split, maker, device, comm, order="C") -> DNDarray:
     """Shared logic of empty/ones/zeros/full (reference ``factories.py:699``)."""
     shape = sanitize_shape(shape)
     dtype = types.canonical_heat_type(dtype)
-    from .devices import complex_creation_ctx
-
-    # complex creation happens on host when the accelerator can't hold it
-    # (devices.accelerator_capabilities); nullcontext otherwise
-    with complex_creation_ctx(np.dtype(dtype.jax_type())):
-        value = maker(shape, dtype=dtype.jax_type())
+    require_device_dtype(np.dtype(dtype.jax_type()))
+    value = maker(shape, dtype=dtype.jax_type())
     return _wrap(value, dtype, split, device, comm)
 
 
@@ -247,21 +224,23 @@ def ones(shape, dtype=types.float32, split=None, device=None, comm=None, order="
 
 def full(shape, fill_value, dtype=None, split=None, device=None, comm=None, order="C") -> DNDarray:
     """Constant fill (reference ``factories.py:957``)."""
-    from .devices import complex_creation_ctx
-
     shape = sanitize_shape(shape)
     target = (
         np.result_type(fill_value)
         if dtype is None
         else np.dtype(types.canonical_heat_type(dtype).jax_type())
     )
-    with complex_creation_ctx(target):
-        if dtype is None:
-            value = jnp.full(shape, fill_value)
-            if value.dtype == jnp.float64 and isinstance(fill_value, float):
-                value = value.astype(jnp.float32)
-        else:
-            value = jnp.full(shape, fill_value, dtype=types.canonical_heat_type(dtype).jax_type())
+    require_device_dtype(target)
+    if dtype is None:
+        value = jnp.full(shape, fill_value)
+        if value.dtype == jnp.float64 and isinstance(fill_value, float):
+            value = value.astype(jnp.float32)
+    else:
+        if isinstance(fill_value, complex) and np.issubdtype(target, np.complexfloating):
+            # cast on the host: a Python complex would enter the program as a
+            # (weak) complex128 scalar (devices.require_device_dtype)
+            fill_value = target.type(fill_value)
+        value = jnp.full(shape, fill_value, dtype=target)
     return _wrap(value, dtype, split, device, comm)
 
 
@@ -323,10 +302,8 @@ def eye(shape, dtype=types.float32, split=None, device=None, comm=None, order="C
         else:
             n, m = int(shape[0]), int(shape[1])
     dtype = types.canonical_heat_type(dtype)
-    from .devices import complex_creation_ctx
-
-    with complex_creation_ctx(np.dtype(dtype.jax_type())):
-        value = jnp.eye(n, m, dtype=dtype.jax_type())
+    require_device_dtype(np.dtype(dtype.jax_type()))
+    value = jnp.eye(n, m, dtype=dtype.jax_type())
     return _wrap(value, dtype, split, device, comm)
 
 

@@ -55,7 +55,6 @@ import jax
 import jax.numpy as jnp
 
 from .. import _executor, diagnostics, types
-from ..communication import compat_shard_map
 from ..dndarray import DNDarray
 
 __all__ = ["Plan", "plan_matmul", "try_matmul", "try_resplit"]
@@ -90,9 +89,8 @@ def _phys_bytes(comm, gshape, split, dtype) -> int:
 
 def _plannable_dtype(x: DNDarray) -> bool:
     dt = np.dtype(x.dtype.jax_type() if hasattr(x.dtype, "jax_type") else x.dtype)
-    return (
-        np.issubdtype(dt, np.floating) or np.issubdtype(dt, np.integer)
-    ) and not np.issubdtype(dt, np.bool_)
+    # jnp's lattice, not numpy's: bfloat16 is no subtype of np.floating
+    return jnp.issubdtype(dt, jnp.floating) or jnp.issubdtype(dt, jnp.integer)
 
 
 def _structural(a, b):
@@ -297,10 +295,10 @@ def _ring_body(variant: str, comm, agshape, bgshape, precision):
         raise ValueError(f"unknown ring variant {variant!r}")
 
     def body(pa, pb):
-        return compat_shard_map(
-            block, comm.mesh,
+        return jax.shard_map(
+            block, mesh=comm.mesh,
             in_specs=(comm.spec(2, in_splits[0]), comm.spec(2, in_splits[1])),
-            out_specs=comm.spec(2, out_split),
+            out_specs=comm.spec(2, out_split), check_vma=False,
         )(pa, pb)
 
     return body, out_split
@@ -331,10 +329,10 @@ def _rs_body(variant: str, comm, agshape, bgshape, precision):
         return comm.psum_scatter(part, scatter_axis=0, axis_name=ax)
 
     def body(pa, pb):
-        return compat_shard_map(
-            block, comm.mesh,
+        return jax.shard_map(
+            block, mesh=comm.mesh,
             in_specs=(comm.spec(2, a_split), comm.spec(2, b_split)),
-            out_specs=comm.spec(2, 0),
+            out_specs=comm.spec(2, 0), check_vma=False,
         )(pa, pb)
 
     return body, 0
@@ -439,10 +437,10 @@ def try_resplit(x: DNDarray, axis: int) -> Any:
             return out
 
         def body(val):
-            return compat_shard_map(
-                block, comm.mesh,
+            return jax.shard_map(
+                block, mesh=comm.mesh,
                 in_specs=(comm.spec(nd, src),),
-                out_specs=comm.spec(nd, dst),
+                out_specs=comm.spec(nd, dst), check_vma=False,
             )(val)
 
         return body, comm.sharding(nd, dst), None, None

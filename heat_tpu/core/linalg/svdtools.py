@@ -33,8 +33,9 @@ __all__ = ["hsvd", "hsvd_rank", "hsvd_rtol"]
 def guarded_svd(x, full_matrices: bool = False, compute_uv: bool = True):
     """``jnp.linalg.svd`` with the TPU x64 guard, shared by hsvd and the full
     :func:`heat_tpu.linalg.svd`: the float32 SVD lowering SIGABRTs the TPU
-    compiler when global x64 mode is on (int64 index types), so the op is traced
-    in x32 scope there."""
+    compiler when global x64 mode is on (a CHECK in shape.h; still so on TPU v5e
+    with jax 0.9.0 / libtpu 0.0.34, where float64 SVD and float32 QR/eigh/Cholesky
+    compile unguarded), so the op is traced in x32 scope there."""
     if jax.default_backend() != "cpu" and x.dtype == jnp.float32:
         with jax.enable_x64(False):
             return jnp.linalg.svd(x, full_matrices=full_matrices, compute_uv=compute_uv)

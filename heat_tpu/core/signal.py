@@ -23,7 +23,8 @@ from .dndarray import DNDarray
 __all__ = ["convolve"]
 
 
-def _convolve_overlap_add(comm, av: jax.Array, vv: jax.Array, n: int, m: int) -> jax.Array:
+def _convolve_overlap_add(comm, av: jax.Array, vv: jax.Array, n: int, m: int,
+                          precision=None) -> jax.Array:
     """Distributed full convolution by overlap-add under ``shard_map``.
 
     Shard ``i`` holds ``c = n_pad/P`` samples and computes a local full convolution
@@ -41,7 +42,8 @@ def _convolve_overlap_add(comm, av: jax.Array, vv: jax.Array, n: int, m: int) ->
     av = comm.shard(av, 0)
 
     def body(al, vl):
-        y = jnp.convolve(al.reshape(-1), vl.reshape(-1), mode="full")  # c+m-1
+        y = jnp.convolve(al.reshape(-1), vl.reshape(-1), mode="full",
+                         precision=precision)  # c+m-1
         tail = y[c:]  # my halo into the next shard's head
         recv = comm.ppermute(
             tail, [(i, i + 1) for i in range(nproc - 1)], axis_name=axis
@@ -79,11 +81,16 @@ def convolve(a, v, mode: str = "full") -> DNDarray:
     av = a.larray.astype(dt.jax_type())
     vv = v.larray.astype(dt.jax_type())
     n, m = a.gshape[0], v.gshape[0]
+    # the framework's contraction policy: float32 runs full-f32 passes (a TPU's
+    # single-pass default rounds the inputs to bf16: 5e-3 off on unit-scale data)
+    from .linalg.basics import _contraction_precision
+
+    precision = _contraction_precision(None, av, vv)
     if a.split == 0 and a.is_distributed() and m >= 2 and m - 1 <= -(-n // a.comm.size):
         # distributed signal: explicit halo/overlap-add schedule on the ring
-        full = _convolve_overlap_add(a.comm, av, vv, n, m)
+        full = _convolve_overlap_add(a.comm, av, vv, n, m, precision)
     else:
-        full = jnp.convolve(av, vv, mode="full")
+        full = jnp.convolve(av, vv, mode="full", precision=precision)
     if mode == "full":
         result = full
     elif mode == "same":

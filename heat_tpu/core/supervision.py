@@ -974,6 +974,10 @@ def kv_barrier(ns: str, *, nprocs: Optional[int] = None,
 
 
 # ------------------------------------------------- supervised jax runtime
+#: seconds; effectively never (the old 10 s beat x 1e6 missed-beat budget)
+_NATIVE_HEARTBEAT_TIMEOUT_S = 10_000_000
+
+
 def _service_bind_address(coordinator_address: str) -> str:
     return "[::]:" + coordinator_address.rsplit(":", 1)[1]
 
@@ -991,7 +995,7 @@ def bootstrap_distributed(coordinator_address: str, num_processes: int,
     error propagation makes impossible."""
     import jax  # noqa: F401
     from jax._src import distributed as _dist
-    from jax._src.lib import xla_extension as xe
+    from jax._src.lib import _jax as xe
 
     global _owns_client
     state = _dist.global_state
@@ -1000,16 +1004,16 @@ def bootstrap_distributed(coordinator_address: str, num_processes: int,
     timeout = (int(init_timeout_s) if init_timeout_s is not None
                else max(1, coord_timeout_ms() // 1000))
     if process_id == 0 and state.service is None:
-        # native failure detection OFF (one beat per 10 s, a practically
-        # infinite miss budget): supervision's KV heartbeats own detection,
-        # and the service must never fail-stop the survivors
+        # native failure detection OFF (a practically infinite heartbeat
+        # timeout): supervision's KV heartbeats own detection, and the
+        # service must never fail-stop the survivors
         state.service = xe.get_distributed_runtime_service(
             _service_bind_address(coordinator_address), num_processes,
-            heartbeat_interval=10, max_missing_heartbeats=1_000_000,
+            heartbeat_timeout=_NATIVE_HEARTBEAT_TIMEOUT_S,
         )
     client = xe.get_distributed_runtime_client(
         coordinator_address, process_id, init_timeout=timeout,
-        heartbeat_interval=10, max_missing_heartbeats=1_000_000,
+        heartbeat_timeout=_NATIVE_HEARTBEAT_TIMEOUT_S,
         shutdown_on_destruction=False, use_compression=True,
     )
     client.connect()

@@ -21,6 +21,7 @@ import jax.numpy as jnp
 
 from ..core import types
 from ..core._operations import wrap_result
+from ..core.devices import require_device_dtype
 from ..core.dndarray import DNDarray
 from ..core.sanitation import sanitize_in
 from ..core.stride_tricks import sanitize_axis
@@ -66,29 +67,14 @@ def _pencil_split(x: DNDarray, transformed: Tuple[int, ...]) -> Optional[int]:
     return None
 
 
-def _fft_backend_supported() -> bool:
-    """Whether the default accelerator backend lowers FFT (some TPU runtimes report
-    UNIMPLEMENTED for every fft HLO — and the failed compile poisons the issuing
-    process). Delegates to the shared subprocess capability probe in
-    :func:`heat_tpu.core.devices.accelerator_capabilities`; override with
-    HEAT_TPU_FFT_BACKEND=cpu|device."""
-    from ..core.devices import accelerator_capabilities
-
-    return accelerator_capabilities()["fft"]
-
-
 def _run_fft(op, value, **kw):
-    """Run one jnp.fft op, falling back to the host CPU backend when the
-    accelerator cannot lower FFT (the result is re-sharded by the caller's
-    wrap_result, so distribution semantics are unchanged — only the transform
-    itself executes on host)."""
-    if _fft_backend_supported():
-        return op(value, **kw)
-    from ..core.devices import cpu_fallback_device
-
-    cpu = cpu_fallback_device()
-    with jax.default_device(cpu):
-        return op(jax.device_put(value, cpu), **kw)
+    """Run one jnp.fft op on the device. Only single-precision (and narrower) float
+    or complex64 input transforms in complex64; every other input dtype goes
+    through complex128, which a TPU cannot hold: refused with a typed error
+    (``devices.require_device_dtype``)."""
+    if value.dtype not in (jnp.float32, jnp.complex64, jnp.float16, jnp.bfloat16):
+        require_device_dtype(jnp.complex128)
+    return op(value, **kw)
 
 
 def _fft_op(x: DNDarray, op, n=None, axis=-1, norm=None) -> DNDarray:

@@ -187,7 +187,21 @@ def scaled_dot_product_attention(query, key, value, attn_mask=None,
         k = key.larray if isinstance(key, DNDarray) else key
         v = value.larray if isinstance(value, DNDarray) else value
         m = attn_mask.larray if isinstance(attn_mask, DNDarray) else attn_mask
-        out = _dense_attention(q, k, v, m, is_causal, scale)
+        if (
+            query.split is not None
+            and query.split < seq_axis
+            and isinstance(key, DNDarray) and key.split == query.split
+            and isinstance(value, DNDarray) and value.split == query.split
+            and (m is None or m.ndim == 2)
+            and query.comm.is_distributed()
+            and isinstance(query.comm.axis_name, str)
+            and query.shape[query.split] % query.comm.size == 0
+            and key.shape[query.split] == query.shape[query.split]
+        ):
+            out = _batch_sharded(q, k, v, m, query.comm, query.split,
+                                 is_causal=is_causal, scale=scale)
+        else:
+            out = _dense_attention(q, k, v, m, is_causal, scale)
         return wrap_result(out, query, query.split)
     k = key.larray if isinstance(key, DNDarray) else key
     v = value.larray if isinstance(value, DNDarray) else value
@@ -307,6 +321,28 @@ def _ring_sharded(q, k, v, comm, is_causal=False, scale=None):
         out_specs=spec,
     )
     return fn(q, k, v)
+
+
+def _batch_sharded(q, k, v, mask, comm, split, is_causal=False, scale=None):
+    """Dense attention on q/k/v sharded along a batch or head dim: every device
+    attends its own slice under shard_map, no communication. The partitioner
+    cannot do this itself: it refuses a Mosaic call on sharded operands
+    ("cannot be automatically partitioned")."""
+    from jax import shard_map
+
+    spec = P(*[comm.axis_name if i == split else None for i in range(q.ndim)])
+
+    def body(ql, kl, vl, *ml):
+        return _dense_attention(ql, kl, vl, ml[0] if ml else None, is_causal, scale)
+
+    masks = () if mask is None else (mask,)
+    # check_vma off: a pallas_call's out_shape carries no varying-axes annotation
+    fn = shard_map(
+        body, mesh=comm.mesh,
+        in_specs=(spec, spec, spec) + (P(),) * len(masks), out_specs=spec,
+        check_vma=False,
+    )
+    return fn(q, k, v, *masks)
 
 
 def zigzag_order(t: int, p: int) -> np.ndarray:

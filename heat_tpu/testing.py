@@ -10,12 +10,27 @@ is the device count of the mesh (1 on a single chip, N under
 
 from __future__ import annotations
 
+import re
 import unittest
 from typing import Callable, Optional, Sequence, Tuple, Union
 
 import numpy as np
 
 import heat_tpu as ht
+
+
+_LOCATION_TABLES = re.compile(
+    r"^(FileNames|FunctionNames|FileLocations|StackFrames)\n(?:.+\n)*\n", re.MULTILINE
+)
+_LOCATION_FIELDS = re.compile(r" (?:stack_frame_id|source_file|source_line)=(?:\"[^\"]*\"|\S+?)(?=[ }])")
+
+
+def program_text(compiled) -> str:
+    """``compiled.as_text()`` without source-location metadata (the file / function
+    / location / stack-frame tables and the per-op ``stack_frame_id``): the
+    byte-parity contracts are about the program, not the line the caller stands
+    on. ``op_name`` scopes stay."""
+    return _LOCATION_FIELDS.sub("", _LOCATION_TABLES.sub("", compiled.as_text()))
 
 
 class TestCase(unittest.TestCase):

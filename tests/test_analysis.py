@@ -49,7 +49,7 @@ def run_fixture(files):
             os.makedirs(os.path.dirname(path), exist_ok=True)
             with open(path, "w", encoding="utf-8") as fh:
                 fh.write(textwrap.dedent(src))
-        findings, _ = run_analysis(package_root=pkg, extra_files=[])
+        findings, _ = run_analysis(package_root=pkg)
         return findings
 
 
@@ -565,7 +565,7 @@ class TestFixUnusedPragmas(unittest.TestCase):
             self.assertNotIn("trace-env-read", after)
             self.assertIn("ht: ignore[silent-except] -- the swallow is deliberate", after)
             # round trip: the fixed tree is pragma-clean
-            findings, _ = run_analysis(package_root=pkg, extra_files=[])
+            findings, _ = run_analysis(package_root=pkg)
             self.assertEqual([f for f in findings if f.rule.startswith("pragma")], [])
 
 
@@ -640,7 +640,7 @@ class TestIncrementalCache(unittest.TestCase):
             payload["code_hash"] = "stale-rules"
             with open(cache_path, "w") as fh:
                 json.dump(payload, fh)
-            hashes = cache_mod.module_hashes(pkg, [])
+            hashes = cache_mod.module_hashes(pkg)
             self.assertIsNone(cache_mod.lookup(
                 payload, pkg, cache_mod.code_fingerprint(), hashes
             ))
@@ -708,7 +708,6 @@ class TestRuntimeImportContract(unittest.TestCase):
                 os.path.join("heat_tpu", "core", "resilience.py"),
                 os.path.join("heat_tpu", "core", "_scheduler.py"),
                 os.path.join("heat_tpu", "core", "telemetry.py"),
-                "_diag_bootstrap.py",
             ]
             for rel in rels:
                 path = os.path.join(root, rel)
@@ -732,7 +731,7 @@ class TestRuntimeImportContract(unittest.TestCase):
         )
         self.assertIn("STDLIB_ONLY_OK", proc.stdout)
         for rel in ("diagnostics.py", "profiler.py", "resilience.py",
-                    "_scheduler.py", "telemetry.py", "_diag_bootstrap.py"):
+                    "_scheduler.py", "telemetry.py"):
             self.assertIn(rel, proc.stdout)
 
 

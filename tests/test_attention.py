@@ -10,19 +10,7 @@ import pytest
 
 import jax
 import jax.numpy as jnp
-from jax import lax
-
-try:
-    from jax import shard_map
-except ImportError:
-    # jax-0.4.x exposes shard_map only under jax.experimental — the module
-    # under test targets the newer top-level API, so every test here would
-    # fail on the old runtime anyway: skip the module cleanly instead of
-    # erroring at collection (the known-red set stays visible, not fatal).
-    pytest.skip(
-        "jax.shard_map unavailable on this jax runtime (pre-0.5 API)",
-        allow_module_level=True,
-    )
+from jax import lax, shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 from functools import partial
 

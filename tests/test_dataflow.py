@@ -43,7 +43,7 @@ def build_universe(files):
         os.makedirs(os.path.dirname(path), exist_ok=True)
         with open(path, "w", encoding="utf-8") as fh:
             fh.write(textwrap.dedent(src))
-    uni = Universe(pkg, extra_files=[])
+    uni = Universe(pkg)
     return td, uni, dataflow.get(uni)
 
 

@@ -82,42 +82,31 @@ class TestPencilEdge:
         )
 
 
-class TestAcceleratorCaps:
-    def test_caps_on_cpu_backend(self):
-        """The CPU test mesh always reports full support (no subprocess probe)."""
+class TestDeviceDtypeRefusal:
+    """complex128 is the one dtype a TPU cannot hold (libtpu aborts the process):
+    the call sites refuse it with a TypeError; complex64 and FFT run on the device."""
+
+    def test_noop_off_tpu(self):
         from heat_tpu.core import devices as dv
 
-        old = dv._ACCEL_CAPS
-        dv._ACCEL_CAPS = None
-        try:
-            caps = dv.accelerator_capabilities()
-            assert caps == {"complex": True, "fft": True}
-        finally:
-            dv._ACCEL_CAPS = old
+        dv.require_device_dtype(np.complex128)  # the CPU mesh holds everything
+        assert ht.array([1 + 2j]).dtype is ht.complex128
 
-    def test_env_overrides(self, monkeypatch):
+    def test_refuses_complex128_on_tpu(self, monkeypatch):
+        import jax
+
         from heat_tpu.core import devices as dv
 
-        old = dv._ACCEL_CAPS
-        dv._ACCEL_CAPS = None
-        monkeypatch.setenv("HEAT_TPU_COMPLEX_BACKEND", "cpu")
-        monkeypatch.setenv("HEAT_TPU_FFT_BACKEND", "device")
-        try:
-            caps = dv.accelerator_capabilities()
-            assert caps == {"complex": False, "fft": True}
-        finally:
-            dv._ACCEL_CAPS = old
-
-    def test_run_fft_cpu_route_matches(self, monkeypatch):
-        """Forcing the CPU FFT route gives identical results to the direct path."""
-        import importlib
-
-        import jax.numpy as jnp
-
-        fmod = importlib.import_module("heat_tpu.fft.fft")
-
-        x = jnp.array(np.arange(8.0))
-        direct = np.asarray(jnp.fft.rfft(x))
-        monkeypatch.setattr(fmod, "_fft_backend_supported", lambda: False)
-        routed = np.asarray(fmod._run_fft(jnp.fft.rfft, x))
-        np.testing.assert_allclose(routed, direct, rtol=1e-6)
+        monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+        dv.require_device_dtype(np.complex64, np.float32, 1j)  # weak scalar stays c64
+        for bad in ((np.complex128,), (np.float64, 1j), (np.complex64, np.float64)):
+            with pytest.raises(TypeError, match="complex128"):
+                dv.require_device_dtype(*bad)
+        with pytest.raises(TypeError, match="complex128"):
+            ht.array([1 + 2j])
+        with pytest.raises(TypeError, match="complex128"):
+            ht.fft.fft(ht.array(np.arange(8.0)))  # float64 in -> complex128 out
+        z = ht.array(np.arange(4) + 1j, dtype=ht.complex64, split=0)
+        w = z * (1 + 2j)  # the Python scalar is narrowed on the host
+        assert w.dtype is ht.complex64
+        np.testing.assert_allclose(w.numpy(), (np.arange(4) + 1j) * (1 + 2j), rtol=1e-6)

@@ -20,7 +20,7 @@ import heat_tpu as ht
 from heat_tpu.core import (
     _executor, diagnostics, forensics, ops, profiler, resilience, telemetry,
 )
-from heat_tpu.testing import TestCase
+from heat_tpu.testing import TestCase, program_text
 
 _OLD_THRESHOLD = None
 
@@ -117,7 +117,7 @@ class TestDisabledContract(_ForensicsCase):
                     out_shardings=entry.out_shardings,
                     keep_unused=entry.donate_index is not None,
                 )
-                texts[entry.label] = fn.lower(*entry.arg_specs).compile().as_text()
+                texts[entry.label] = program_text(fn.lower(*entry.arg_specs).compile())
             return texts
 
         baseline = chain_hlos()

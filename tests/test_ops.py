@@ -59,7 +59,7 @@ from heat_tpu.core import (
     supervision,
     telemetry,
 )
-from heat_tpu.testing import TestCase
+from heat_tpu.testing import TestCase, program_text
 
 
 class _OpsTestCase(TestCase):
@@ -701,7 +701,7 @@ class TestZeroCost(_OpsTestCase):
                     out_shardings=entry.out_shardings,
                     keep_unused=entry.donate_index is not None,
                 )
-                texts[entry.label] = fn.lower(*entry.arg_specs).compile().as_text()
+                texts[entry.label] = program_text(fn.lower(*entry.arg_specs).compile())
             return texts
 
         baseline = chain_hlos()

@@ -30,7 +30,7 @@ import jax
 
 import heat_tpu as ht
 from heat_tpu.core import _executor, profiler
-from heat_tpu.testing import TestCase
+from heat_tpu.testing import TestCase, program_text
 
 _OLD_THRESHOLD = None
 
@@ -516,7 +516,7 @@ class TestHLOParity(_ProfTestCase):
                 out_shardings=entry.out_shardings,
                 keep_unused=entry.donate_index is not None,
             )
-            texts[entry.label] = fn.lower(*entry.arg_specs).compile().as_text()
+            texts[entry.label] = program_text(fn.lower(*entry.arg_specs).compile())
         return texts
 
     def test_hlo_byte_parity_across_toggles(self):

@@ -24,6 +24,7 @@ import numpy as np
 import heat_tpu as ht
 import jax
 from heat_tpu.core import _executor, checkpoint, diagnostics, resilience, supervision
+from heat_tpu.testing import program_text
 
 
 class _SupervisionCase(unittest.TestCase):
@@ -510,7 +511,7 @@ class TestHLOByteParity(_SupervisionCase):
                 out_shardings=entry.out_shardings,
                 keep_unused=entry.donate_index is not None,
             )
-            texts[entry.label] = fn.lower(*entry.arg_specs).compile().as_text()
+            texts[entry.label] = program_text(fn.lower(*entry.arg_specs).compile())
         return texts
 
     def test_hlo_byte_parity_armed_idle(self):

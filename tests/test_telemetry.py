@@ -40,7 +40,7 @@ import jax
 
 import heat_tpu as ht
 from heat_tpu.core import _executor, diagnostics, profiler, resilience, telemetry
-from heat_tpu.testing import TestCase
+from heat_tpu.testing import TestCase, program_text
 
 
 class _TelTestCase(TestCase):
@@ -181,7 +181,7 @@ class TestCollectiveWindows(_TelTestCase):
                     out_shardings=entry.out_shardings,
                     keep_unused=entry.donate_index is not None,
                 )
-                texts[entry.label] = fn.lower(*entry.arg_specs).compile().as_text()
+                texts[entry.label] = program_text(fn.lower(*entry.arg_specs).compile())
             return texts
 
         baseline = chain_hlos()
