@@ -15,6 +15,7 @@ import numpy as np
 import jax.numpy as jnp
 
 import heat_tpu as ht
+from ..core import diagnostics
 from ..core.base import BaseEstimator, ClusteringMixin
 from ..core.dndarray import DNDarray
 
@@ -140,21 +141,25 @@ class _KCluster(ClusteringMixin, BaseEstimator):
         """
         if not isinstance(x, DNDarray):
             raise ValueError(f"input needs to be a DNDarray, but was {type(x)}")
-        self._initialize_cluster_centers(x)
+        on = diagnostics._enabled
+        with diagnostics.span("cluster.fit", x) if on else diagnostics.NO_SPAN:
+            self._initialize_cluster_centers(x)
 
-        promoted = ht.promote_types(x.dtype, ht.float32).jax_type()
-        xv = x.larray.astype(promoted)
-        centers0 = self._cluster_centers.larray.astype(promoted)
-        n_iter, centers, labels, inertia = self._lloyd_fn(x)(xv, centers0)
-        self._n_iter = int(n_iter)
-        self._cluster_centers = ht.array(
-            centers.astype(centers0.dtype), comm=x.comm
-        )
-        from ..core._operations import wrap_result
+            promoted = ht.promote_types(x.dtype, ht.float32).jax_type()
+            xv = x.larray.astype(promoted)
+            centers0 = self._cluster_centers.larray.astype(promoted)
+            # the whole-fit program and the readback that waits for it
+            with diagnostics.span("cluster.fit.lloyd", x) if on else diagnostics.NO_SPAN:
+                n_iter, centers, labels, inertia = self._lloyd_fn(x)(xv, centers0)
+                self._n_iter = int(n_iter)
+            self._cluster_centers = ht.array(
+                centers.astype(centers0.dtype), comm=x.comm
+            )
+            from ..core._operations import wrap_result
 
-        self._labels = wrap_result(labels.astype(jnp.int64), x, x.split)
-        self._inertia = float(inertia)
-        return self
+            self._labels = wrap_result(labels.astype(jnp.int64), x, x.split)
+            self._inertia = float(inertia)
+            return self
 
     def _lloyd_fn(self, x: DNDarray):
         """The jitted whole-fit Lloyd program, cached per
@@ -232,4 +237,5 @@ class _KCluster(ClusteringMixin, BaseEstimator):
         """Nearest learned centroid for each sample (reference ``_kcluster.py:298``)."""
         if self._cluster_centers is None:
             raise RuntimeError("fit needs to be called before predict")
-        return self._assign_to_cluster(x)
+        with diagnostics.span("cluster.predict", x) if diagnostics._enabled else diagnostics.NO_SPAN:
+            return self._assign_to_cluster(x)

@@ -1256,17 +1256,10 @@ class _Program:
             # host-side timing only (never inside the traced body — the HLO
             # parity contract): the first call spans trace + XLA compile +
             # first execution, replays span C++ dispatch
+            # (with diagnostics on, the scope is also the host span
+            # ``compile`` / ``execute`` in the profiler's own trace)
             with profiler.scope("compile" if first else "execute",
                                 self.label or "program"):
-                if diagnostics._tracing:
-                    with jax.profiler.TraceAnnotation(
-                        f"ht.dispatch:{self.label or 'program'}"
-                    ):
-                        out = fn(*args)
-                else:
-                    out = fn(*args)
-        elif diagnostics._tracing:
-            with jax.profiler.TraceAnnotation(f"ht.dispatch:{self.label or 'program'}"):
                 out = fn(*args)
         else:
             out = fn(*args)

@@ -8,8 +8,14 @@ this; the TPU-native stack carries its own instrumentation so device traces and
 run artifacts explain themselves. This module is the registry those hooks
 report into:
 
-- **Counters & spans** — :func:`counter` named tallies; :func:`span` wall-clock
-  aggregation (count / total / max seconds per name).
+- **Counters & spans** — :func:`counter` named tallies; :func:`span`, the
+  program's ONE host-span primitive: a ``jax.profiler.TraceAnnotation``
+  ``ht.<name>`` on the profiler trace's own clock (beside the device's
+  ``XLA Ops`` line), a per-thread stack that gives every span its parent and
+  its self time, per-name aggregates (count / inclusive / self / max seconds,
+  also flat in ``report()["counters"]`` as ``span_n.`` / ``span_s.`` /
+  ``span_self_s.<name>``), and backend compiles attributed to the innermost
+  open span (``compile_n.`` / ``compile_s.<span>``).
 - **Collective telemetry** — every ``MeshCommunication`` collective (``psum`` …
   ``scatter``, plus ``shard`` and ``_pad_reshard``) records (op name, mesh axis,
   participant count, logical bytes moved). Collectives called inside a traced
@@ -50,11 +56,12 @@ calls, never on a compute path.
 Env knobs (read once at import)
 -------------------------------
 - ``HEAT_TPU_METRICS=1``   — start with metrics collection enabled.
-- ``HEAT_TPU_TRACE=1``     — start with tracing enabled: ``jax.named_scope``
-  framework-level op names compiled into program metadata (visible in XLA
-  device traces / HLO dumps) and ``jax.profiler.TraceAnnotation`` host spans
-  around compile + dispatch. Programs cached before the flag flips keep their
-  old annotations — ``clear_executor_cache()`` forces a re-trace.
+- ``HEAT_TPU_TRACE=1``     — start with tracing enabled. Trace-time meaning
+  only: ``jax.named_scope`` framework-level op names compiled into program
+  metadata (visible in XLA device traces / HLO dumps). Host spans are
+  :func:`span`'s, under ``HEAT_TPU_METRICS``. Programs cached before the flag
+  flips keep their old annotations — ``clear_executor_cache()`` forces a
+  re-trace.
 - ``HEAT_TPU_DIAG_DUMP=path`` — dump the full JSON report to ``path`` at
   interpreter exit (the CI tier-1 artifact).
 - ``HEAT_TPU_DIAG_LOG=path``  — append backend-health transitions to ``path``
@@ -62,7 +69,9 @@ Env knobs (read once at import)
 
 This module deliberately imports only the stdlib at top level (the
 import-contract rule of ``ht.analysis``), so tooling can load it by file path
-without JAX.
+without JAX; :func:`span` imports ``jax.profiler`` / ``jax.monitoring`` at the
+first span entered while enabled (without JAX it aggregates and annotates
+nothing).
 
 Thread-safety (audited for the multi-threaded serving harness)
 --------------------------------------------------------------
@@ -83,8 +92,9 @@ than locked:
   OUTSIDE the lock (a slow disk must not stall telemetry); interleaved lines
   from two processes are whole-line atomic on POSIX appends of this size;
 - the late-bound collaborator hooks (``_atomic_writer``, ``_resilience_tee``,
-  ``_fallback_tee``) are written exactly once at their owning module's import
-  and read bare afterwards; tee invocations happen OUTSIDE ``_lock`` so the
+  ``_fallback_tee``, ``_request_id``, and ``_annotation``, which :func:`span`
+  binds at first use) are written exactly once and read bare afterwards; the
+  stack of open spans is per thread (``_open``) and needs no lock; tee invocations happen OUTSIDE ``_lock`` so the
   flight-recorder ring's lock stays strictly below this one;
 - the executor's ``_stats`` tallies (in :mod:`_executor`) are PER-THREAD
   accumulator cells merged at report time: increments stay lock-free on the
@@ -102,6 +112,7 @@ import atexit
 import contextlib
 import json
 import os
+import sys
 import threading
 import time
 from collections import deque
@@ -116,6 +127,7 @@ __all__ = [
     "report",
     "dump",
     "span",
+    "NO_SPAN",
     "counter",
     "record_collective",
     "record_compile",
@@ -173,6 +185,17 @@ _atomic_writer: Optional[Callable[..., Any]] = None
 _resilience_tee: Optional[Callable[[str, str, str], None]] = None
 _fallback_tee: Optional[Callable[[str, str], None]] = None
 _forensics_tee: Optional[Callable[[str, str, str], None]] = None
+# ``_request_id`` is ``profiler.current_request`` (set at the profiler's
+# import): the ambient request id a span's annotation carries as ``req=``.
+_request_id: Optional[Callable[[], Optional[int]]] = None
+# ``jax.profiler.TraceAnnotation`` once :func:`_bind_jax` has run (``False``
+# where JAX cannot be imported), the tracer type that marks an operand met while
+# tracing, and the open spans of each thread, innermost last.
+_annotation: Any = None
+_tracer: Any = ()  # jax.core.Tracer, bound with it
+_open = threading.local()
+
+_COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
 
 
 def _utcnow() -> str:
@@ -181,8 +204,9 @@ def _utcnow() -> str:
 
 # ------------------------------------------------------------------ switches
 def enable(trace: Optional[bool] = None) -> None:
-    """Turn on metrics collection; ``trace=True`` additionally turns on trace
-    annotations (``trace=False`` turns them off, ``None`` leaves them as-is).
+    """Turn on metrics collection and host spans; ``trace=True`` additionally
+    turns on the trace-time ``jax.named_scope`` names (``trace=False`` turns
+    them off, ``None`` leaves them as-is).
 
     Tracing affects programs at *trace* time: executables cached while tracing
     was off keep their unannotated HLO until ``clear_executor_cache()``."""
@@ -190,6 +214,8 @@ def enable(trace: Optional[bool] = None) -> None:
     _enabled = True
     if trace is not None:
         _tracing = bool(trace)
+    if _annotation is None and "jax" in sys.modules:
+        _bind_jax()  # compiles are attributed from here on, also outside any span
 
 
 def disable(trace: Optional[bool] = None) -> None:
@@ -207,7 +233,7 @@ def enabled() -> bool:
 
 
 def tracing() -> bool:
-    """Whether trace annotations (named_scope / TraceAnnotation) are on."""
+    """Whether trace-time ``jax.named_scope`` names are compiled into programs."""
     return _tracing
 
 
@@ -244,25 +270,115 @@ def counter(name: str, value: float = 1) -> None:
         _counters[name] = _counters.get(name, 0) + value
 
 
-@contextlib.contextmanager
-def span(name: str):
-    """Time a ``with`` block into the span registry: per-name count / total
-    seconds / max seconds. No-op (and near-free) while disabled."""
-    if not _enabled:
-        yield
+NO_SPAN = contextlib.nullcontext()  # what :func:`span` is while disabled, shared
+
+
+def _bind_jax() -> None:
+    """First enabled span: bind ``jax.profiler.TraceAnnotation`` and register
+    the one compile-duration listener (JAX has no public way to take a
+    listener off again, so it stays and reads ``_enabled`` itself)."""
+    global _annotation, _tracer
+    with _lock:
+        if _annotation is None:
+            try:
+                import jax.monitoring
+                import jax.profiler
+            except ImportError:  # standalone tooling without JAX
+                _annotation = False
+            else:
+                jax.monitoring.register_event_duration_secs_listener(_on_duration)
+                _tracer = jax.core.Tracer
+                _annotation = jax.profiler.TraceAnnotation
+
+
+def _on_duration(event: str, seconds: float, **_kw) -> None:
+    """Which span compiled: each backend compile of this thread lands on the
+    innermost span open on it (``none`` outside every span)."""
+    if not _enabled or event != _COMPILE_EVENT:
         return
-    t0 = time.perf_counter()
-    try:
-        yield
-    finally:
-        dt = time.perf_counter() - t0
+    stack = getattr(_open, "stack", None)
+    name = stack[-1].name if stack else "none"
+    with _lock:
+        for key, value in ((f"compile_n.{name}", 1), (f"compile_s.{name}", seconds)):
+            _counters[key] = _counters.get(key, 0) + value
+
+
+class _Span:
+    """One open host span (see :func:`span`)."""
+
+    __slots__ = ("name", "label", "t0", "child_s", "ann")
+
+    def __init__(self, name: str, label: str):
+        self.name = name
+        self.label = label
+        self.child_s = 0.0
+        self.ann = None
+
+    def __enter__(self):
+        stack = getattr(_open, "stack", None)
+        if stack is None:
+            stack = _open.stack = []
+        stack.append(self)
+        if _annotation:  # bound by span(); False without JAX
+            rid = _request_id() if _request_id is not None else None
+            self.ann = (_annotation(f"ht.{self.label}") if rid is None
+                        else _annotation(f"ht.{self.label}", req=rid))
+            self.ann.__enter__()
+        self.t0 = time.perf_counter()
+        return self
+
+    def __exit__(self, *exc):
+        dt = time.perf_counter() - self.t0
+        if self.ann is not None:
+            self.ann.__exit__(*exc)
+        stack = _open.stack
+        if stack[-1] is self:
+            stack.pop()
+        else:  # closed out of order (a generator resumed late)
+            stack.remove(self)
+        if stack:
+            stack[-1].child_s += dt
+        name = self.name
         with _lock:
             agg = _spans.get(name)
             if agg is None:
-                agg = _spans[name] = {"count": 0, "total_s": 0.0, "max_s": 0.0}
+                agg = _spans[name] = {"count": 0, "total_s": 0.0, "self_s": 0.0,
+                                      "max_s": 0.0}
             agg["count"] += 1
             agg["total_s"] += dt
+            agg["self_s"] += max(0.0, dt - self.child_s)
             agg["max_s"] = max(agg["max_s"], dt)
+        return False
+
+
+def span(name: str, operand: Any = None, label: Optional[str] = None):
+    """The program's one host span: ``with diagnostics.span("cluster.predict"):``.
+
+    Disabled (the default) this is one attribute read and a shared no-op.
+    Enabled, the block (a) is a ``jax.profiler.TraceAnnotation`` named
+    ``ht.<label or name>`` carrying the ambient request id as ``req=``, so it
+    lies in the profiler's own trace on the clock of the device's operations;
+    (b) knows its parent, the span open on this thread when it was entered,
+    and so its self time: its duration less what its child spans cover; (c) is
+    aggregated under ``name``: ``report()["spans"][name]`` holds count /
+    total_s / self_s / max_s, and ``report()["counters"]`` the same flat, as
+    ``span_n.<name>`` / ``span_s.<name>`` / ``span_self_s.<name>``, so that a
+    reader can take window deltas. Backend compiles inside the block count
+    towards ``compile_n.<name>`` / ``compile_s.<name>`` of the innermost span.
+
+    Host side only, never inside a traced body. An entry point that can also
+    be reached while tracing (``Module.__call__`` under a jitted training step)
+    passes the array it was given as ``operand`` (a DNDarray is looked through
+    to its ``larray``): a tracer there opens no span. Call sites on hot paths
+    gate on ``diagnostics._enabled`` themselves and enter :data:`NO_SPAN`
+    otherwise."""
+    if not _enabled:
+        return NO_SPAN
+    if _annotation is None:
+        _bind_jax()
+    if operand is not None and isinstance(getattr(operand, "larray", operand), _tracer):
+        return NO_SPAN
+    return _Span(name, label or name)
 
 
 def record_collective(op: str, axis: Any, participants: int, nbytes: int) -> None:
@@ -380,6 +496,16 @@ def record_backend_event(up: bool, detail: str = "") -> dict:
 
 
 # ------------------------------------------------------------------ reporting
+def _flat_counters_locked() -> Dict[str, float]:
+    # callers hold _lock; the field leads the name so that a prefix selects one field
+    flat = dict(_counters)
+    for name, agg in _spans.items():
+        flat[f"span_n.{name}"] = agg["count"]
+        flat[f"span_s.{name}"] = agg["total_s"]
+        flat[f"span_self_s.{name}"] = agg["self_s"]
+    return flat
+
+
 def report() -> dict:
     """The full structured snapshot — the JSON schema documented in
     ``doc/source/observability.rst``."""
@@ -389,7 +515,7 @@ def report() -> dict:
             "generated_at": _utcnow(),
             "enabled": _enabled,
             "tracing": _tracing,
-            "counters": dict(_counters),
+            "counters": _flat_counters_locked(),
             "spans": {k: dict(v) for k, v in _spans.items()},
             "collectives": [
                 {

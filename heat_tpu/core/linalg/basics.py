@@ -17,7 +17,7 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
-from .. import _operations, factories, sanitation, types
+from .. import _operations, diagnostics, factories, sanitation, types
 from ..communication import get_comm
 from ..dndarray import DNDarray
 from ..stride_tricks import sanitize_axis
@@ -100,29 +100,30 @@ def matmul(
     default (:func:`_contraction_precision`): full-f32 passes for float32 operands,
     the MXU-native fast path for bf16/f16.
     """
-    sanitation.sanitize_in(a)
-    sanitation.sanitize_in(b)
-    precision = _contraction_precision(precision, a, b)
-    planned = comm_plan.try_matmul(a, b, precision)
-    if planned is not NotImplemented:
-        return planned
-    result = jnp.matmul(a.larray, b.larray, precision=precision)
-    nd_out = result.ndim
-    # position of a's row dim / b's col dim in the output (absent for 1-D operands)
-    row_dim = nd_out - (2 if b.ndim >= 2 else 1) if a.ndim >= 2 else None
-    col_dim = nd_out - 1 if b.ndim >= 2 else None
-    split = None
-    if a.ndim >= 2 and a.split == a.ndim - 2 and row_dim is not None and row_dim >= 0:
-        split = row_dim
-    elif b.ndim >= 2 and b.split == b.ndim - 1 and col_dim is not None and col_dim >= 0:
-        split = col_dim
-    elif a.split is not None and a.ndim >= 2 and a.split < a.ndim - 2:
-        split = a.split  # batch dim
-    elif b.split is not None and b.ndim >= 2 and b.split < b.ndim - 2:
-        split = b.split
-    if nd_out == 0:
+    with diagnostics.span("linalg.matmul", a) if diagnostics._enabled else diagnostics.NO_SPAN:
+        sanitation.sanitize_in(a)
+        sanitation.sanitize_in(b)
+        precision = _contraction_precision(precision, a, b)
+        planned = comm_plan.try_matmul(a, b, precision)
+        if planned is not NotImplemented:
+            return planned
+        result = jnp.matmul(a.larray, b.larray, precision=precision)
+        nd_out = result.ndim
+        # position of a's row dim / b's col dim in the output (absent for 1-D operands)
+        row_dim = nd_out - (2 if b.ndim >= 2 else 1) if a.ndim >= 2 else None
+        col_dim = nd_out - 1 if b.ndim >= 2 else None
         split = None
-    return _wrap_like(result, a, split)
+        if a.ndim >= 2 and a.split == a.ndim - 2 and row_dim is not None and row_dim >= 0:
+            split = row_dim
+        elif b.ndim >= 2 and b.split == b.ndim - 1 and col_dim is not None and col_dim >= 0:
+            split = col_dim
+        elif a.split is not None and a.ndim >= 2 and a.split < a.ndim - 2:
+            split = a.split  # batch dim
+        elif b.split is not None and b.ndim >= 2 and b.split < b.ndim - 2:
+            split = b.split
+        if nd_out == 0:
+            split = None
+        return _wrap_like(result, a, split)
 
 
 def dot(

@@ -189,7 +189,8 @@ def try_matmul(a: DNDarray, b: DNDarray, precision) -> Any:
     ``NotImplemented`` for the caller's XLA-SPMD path (plan ``xla``, an
     unplannable pair, or a staged path still warming up / quarantined —
     the executed plan is what gets recorded)."""
-    plan = plan_matmul(a, b)
+    with diagnostics.span("linalg.plan", a) if diagnostics._enabled else diagnostics.NO_SPAN:
+        plan = plan_matmul(a, b)
     if plan is None:
         return NotImplemented
     if plan.kind != "xla":

@@ -170,6 +170,8 @@ _current_deadline: "contextvars.ContextVar[Optional[float]]" = contextvars.Conte
 )
 _deadline_seen: bool = False
 
+_NO_SPAN = contextlib.nullcontext()  # request/scope while diagnostics is off
+
 # Late-bound forensics module (set once at `heat_tpu.core.forensics` import,
 # read bare afterwards — the diagnostics tee pattern). While the forensics
 # plane is armed, `request()` runs "lite-active": it allocates a request id
@@ -500,6 +502,11 @@ def request(tag: str, deadline_s: Optional[float] = None):
     is a lifecycle contract, not telemetry: it is armed even while the
     profiler is disabled.
 
+    While ``diagnostics._enabled`` the scope is also the host span
+    ``request.<tag>`` (:func:`diagnostics.span`: on the profiler trace's clock,
+    parent of every span the request's entry points open on this thread),
+    whether or not this module is collecting.
+
     While the forensics plane is armed the scope runs "lite-active" even with
     the profiler disabled: a request id is allocated and threaded (so tenant
     attribution and the lifecycle record work) and the forensic record is
@@ -511,9 +518,12 @@ def request(tag: str, deadline_s: Optional[float] = None):
         dtoken = _current_deadline.set(time.monotonic() + float(deadline_s))
     f = _forensics
     fon = f is not None and f._enabled
+    d = diagnostics
+    span = d.span(f"request.{tag}") if d is not None and d._enabled else _NO_SPAN
     if not _active and not fon:
         try:
-            yield None
+            with span:
+                yield None
         finally:
             if dtoken is not None:
                 _current_deadline.reset(dtoken)
@@ -528,7 +538,8 @@ def request(tag: str, deadline_s: Optional[float] = None):
     if fon:
         f.begin_request(rid, str(tag), _current_deadline.get())
     try:
-        yield rid
+        with span:  # entered after the request id is ambient: it carries req=rid
+            yield rid
     finally:
         _current_request.reset(token)
         if dtoken is not None:
@@ -558,9 +569,18 @@ def scope(cat: str, name: str, req: Optional[int] = None):
     Yields a control handle: setting ``handle["keep"] = False`` before the
     block exits discards the slice. The executor uses this for a force that
     lost the plan race and had nothing to execute — recording it would put a
-    phantom empty ``force`` on the timeline."""
+    phantom empty ``force`` on the timeline.
+
+    While ``diagnostics._enabled`` the block is also a host span aggregated by
+    category (``diagnostics.span(cat)``; its annotation reads ``ht.<cat>:<name>``),
+    which puts dispatch, compile/execute, queue wait, force and the trace-time
+    collectives on the profiler trace's clock."""
+    d = diagnostics
+    span = (d.span(str(cat), label=f"{cat}:{name}") if d is not None and d._enabled
+            else _NO_SPAN)
     if not _active:
-        yield {"keep": True}
+        with span:
+            yield {"keep": True}
         return
     token = None
     if req is not None and _current_request.get() is None:
@@ -569,7 +589,8 @@ def scope(cat: str, name: str, req: Optional[int] = None):
     ctl = {"keep": True}
     t0 = _now_us()
     try:
-        yield ctl
+        with span:
+            yield ctl
     finally:
         t1 = _now_us()
         if ctl["keep"]:
@@ -778,6 +799,7 @@ def dump_trace(path: str) -> dict:
 # diagnostics instance to report into.)
 if diagnostics is not None:
     diagnostics.register_provider("profiler", report)
+    diagnostics._request_id = current_request  # the req= of every host span
 
 
 # ------------------------------------------------------------------ env bootstrap

@@ -20,7 +20,7 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
-from . import _operations, sanitation, types
+from . import _operations, diagnostics, sanitation, types
 from .dndarray import DNDarray
 from .stride_tricks import sanitize_axis
 
@@ -54,19 +54,20 @@ _handle_out = _operations.handle_out
 
 def _arg_reduce(op, x: DNDarray, axis, out, keepdims: bool) -> DNDarray:
     """Shared argmax/argmin logic (reference custom MPI ops ``statistics.py:1370-1405``)."""
-    sanitation.sanitize_in(x)
-    if axis is None:
-        result = op(x.larray.reshape(-1)).astype(jnp.int64)
-        if keepdims:
-            result = result.reshape((1,) * x.ndim)
-        out_split = None
-    else:
-        axis = sanitize_axis(x.gshape, axis)
-        result = op(x.larray, axis=axis).astype(jnp.int64)
-        if keepdims:
-            result = jnp.expand_dims(result, axis)
-        out_split = _operations._out_split_reduce(x, axis, keepdims)
-    return _handle_out(_wrap(result, x, out_split), out, x)
+    with diagnostics.span("statistics.argreduce", x) if diagnostics._enabled else diagnostics.NO_SPAN:
+        sanitation.sanitize_in(x)
+        if axis is None:
+            result = op(x.larray.reshape(-1)).astype(jnp.int64)
+            if keepdims:
+                result = result.reshape((1,) * x.ndim)
+            out_split = None
+        else:
+            axis = sanitize_axis(x.gshape, axis)
+            result = op(x.larray, axis=axis).astype(jnp.int64)
+            if keepdims:
+                result = jnp.expand_dims(result, axis)
+            out_split = _operations._out_split_reduce(x, axis, keepdims)
+        return _handle_out(_wrap(result, x, out_split), out, x)
 
 
 def argmax(x: DNDarray, axis: Optional[int] = None, out: Optional[DNDarray] = None, keepdims: bool = False) -> DNDarray:

@@ -18,6 +18,7 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
+from ..core import diagnostics
 from ..core.dndarray import DNDarray
 
 __all__ = [
@@ -172,25 +173,26 @@ class Module:
         return key, train
 
     def __call__(self, x, *, key=None, train: Optional[bool] = None):
-        key, train = self._resolve_ctx(key, train)
-        value = self.apply(self.params, _to_value(x), key=key, train=train)
-        if isinstance(x, DNDarray) and not isinstance(value, DNDarray):
-            from ..core._operations import wrap_result
+        with diagnostics.span("nn.forward", x) if diagnostics._enabled else diagnostics.NO_SPAN:
+            key, train = self._resolve_ctx(key, train)
+            value = self.apply(self.params, _to_value(x), key=key, train=train)
+            if isinstance(x, DNDarray) and not isinstance(value, DNDarray):
+                from ..core._operations import wrap_result
 
-            # a split survives whenever its axis still exists with the same
-            # global extent (batch through convs/embedding, sequence through
-            # norms/linear); axes the op consumed or resized fall back to
-            # replicated. split is a layout over a global array, so a
-            # false-positive keep is a layout choice, never wrong data.
-            keep = None
-            if (
-                x.split is not None
-                and x.split < value.ndim
-                and value.shape[x.split] == x.shape[x.split]
-            ):
-                keep = x.split
-            return wrap_result(value, x, keep)
-        return value
+                # a split survives whenever its axis still exists with the same
+                # global extent (batch through convs/embedding, sequence through
+                # norms/linear); axes the op consumed or resized fall back to
+                # replicated. split is a layout over a global array, so a
+                # false-positive keep is a layout choice, never wrong data.
+                keep = None
+                if (
+                    x.split is not None
+                    and x.split < value.ndim
+                    and value.shape[x.split] == x.shape[x.split]
+                ):
+                    keep = x.split
+                return wrap_result(value, x, keep)
+            return value
 
 
 class Linear(Module):

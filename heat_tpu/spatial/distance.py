@@ -19,7 +19,7 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
-from ..core import types
+from ..core import diagnostics, types
 from ..core._operations import wrap_result
 from ..core.dndarray import DNDarray
 from ..core.sanitation import sanitize_in
@@ -97,39 +97,40 @@ def _dist(X: DNDarray, Y: Optional[DNDarray], metric: str) -> DNDarray:
     both-row-split case runs the explicit :func:`_ring_pairwise` schedule when the
     shapes divide the mesh evenly (falling back to the SPMD-global formulation
     otherwise)."""
-    sanitize_in(X)
-    if X.ndim != 2:
-        raise NotImplementedError(f"X should be 2D, but is {X.ndim}D")
-    promoted = types.promote_types(X.dtype, types.float32)
-    xv = X.larray.astype(promoted.jax_type())
-    if Y is None:
-        y_split = X.split
-        yv = xv
-    else:
-        sanitize_in(Y)
-        if Y.ndim != 2:
-            raise NotImplementedError(f"Y should be 2D, but is {Y.ndim}D")
-        p2 = types.promote_types(Y.dtype, types.float32)
-        if p2 is not promoted:
-            promoted = types.promote_types(promoted, p2)
-            xv = xv.astype(promoted.jax_type())
-        y_split = Y.split
-        yv = Y.larray.astype(promoted.jax_type())
-    comm = X.comm
-    use_ring = (
-        X.split == 0
-        and y_split == 0
-        and X.is_distributed()
-        and not getattr(comm, "is_hierarchical", False)
-        and xv.shape[0] % comm.size == 0
-        and yv.shape[0] % comm.size == 0
-    )
-    if use_ring:
-        result = _ring_pairwise(comm, xv, yv, metric)
-    else:
-        result = _pairwise(xv, yv, metric)
-    out_split = 0 if X.split == 0 else (1 if y_split == 0 else None)
-    return wrap_result(result, X, out_split)
+    with diagnostics.span("spatial.cdist", X) if diagnostics._enabled else diagnostics.NO_SPAN:
+        sanitize_in(X)
+        if X.ndim != 2:
+            raise NotImplementedError(f"X should be 2D, but is {X.ndim}D")
+        promoted = types.promote_types(X.dtype, types.float32)
+        xv = X.larray.astype(promoted.jax_type())
+        if Y is None:
+            y_split = X.split
+            yv = xv
+        else:
+            sanitize_in(Y)
+            if Y.ndim != 2:
+                raise NotImplementedError(f"Y should be 2D, but is {Y.ndim}D")
+            p2 = types.promote_types(Y.dtype, types.float32)
+            if p2 is not promoted:
+                promoted = types.promote_types(promoted, p2)
+                xv = xv.astype(promoted.jax_type())
+            y_split = Y.split
+            yv = Y.larray.astype(promoted.jax_type())
+        comm = X.comm
+        use_ring = (
+            X.split == 0
+            and y_split == 0
+            and X.is_distributed()
+            and not getattr(comm, "is_hierarchical", False)
+            and xv.shape[0] % comm.size == 0
+            and yv.shape[0] % comm.size == 0
+        )
+        if use_ring:
+            result = _ring_pairwise(comm, xv, yv, metric)
+        else:
+            result = _pairwise(xv, yv, metric)
+        out_split = 0 if X.split == 0 else (1 if y_split == 0 else None)
+        return wrap_result(result, X, out_split)
 
 
 def cdist(X: DNDarray, Y: Optional[DNDarray] = None, quadratic_expansion: bool = False) -> DNDarray:

@@ -523,3 +523,290 @@ class TestThreadSafety(_DiagTestCase):
             t.join()
         for i in range(4):
             diagnostics._providers.pop(f"_hammer_{i}", None)
+
+
+class _RecordedAnnotation:
+    """Stands in for ``jax.profiler.TraceAnnotation``: what was opened inside what."""
+
+    def __init__(self):
+        self.events = []  # (name, parent name or None, keyword arguments), in entry order
+        self._open = {}
+
+    def __call__(self, name, **kwargs):
+        recorder = self
+
+        class _Annotation:
+            def __enter__(self):
+                import threading
+
+                stack = recorder._open.setdefault(threading.get_ident(), [])
+                recorder.events.append((name, stack[-1] if stack else None, kwargs))
+                stack.append(name)
+
+            def __exit__(self, *exc):
+                import threading
+
+                recorder._open[threading.get_ident()].pop()
+
+        return _Annotation()
+
+    def parents(self, name):
+        return {parent for n, parent, _ in self.events if n == name}
+
+
+@contextlib.contextmanager
+def recorded_annotations():
+    from unittest import mock
+
+    diagnostics._bind_jax()  # the real binding first, so that the fake is not replaced
+    recorder = _RecordedAnnotation()
+    with mock.patch.object(diagnostics, "_annotation", recorder):
+        yield recorder
+
+
+class TestHostSpans(_DiagTestCase):
+    """ISSUE 25: ``diagnostics.span`` is the program's one host span — an annotation in
+    the profiler's own trace, a per-thread stack (parent, self time), flat per-name
+    aggregates and compile attribution; free and silent while disabled."""
+
+    def test_nesting_and_self_time(self):
+        import time
+
+        with metrics():
+            diagnostics.reset()
+            with diagnostics.span("outer"):
+                time.sleep(0.02)
+                for _ in range(2):
+                    with diagnostics.span("inner"):
+                        time.sleep(0.03)
+            spans = diagnostics.report()["spans"]
+        outer, inner = spans["outer"], spans["inner"]
+        self.assertEqual((outer["count"], inner["count"]), (1, 2))
+        self.assertGreaterEqual(inner["total_s"], 0.06)
+        self.assertAlmostEqual(inner["self_s"], inner["total_s"])  # no child of its own
+        self.assertGreaterEqual(outer["total_s"], inner["total_s"] + 0.02)
+        # self time is the duration less what the child spans cover, to the clock's digit
+        self.assertAlmostEqual(outer["self_s"], outer["total_s"] - inner["total_s"], places=9)
+        self.assertGreaterEqual(outer["self_s"], 0.02)
+        self.assertLess(outer["self_s"], 0.05)  # the children's 60 ms are not in it
+        self.assertGreaterEqual(inner["max_s"], 0.03)
+
+    def test_flat_counters_and_their_window_deltas(self):
+        with metrics():
+            diagnostics.reset()
+            for _ in range(3):
+                with diagnostics.span("win.a"):
+                    pass
+            before = diagnostics.report()["counters"]
+            for _ in range(5):
+                with diagnostics.span("win.a"):
+                    with diagnostics.span("win.b"):
+                        pass
+            after = diagnostics.report()["counters"]
+            spans = diagnostics.report()["spans"]
+        self.assertEqual(before["span_n.win.a"], 3)
+        self.assertNotIn("span_n.win.b", before)
+        delta = {k: after[k] - before.get(k, 0) for k in after}
+        self.assertEqual(delta["span_n.win.a"], 5)
+        self.assertEqual(delta["span_n.win.b"], 5)
+        self.assertGreater(delta["span_s.win.a"], delta["span_self_s.win.a"])
+        self.assertAlmostEqual(delta["span_s.win.a"] - delta["span_self_s.win.a"],
+                               delta["span_s.win.b"], places=9)
+        # the field leads the key, so a prefix selects one field of every span
+        self.assertEqual({k for k in after if k.startswith("span_n.")},
+                         {"span_n.win.a", "span_n.win.b"})
+        self.assertEqual(set(spans["win.a"]), {"count", "total_s", "self_s", "max_s"})
+        self.assertEqual(after["span_s.win.a"], spans["win.a"]["total_s"])
+
+    def test_exact_counts_and_parents_under_four_threads(self):
+        import threading
+        import time
+
+        n_threads, n_iters = 4, 200
+        errors = []
+
+        def work(slot):
+            try:
+                for _ in range(n_iters):
+                    with diagnostics.span("mt.outer"):
+                        with diagnostics.span(f"mt.inner.{slot}"):
+                            time.sleep(0)
+            except Exception as exc:  # pragma: no cover - failure path
+                errors.append(exc)
+
+        with metrics(), recorded_annotations() as seen:
+            diagnostics.reset()
+            threads = [threading.Thread(target=work, args=(s,)) for s in range(n_threads)]
+            for t in threads:
+                t.start()
+            for t in threads:
+                t.join(timeout=60)
+            self.assertFalse(any(t.is_alive() for t in threads))
+            spans = diagnostics.report()["spans"]
+        self.assertEqual(errors, [])
+        self.assertEqual(spans["mt.outer"]["count"], n_threads * n_iters)
+        inner_total = 0.0
+        for slot in range(n_threads):
+            self.assertEqual(spans[f"mt.inner.{slot}"]["count"], n_iters)
+            # the stack is per thread: a span's parent is its own thread's outer
+            self.assertEqual(seen.parents(f"ht.mt.inner.{slot}"), {"ht.mt.outer"})
+            inner_total += spans[f"mt.inner.{slot}"]["total_s"]
+        self.assertEqual(seen.parents("ht.mt.outer"), {None})
+        self.assertAlmostEqual(spans["mt.outer"]["total_s"] - spans["mt.outer"]["self_s"],
+                               inner_total, places=6)
+
+    def test_disabled_is_one_shared_no_op(self):
+        diagnostics.disable()
+        diagnostics.reset()
+        with recorded_annotations() as seen:
+            self.assertIs(diagnostics.span("off.a"), diagnostics.NO_SPAN)
+            with diagnostics.span("off.a"):
+                with diagnostics.span("off.b", operand=jnp.ones(2)):
+                    pass
+            x = ht.array(np.arange(12, dtype=np.float32).reshape(6, 2), split=0)
+            ht.argmin(ht.spatial.cdist(x, x), axis=1).parray
+        rep = diagnostics.report()
+        self.assertEqual(seen.events, [])
+        self.assertEqual(rep["spans"], {})
+        self.assertEqual(rep["counters"], {})
+
+    def test_entry_point_under_jit_opens_no_span_and_leaves_hlo_alone(self):
+        model = ht.nn.Sequential(ht.nn.Linear(4, 3), ht.nn.ReLU(), ht.nn.Linear(3, 2))
+        model.reset_parameters(1)
+        v = jnp.ones((5, 4), jnp.float32)
+
+        def lowered():
+            return program_text(jax.jit(lambda t: model(t)).lower(v).compile())
+
+        diagnostics.disable()
+        off = lowered()
+        with metrics(), recorded_annotations() as seen:
+            diagnostics.reset()
+            on = lowered()
+            traced = diagnostics.report()["spans"]
+            model(v)  # the same call on a concrete array does open one
+            eager = diagnostics.report()["spans"]
+        self.assertEqual(on, off, "host spans changed compiled HLO")
+        self.assertNotIn("nn.forward", traced)
+        self.assertEqual(eager["nn.forward"]["count"], 1)
+        self.assertEqual([n for n, _, _ in seen.events], ["ht.nn.forward"])
+
+    def test_compile_is_attributed_to_the_innermost_span(self):
+        v = jnp.arange(7, dtype=jnp.float32)
+        with metrics():
+            diagnostics.reset()
+            with diagnostics.span("cmp.outer"):
+                with diagnostics.span("cmp.inner"):
+                    jax.jit(lambda t: t * 3.0 + 25.0)(v).block_until_ready()
+                with diagnostics.span("cmp.quiet"):
+                    pass
+            jax.jit(lambda t: t * 5.0 - 25.0)(v).block_until_ready()
+            counters = diagnostics.report()["counters"]
+        self.assertGreaterEqual(counters["compile_n.cmp.inner"], 1)
+        self.assertGreater(counters["compile_s.cmp.inner"], 0.0)
+        self.assertGreaterEqual(counters["compile_n.none"], 1)
+        self.assertNotIn("compile_n.cmp.outer", counters)
+        self.assertNotIn("compile_n.cmp.quiet", counters)
+        diagnostics.disable()
+        diagnostics.reset()
+        jax.jit(lambda t: t * 7.0 - 25.0)(v).block_until_ready()
+        self.assertEqual(diagnostics.report()["counters"], {})  # the listener reads the switch
+
+    def test_served_entry_points_nest_as_the_table_says(self):
+        from heat_tpu.core import profiler
+
+        rng = np.random.default_rng(0)
+        x = ht.array(rng.normal(size=(48, 8)).astype(np.float32), split=0)
+        q = ht.array(rng.normal(size=(6, 8)).astype(np.float32))
+        km = ht.cluster.KMeans(n_clusters=3, init=ht.array(x.numpy()[:3]), max_iter=2, tol=-1.0)
+        model = ht.nn.Sequential(ht.nn.Linear(8, 4), ht.nn.ReLU(), ht.nn.Linear(4, 2))
+        a = ht.array(rng.normal(size=(16, 16)).astype(np.float32), split=0)
+        b = ht.array(rng.normal(size=(16, 16)).astype(np.float32), split=1)
+        with metrics(), recorded_annotations() as seen:
+            diagnostics.reset()
+            km.fit(x)
+            with profiler.request("t.kmeans"):
+                km.predict(x).parray
+            with profiler.request("t.knn"):
+                ht.argmin(ht.spatial.cdist(q, x), axis=1).parray
+            with profiler.request("t.mlp"):
+                model(x).parray
+            ht.linalg.matmul(a, b).parray
+            counters = diagnostics.report()["counters"]
+        self.assertEqual(seen.parents("ht.cluster.fit"), {None})
+        self.assertEqual(seen.parents("ht.cluster.fit.lloyd"), {"ht.cluster.fit"})
+        self.assertEqual(seen.parents("ht.cluster.predict"), {"ht.request.t.kmeans"})
+        self.assertEqual(seen.parents("ht.spatial.cdist"),
+                         {"ht.cluster.predict", "ht.request.t.knn"})
+        self.assertEqual(seen.parents("ht.statistics.argreduce"),
+                         {"ht.cluster.predict", "ht.request.t.knn"})
+        self.assertEqual(seen.parents("ht.nn.forward"), {"ht.request.t.mlp"})
+        self.assertEqual(seen.parents("ht.linalg.matmul"), {None})
+        self.assertEqual(seen.parents("ht.linalg.plan"), {"ht.linalg.matmul"})
+        for name in ("cluster.fit", "cluster.fit.lloyd", "cluster.predict", "nn.forward",
+                     "linalg.matmul", "linalg.plan", "request.t.kmeans", "request.t.knn",
+                     "request.t.mlp"):
+            self.assertEqual(counters[f"span_n.{name}"], 1, name)
+        self.assertEqual(counters["span_n.spatial.cdist"], 2)
+        self.assertEqual(counters["span_n.statistics.argreduce"], 2)
+        # what a request spends inside the entry points is never more than the request
+        for tag in ("t.kmeans", "t.knn", "t.mlp"):
+            whole = counters[f"span_s.request.{tag}"]
+            self.assertGreater(whole, whole - counters[f"span_self_s.request.{tag}"])
+            self.assertGreater(whole - counters[f"span_self_s.request.{tag}"], 0.0)
+
+    def test_spans_lie_in_the_profiler_trace_on_one_clock(self):
+        """A real ``jax.profiler`` trace: ``ht.request.*`` and its children are events
+        of the thread's own line, inside an annotation the caller opened itself."""
+        import glob
+
+        from jax.profiler import ProfileData
+
+        from heat_tpu.core import profiler
+
+        rng = np.random.default_rng(1)
+        x = ht.array(rng.normal(size=(32, 4)).astype(np.float32), split=0)
+        km = ht.cluster.KMeans(n_clusters=2, init=ht.array(x.numpy()[:2]), max_iter=2, tol=-1.0)
+        km.fit(x)
+        km.predict(x).parray  # compiled before the trace
+        options = jax.profiler.ProfileOptions()
+        options.python_tracer_level = 0
+        options.host_tracer_level = 1
+        with tempfile.TemporaryDirectory() as d, metrics():
+            was_active = profiler.active()
+            profiler.enable()  # the request id is threaded only while it collects
+            jax.profiler.start_trace(d, profiler_options=options)
+            try:
+                with jax.profiler.TraceAnnotation("bench.request:kmeans"):
+                    with profiler.request("bench.kmeans") as rid:
+                        jax.block_until_ready(km.predict(x).parray)
+            finally:
+                jax.profiler.stop_trace()
+                if not was_active:
+                    profiler.disable()
+                    profiler.reset()
+            (path,) = glob.glob(os.path.join(d, "**", "*.xplane.pb"), recursive=True)
+            profile = ProfileData.from_file(path)
+        found = {}
+        for plane in profile.planes:
+            for line in plane.lines:
+                events = {}
+                for ev in line.events:
+                    if ev.name.startswith(("bench.", "ht.")):
+                        events[ev.name] = (ev.start_ns, ev.start_ns + ev.duration_ns,
+                                           dict(ev.stats))
+                if "ht.request.bench.kmeans" in events:
+                    found = events
+        self.assertTrue(found, "no ht.request.* event in the trace")
+        nest = ["bench.request:kmeans", "ht.request.bench.kmeans", "ht.cluster.predict"]
+        for outer, inner in zip(nest, nest[1:] + ["ht.spatial.cdist"]):
+            self.assertIn(inner, found)
+            self.assertLessEqual(found[outer][0], found[inner][0], (outer, inner))
+            self.assertGreaterEqual(found[outer][1], found[inner][1], (outer, inner))
+        predict, cdist, arg = (found[n] for n in ("ht.cluster.predict", "ht.spatial.cdist",
+                                                  "ht.statistics.argreduce"))
+        self.assertLessEqual(cdist[1], arg[0])  # siblings, one after the other
+        self.assertGreaterEqual(predict[1], arg[1])
+        self.assertIsNotNone(rid)
+        for name in nest[1:] + ["ht.spatial.cdist", "ht.statistics.argreduce"]:
+            self.assertEqual(found[name][2].get("req"), rid, name)  # one request, one id

@@ -594,3 +594,102 @@ class TestProfilerHammer(_ProfTestCase):
             self, {"schema": profiler.TRACE_SCHEMA,
                    "traceEvents": profiler._trace_events_locked()},
         )
+
+
+class TestHostSpanRouting(_ProfTestCase):
+    """ISSUE 25: ``request`` and ``scope`` are also ``diagnostics.span`` host spans —
+    ``request.<tag>`` and ``<cat>`` — exactly while ``diagnostics._enabled``."""
+
+    def setUp(self):
+        super().setUp()
+        from heat_tpu.core import diagnostics
+
+        self.diagnostics = diagnostics
+        self._was_enabled = diagnostics.enabled()
+        diagnostics.disable(trace=diagnostics.tracing())
+        diagnostics.reset()
+
+    def tearDown(self):
+        self.diagnostics.reset()
+        if self._was_enabled:
+            self.diagnostics.enable()
+        else:
+            self.diagnostics.disable(trace=self.diagnostics.tracing())
+        super().tearDown()
+
+    def _annotations(self):
+        """The annotations :func:`diagnostics.span` opens, as (text, keywords) pairs."""
+        from unittest import mock
+
+        self.diagnostics._bind_jax()
+        opened = []
+
+        class Annotation:
+            def __init__(self, text, **kwargs):
+                opened.append((text, kwargs))
+
+            def __enter__(self):
+                return self
+
+            def __exit__(self, *exc):
+                return False
+
+        patch = mock.patch.object(self.diagnostics, "_annotation", Annotation)
+        patch.start()
+        self.addCleanup(patch.stop)
+        return opened
+
+    def test_request_and_scope_emit_only_when_diagnostics_is_enabled(self):
+        opened = self._annotations()
+        profiler.enable()
+        with profiler.request("quiet"):
+            with profiler.scope("dispatch", "add"):
+                pass
+        self.assertEqual(opened, [])
+        self.assertEqual(self.diagnostics.report()["spans"], {})
+        self.assertEqual(profiler.report()["slices_recorded"], 2)  # its own slices stay
+
+        self.diagnostics.enable()
+        with profiler.request("loud") as rid:
+            with profiler.scope("dispatch", "add"):
+                pass
+            with profiler.scope("dispatch", "mul"):
+                pass
+        counters = self.diagnostics.report()["counters"]
+        self.assertEqual(counters["span_n.request.loud"], 1)
+        self.assertEqual(counters["span_n.dispatch"], 2)  # aggregated by category
+        self.assertNotIn("span_n.request.quiet", counters)
+        # the annotation keeps the full cat:name, and every one carries the request id
+        self.assertEqual(opened, [("ht.request.loud", {"req": rid}),
+                                  ("ht.dispatch:add", {"req": rid}),
+                                  ("ht.dispatch:mul", {"req": rid})])
+        self.assertEqual(profiler.report()["slices_recorded"], 5)
+
+    def test_request_span_does_not_wait_for_the_profiler(self):
+        opened = self._annotations()
+        self.diagnostics.enable()
+        self.assertFalse(profiler.active())
+        with profiler.request("solo") as rid:
+            pass
+        self.assertIsNone(rid)
+        self.assertEqual(opened, [("ht.request.solo", {})])  # no ambient id to carry
+        self.assertEqual(self.diagnostics.report()["counters"]["span_n.request.solo"], 1)
+        self.assertEqual(profiler.report()["slices_recorded"], 0)
+
+    def test_executor_program_calls_are_compile_and_execute_spans(self):
+        opened = self._annotations()
+        _executor.clear_executor_cache()
+        x = ht.array(np.arange(8, dtype=np.float32), split=0)
+        profiler.enable()
+        self.diagnostics.enable()
+        with profiler.request("chain"):
+            (x + 1.0).sum().parray
+            (x + 1.0).sum().parray
+        counters = self.diagnostics.report()["counters"]
+        self.assertGreaterEqual(counters["span_n.compile"], 1)
+        self.assertGreaterEqual(counters["span_n.execute"], 1)
+        texts = [text for text, _ in opened]
+        self.assertTrue(any(t.startswith("ht.compile:") for t in texts), texts)
+        self.assertTrue(any(t.startswith("ht.execute:") for t in texts), texts)
+        # what the executor's own HEAT_TPU_TRACE branches used to write is gone
+        self.assertFalse(any(t.startswith("ht.dispatch:program") for t in texts), texts)
