@@ -8,6 +8,11 @@ the ICI ring, O(n_y/P) resident Y per device); every other split combination —
 splits, unsplit operands, ragged sizes — is the SPMD-global formulation where XLA
 inserts the gathers. Output split: row-split X → split 0; else row-split Y → split 1;
 else replicated.
+
+One call is one program: the promotion cast, either formulation, :func:`rbf`'s kernel
+and the result's layout are traced once and run as a single cached ``jax.jit``
+(:func:`_program`), so a call costs one dispatch and one write of the matrix.
+``spatial.cdist.traces`` (``ht.diagnostics``) counts the traces.
 """
 
 from __future__ import annotations
@@ -18,6 +23,7 @@ import numpy as np
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import PartitionSpec
 
 from ..core import diagnostics, types
 from ..core._operations import wrap_result
@@ -52,8 +58,6 @@ def _ring_pairwise(comm, xv: jax.Array, yv: jax.Array, metric: str) -> jax.Array
     O(n_y/P) for Y instead of the all-gathered O(n_y) the SPMD-global formulation
     materialises — the reason the reference uses a ring, preserved here.
     """
-    from jax.sharding import PartitionSpec
-
     axis = comm.axis_name
     nproc = comm.size
     ny_chunk = yv.shape[0] // nproc
@@ -89,23 +93,59 @@ def _ring_pairwise(comm, xv: jax.Array, yv: jax.Array, metric: str) -> jax.Array
     )(xv, yv)
 
 
-def _dist(X: DNDarray, Y: Optional[DNDarray], metric: str) -> DNDarray:
+# one jitted program per (metric, promoted dtype, ring mesh + axis, output sharding): the
+# trace depends on nothing else, and shapes and input shardings are jax.jit's own key
+_PROGRAMS: dict = {}
+
+
+def _program(metric: str, dtype, ring_comm, out_sharding) -> Callable:
+    """The compiled form of one :func:`_dist` call: the promotion cast, the distance
+    matrix (``ring_comm`` set: by :func:`_ring_pairwise` on that communicator) and, for
+    :func:`rbf`, the kernel, laid out as ``out_sharding`` (None: wherever XLA leaves it)."""
+    key = (
+        metric,
+        dtype,
+        None if ring_comm is None else (ring_comm.mesh, ring_comm.axis_name),
+        out_sharding,
+    )
+    fn = _PROGRAMS.get(key)
+    if fn is not None:
+        return fn
+
+    def dist(x, y, gamma):
+        if diagnostics._enabled:
+            diagnostics.counter("spatial.cdist.traces")  # trace time only
+        x = x.astype(dtype)
+        y = x if y is None else y.astype(dtype)
+        if ring_comm is not None:
+            d = _ring_pairwise(ring_comm, x, y, metric)
+        else:
+            d = _pairwise(x, y, metric)
+        return d if gamma is None else jnp.exp(-(d**2) / gamma)
+
+    return _PROGRAMS.setdefault(key, jax.jit(dist, out_shardings=out_sharding))
+
+
+def _dist(
+    X: DNDarray, Y: Optional[DNDarray], metric: str, gamma: Optional[float] = None
+) -> DNDarray:
     """Shared driver (reference ``_dist`` ``distance.py:209``).
 
     Any (X.split, Y.split) combination is accepted: split feature axes are a
     contraction XLA resolves, a row-split X yields a row-split result, and the
     both-row-split case runs the explicit :func:`_ring_pairwise` schedule when the
     shapes divide the mesh evenly (falling back to the SPMD-global formulation
-    otherwise)."""
+    otherwise). ``gamma`` set: the result is ``exp(-d²/gamma)`` (:func:`rbf`)."""
     with diagnostics.span("spatial.cdist", X) if diagnostics._enabled else diagnostics.NO_SPAN:
         sanitize_in(X)
         if X.ndim != 2:
             raise NotImplementedError(f"X should be 2D, but is {X.ndim}D")
         promoted = types.promote_types(X.dtype, types.float32)
-        xv = X.larray.astype(promoted.jax_type())
+        xv = X.larray
         if Y is None:
             y_split = X.split
-            yv = xv
+            yv = None
+            ny = xv.shape[0]
         else:
             sanitize_in(Y)
             if Y.ndim != 2:
@@ -113,9 +153,9 @@ def _dist(X: DNDarray, Y: Optional[DNDarray], metric: str) -> DNDarray:
             p2 = types.promote_types(Y.dtype, types.float32)
             if p2 is not promoted:
                 promoted = types.promote_types(promoted, p2)
-                xv = xv.astype(promoted.jax_type())
             y_split = Y.split
-            yv = Y.larray.astype(promoted.jax_type())
+            yv = Y.larray
+            ny = yv.shape[0]
         comm = X.comm
         use_ring = (
             X.split == 0
@@ -123,14 +163,18 @@ def _dist(X: DNDarray, Y: Optional[DNDarray], metric: str) -> DNDarray:
             and X.is_distributed()
             and not getattr(comm, "is_hierarchical", False)
             and xv.shape[0] % comm.size == 0
-            and yv.shape[0] % comm.size == 0
+            and ny % comm.size == 0
         )
-        if use_ring:
-            result = _ring_pairwise(comm, xv, yv, metric)
-        else:
-            result = _pairwise(xv, yv, metric)
         out_split = 0 if X.split == 0 else (1 if y_split == 0 else None)
-        return wrap_result(result, X, out_split)
+        # a ragged extent is padded by wrap_result's comm.shard, after the program
+        ragged = out_split is not None and (xv.shape[0], ny)[out_split] % comm.size != 0
+        program = _program(
+            metric,
+            promoted.jax_type(),
+            comm if use_ring else None,
+            None if ragged else comm.sharding(2, out_split),
+        )
+        return wrap_result(program(xv, yv, gamma), X, out_split)
 
 
 def cdist(X: DNDarray, Y: Optional[DNDarray] = None, quadratic_expansion: bool = False) -> DNDarray:
@@ -151,6 +195,4 @@ def rbf(
     quadratic_expansion: bool = False,
 ) -> DNDarray:
     """Gaussian RBF kernel matrix exp(-d²/(2σ²)) (reference ``distance.py:159``)."""
-    d = _dist(X, Y, "euclidean")
-    result = jnp.exp(-(d.larray**2) / (2.0 * sigma * sigma))
-    return wrap_result(result, d, d.split)
+    return _dist(X, Y, "euclidean", gamma=2.0 * sigma * sigma)
