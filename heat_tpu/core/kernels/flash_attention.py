@@ -29,7 +29,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-__all__ = ["flash_attention", "flash_attention_reference"]
+__all__ = ["flash_attention", "flash_attention_reference", "flash_forward", "forward_blocks"]
 
 _NEG_INF = float(jnp.finfo(jnp.float32).min)
 
@@ -348,20 +348,24 @@ def _pair_schedule(nq: int, nk: int, bq: int, bk: int, causal: bool):
 
 
 @functools.partial(
-    jax.jit, static_argnames=("causal", "scale", "bq", "bk", "interpret", "pipelined")
+    jax.jit,
+    static_argnames=("causal", "scale", "bq", "bk", "interpret", "pipelined", "name"),
 )
 def _flash_pallas(q, k, v, causal: bool, scale: float, bq: int, bk: int,
-                  interpret: bool = False, bias=None, pipelined: bool = False):
+                  interpret: bool = False, bias=None, pipelined: bool = False,
+                  name=None):
+    """q, k: (..., T, d); v: (..., Tk, dv) with its own width (dv != d is the latent-
+    attention case: 192 against 128). ``name`` names the Pallas call in a device trace."""
     import jax.experimental.pallas as pl  # ht: ignore[trace-lazy-import] -- pallas imports deferred so CPU-only processes never pay them; runs once per compile, imports nothing of heat_tpu
     from jax.experimental.pallas import tpu as pltpu  # ht: ignore[trace-lazy-import] -- pallas imports deferred so CPU-only processes never pay them; runs once per compile, imports nothing of heat_tpu
 
     with jax.enable_x64(False):
         *batch, tq, d = q.shape
-        tk = k.shape[-2]
+        tk, dv = k.shape[-2], v.shape[-1]
         bh = math.prod(batch) if batch else 1
         qr = q.reshape(bh, tq, d)
         kr = k.reshape(bh, tk, d)
-        vr = v.reshape(bh, tk, d)
+        vr = v.reshape(bh, tk, dv)
         has_bias = bias is not None
 
         schedule = _pair_schedule_pipelined if pipelined else _pair_schedule
@@ -371,10 +375,10 @@ def _flash_pallas(q, k, v, causal: bool, scale: float, bq: int, bk: int,
         if pipelined:
             # the exp/PV chain consumes the PREVIOUS pair's v block
             v_spec = pl.BlockSpec(
-                (1, bk, d), lambda b, p, im, jm, fl: (b, jm[jnp.maximum(p - 1, 0)], 0)
+                (1, bk, dv), lambda b, p, im, jm, fl: (b, jm[jnp.maximum(p - 1, 0)], 0)
             )
         else:
-            v_spec = pl.BlockSpec((1, bk, d), lambda b, p, im, jm, fl: (b, jm[p], 0))
+            v_spec = pl.BlockSpec((1, bk, dv), lambda b, p, im, jm, fl: (b, jm[p], 0))
         in_specs = [
             pl.BlockSpec((1, bq, d), lambda b, p, im, jm, fl: (b, im[p], 0)),
             pl.BlockSpec((1, bk, d), lambda b, p, im, jm, fl: (b, jm[p], 0)),
@@ -389,7 +393,7 @@ def _flash_pallas(q, k, v, causal: bool, scale: float, bq: int, bk: int,
             )
             inputs.append(bias.astype(jnp.float32))
         scratch_shapes = [
-            pltpu.VMEM((bq, d), jnp.float32),
+            pltpu.VMEM((bq, dv), jnp.float32),
             pltpu.VMEM((bq, 1), jnp.float32),
             pltpu.VMEM((bq, 1), jnp.float32),
         ]
@@ -400,7 +404,7 @@ def _flash_pallas(q, k, v, causal: bool, scale: float, bq: int, bk: int,
             grid=(bh, npairs),
             in_specs=in_specs,
             out_specs=[
-                pl.BlockSpec((1, bq, d), lambda b, p, im, jm, fl: (b, im[p], 0)),
+                pl.BlockSpec((1, bq, dv), lambda b, p, im, jm, fl: (b, im[p], 0)),
                 pl.BlockSpec((1, bq, 1), lambda b, p, im, jm, fl: (b, im[p], 0)),
             ],
             scratch_shapes=scratch_shapes,
@@ -410,13 +414,14 @@ def _flash_pallas(q, k, v, causal: bool, scale: float, bq: int, bk: int,
             functools.partial(kern, scale=scale, bq=bq, bk=bk, has_bias=has_bias),
             grid_spec=grid_spec,
             out_shape=[
-                jax.ShapeDtypeStruct((bh, tq, d), q.dtype),
+                jax.ShapeDtypeStruct((bh, tq, dv), q.dtype),
                 jax.ShapeDtypeStruct((bh, tq, 1), jnp.float32),
             ],
             interpret=interpret,
             compiler_params=None if interpret else _compiler_params(pltpu),
+            name=name,
         )(jnp.asarray(im), jnp.asarray(jm), jnp.asarray(flags), *inputs)
-        return out.reshape(*batch, tq, d), lse.reshape(*batch, tq)
+        return out.reshape(*batch, tq, dv), lse.reshape(*batch, tq)
 
 
 def _dq_kernel(im_ref, jm_ref, flags_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
@@ -676,7 +681,8 @@ def _flash_bwd_pallas(q, k, v, o, do, lse, causal: bool, scale: float, bq: int,
 def _fits(q, k, bq: int, bk: int, with_bias: bool = False) -> bool:
     """VMEM gate: forward and backward all stream blocks through the grid now, so
     residency is O(bq·bk) regardless of T — the gate only enforces even tiling
-    and a sane per-step footprint."""
+    and a sane per-step footprint. One head width ``d`` for q, k and v: a narrower
+    v goes through :func:`forward_blocks`."""
     tq, d = q.shape[-2], q.shape[-1]
     tk = k.shape[-2]
     if tq % bq or tk % bk:
@@ -707,6 +713,42 @@ def _fits(q, k, bq: int, bk: int, with_bias: bool = False) -> bool:
     # experiments that lift the VMEM budget actually reach the flash path
     limit = _env_vmem_limit() or 12 * 2**20
     return max(fwd, bwd) <= limit
+
+
+def forward_blocks(q, k, v):
+    """The largest preferred ``(bq, bk)`` with which the forward kernel alone runs
+    ``q, k: (..., T, d)``, ``v: (..., Tk, dv)``, or None: the sequence does not tile,
+    the pair list outgrows SMEM, a type Mosaic does not take, or no block pair fits the
+    VMEM budget. Counted per grid step: the f32 score and probability tiles and the
+    probabilities once more in v's type, the f32 accumulator of v's width, the running
+    max / sum scratch and the double-buffered LSE block (one column each, padded to 128
+    lanes), and the q / k / v / out blocks double-buffered. At d = 192, dv = 128 in
+    bfloat16 that is 8.2 MiB for (512, 1024) and 15 MiB for (1024, 1024), which Mosaic
+    refuses under its 16 MiB default scope (16.37 MiB with its own temporaries)."""
+    tq, d = q.shape[-2], q.shape[-1]
+    tk, dv = k.shape[-2], v.shape[-1]
+    if any(t.dtype not in (jnp.float32, jnp.bfloat16, jnp.float16) for t in (q, k, v)):
+        return None
+    itemsize = jnp.dtype(q.dtype).itemsize
+    limit = _env_vmem_limit() or 12 * 2**20
+    for bq, bk in _FWD_BLOCK_PREFS.get(itemsize, ((512, 512),)):
+        if tq % bq or tk % bk or (tq // bq) * (tk // bk) > _MAX_PAIRS:
+            continue
+        tiles = (8 + itemsize) * bq * bk + 4 * bq * dv + 4 * 4 * bq * 128
+        blocks = (bq * d + bk * d + bk * dv + bq * dv) * itemsize * 2
+        if tiles + blocks <= limit:
+            return bq, bk
+    return None
+
+
+def flash_forward(q, k, v, causal: bool, scale: float, blocks, name=None,
+                  interpret: bool = False):
+    """The forward kernel alone, for inference paths: v may be narrower or wider than
+    q and k, ``blocks`` is what :func:`forward_blocks` chose, ``name`` names the Pallas
+    call in device traces. No gradient is defined on this entry."""
+    out, _ = _flash_pallas(q, k, v, causal, float(scale), *blocks, interpret=interpret,
+                           name=name)
+    return out
 
 
 def _as_bias(mask):
@@ -753,6 +795,11 @@ def _fwd(q, k, v, causal, scale, mask):
 
 def _bwd(causal, scale, res, g):
     q, k, v, out, lse, mask = res
+    if v.shape[-1] != q.shape[-1]:
+        raise NotImplementedError(
+            "the flash backward kernels take one head width for q, k and v; "
+            f"got {q.shape[-1]} and {v.shape[-1]}"
+        )
     if mask is not None and mask.dtype != jnp.bool_:
         # a float bias has a real gradient (Σ_{b,h} dS) that this backward does not
         # compute — fail loudly rather than silently training the bias to nothing.

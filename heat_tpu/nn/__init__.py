@@ -6,4 +6,7 @@ from .data_parallel import *
 from .modules import *
 from .attention import *
 from .recurrent import *
-from . import attention, data_parallel, functional, modules, recurrent
+from .hyper_connections import *
+from .moe import *
+from .xing4 import *
+from . import attention, data_parallel, functional, hyper_connections, modules, moe, recurrent, xing4

@@ -39,7 +39,13 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import PartitionSpec as P
 
-from ..core.kernels.flash_attention import flash_attention, use_flash
+from ..core import diagnostics
+from ..core.kernels.flash_attention import (
+    flash_attention,
+    flash_forward,
+    forward_blocks,
+    use_flash,
+)
 
 from ..core.dndarray import DNDarray
 
@@ -51,6 +57,10 @@ __all__ = [
     "zigzag_inverse",
     "ulysses_attention",
     "MultiheadAttention",
+    "MultiheadLatentAttention",
+    "yarn_inv_freq",
+    "rotate_halves",
+    "even_then_odd",
     "TransformerEncoderLayer",
     "TransformerEncoder",
     "TransformerDecoderLayer",
@@ -482,7 +492,7 @@ def ulysses_attention(q, k, v, axis_name: str, is_causal: bool = False,
     return lax.all_to_all(o, axis_name, split_axis=2, concat_axis=1, tiled=True)  # ht: ignore[collective-uncontracted] -- axis-name shard_map-body kernel API: no communicator in scope by design; callers (attention()/_ring_sharded) own the comm
 
 
-from .modules import Module
+from .modules import Module, RMSNorm, contract, normal_weight
 
 
 class MultiheadAttention(Module):
@@ -681,6 +691,157 @@ class MultiheadAttention(Module):
             is_causal=is_causal, key_padding_mask=key_padding_mask,
         )
         return out, None
+
+
+def yarn_inv_freq(rope_dim: int, theta: float, scaling: Optional[dict]) -> np.ndarray:
+    """Inverse frequencies of a rotary part of width ``rope_dim``. With YaRN
+    (``scaling``: ``factor``, ``original_max_position_embeddings``, ``beta_fast``,
+    ``beta_slow``) the pairs that turn more than ``beta_fast`` times inside the original
+    context keep their frequency, those that turn less than ``beta_slow`` times are
+    slowed by ``factor``, and a linear ramp blends the pairs between."""
+    freq = theta ** (-np.arange(0, rope_dim, 2, dtype=np.float64) / rope_dim)
+    if not scaling:
+        return freq.astype(np.float32)
+    factor, orig = scaling["factor"], scaling["original_max_position_embeddings"]
+
+    def pair_turning(turns):  # index of the pair that makes ``turns`` turns in ``orig``
+        return rope_dim * math.log(orig / (turns * 2 * math.pi)) / (2 * math.log(theta))
+
+    low = max(math.floor(pair_turning(scaling["beta_fast"])), 0)
+    high = min(math.ceil(pair_turning(scaling["beta_slow"])), rope_dim - 1)
+    span = (high - low) or 1e-3
+    slowed = np.clip((np.arange(rope_dim // 2, dtype=np.float64) - low) / span, 0.0, 1.0)
+    return (freq / factor * slowed + freq * (1.0 - slowed)).astype(np.float32)
+
+
+def _yarn_mscale(scaling: Optional[dict], key: str) -> float:
+    if not scaling or scaling["factor"] <= 1:
+        return 1.0
+    return 0.1 * scaling[key] * math.log(scaling["factor"]) + 1.0
+
+
+def rotate_halves(x, inv_freq, magnitude: float = 1.0):
+    """Rotary positions on ``x`` (..., T, rope_dim) laid out as two halves ``[a | b]``:
+    the pair ``(a[i], b[i])`` at position ``t`` (the index on axis -2) turns by
+    ``t * inv_freq[i]``. Angles, cosines and sines are float32; the result has ``x``'s
+    type. A layout of interleaved pairs ``(x[2i], x[2i+1])`` becomes this one by taking
+    the even columns first (:func:`even_then_odd`); on the TPU the halves are two lane
+    slices, where interleaved pairs would put a dimension of 2 on the lanes."""
+    t, half = x.shape[-2], x.shape[-1] // 2
+    angle = jnp.arange(t, dtype=jnp.int32).astype(jnp.float32)[:, None] \
+        * jnp.asarray(inv_freq, jnp.float32)[None, :]
+    cos = jnp.cos(angle) * jnp.float32(magnitude)
+    sin = jnp.sin(angle) * jnp.float32(magnitude)
+    a, b = x[..., :half].astype(jnp.float32), x[..., half:].astype(jnp.float32)
+    return jnp.concatenate([a * cos - b * sin, a * sin + b * cos], axis=-1).astype(x.dtype)
+
+
+def even_then_odd(w, start: int):
+    """The columns of ``w`` (last axis) from ``start`` on, reordered even ones first:
+    a projection whose rope columns are published as interleaved pairs then yields the
+    two-halves layout of :func:`rotate_halves`. Applied to q's and k's projection alike
+    it leaves ``q . k`` as it was."""
+    width = w.shape[-1] - start
+    order = np.concatenate([np.arange(start), start + np.arange(0, width, 2),
+                            start + np.arange(1, width, 2)])
+    return jnp.take(w, jnp.asarray(order, jnp.int32), axis=-1)
+
+
+class MultiheadLatentAttention(Module):
+    """Causal self-attention through low-rank latents (multi-head latent attention).
+
+    ``c_q = RMSNorm(x W_qa)``; per head ``[q_nope; q_rope] = c_q W_qb``;
+    ``[c_kv; k_rope] = x W_kva`` with ``c_kv <- RMSNorm(c_kv)``; per head
+    ``[k_nope; v] = c_kv W_kvb``. ``k_rope`` is one vector for all heads; rotary
+    positions (YaRN frequencies) go on the rope parts only. Scores are
+    ``q . k * (nope + rope)^-1/2 * m^2`` with YaRN's ``m = 0.1 mscale_all_dim ln(factor) + 1``,
+    the heads' outputs (``v_head_dim`` wide, not the query's width) are concatenated into
+    ``W_o``. No bias anywhere. Input ``(..., T, dim)``, positions ``0..T-1`` on axis -2.
+
+    This is the whole-sequence forward (scoring, prefill): no key/value cache and no
+    absorbed products. On TPU the core runs in the flash Pallas kernel at
+    ``d_qk != d_v``, named ``mla_flash_fwd`` in device traces; where it does not apply
+    (another backend, a sequence that does not tile) the XLA path runs and
+    ``record_fallback("nn.mla", ...)`` says why. Parameters are stored in ``dtype``
+    (norm weights float32); contractions accumulate in float32.
+    """
+
+    def __init__(self, dim: int, num_heads: int, q_lora_rank: int, kv_lora_rank: int,
+                 qk_nope_head_dim: int, qk_rope_head_dim: int, v_head_dim: int,
+                 rope_theta: float = 10000.0, rope_scaling: Optional[dict] = None,
+                 eps: float = 1e-6, dtype=jnp.float32, norm_init_std: float = 0.0):
+        self.dim = dim
+        self.num_heads = num_heads
+        self.q_lora_rank = q_lora_rank
+        self.kv_lora_rank = kv_lora_rank
+        self.nope, self.rope, self.v_dim = qk_nope_head_dim, qk_rope_head_dim, v_head_dim
+        self.inv_freq = yarn_inv_freq(qk_rope_head_dim, rope_theta, rope_scaling)
+        # cos and sin carry mscale / mscale_all_dim; the softmax scale carries m^2
+        self.rope_magnitude = (_yarn_mscale(rope_scaling, "mscale")
+                               / _yarn_mscale(rope_scaling, "mscale_all_dim"))
+        self.scale = (qk_nope_head_dim + qk_rope_head_dim) ** -0.5 \
+            * _yarn_mscale(rope_scaling, "mscale_all_dim") ** 2
+        self.dtype = jnp.dtype(dtype)
+        self.q_norm = RMSNorm(q_lora_rank, eps, norm_init_std)
+        self.kv_norm = RMSNorm(kv_lora_rank, eps, norm_init_std)
+
+    def init(self, key):
+        kqa, kqb, kva, kvb, ko, kqn, kkn = jax.random.split(key, 7)
+        h, dt = self.num_heads, self.dtype
+        return {
+            "wq_a": normal_weight(kqa, (self.dim, self.q_lora_rank), dt, self.dim ** -0.5),
+            "q_norm": self.q_norm.init(kqn),
+            "wq_b": normal_weight(kqb, (self.q_lora_rank, h * (self.nope + self.rope)), dt,
+                                  self.q_lora_rank ** -0.5),
+            "wkv_a": normal_weight(kva, (self.dim, self.kv_lora_rank + self.rope), dt,
+                                   self.dim ** -0.5),
+            "kv_norm": self.kv_norm.init(kkn),
+            "wkv_b": normal_weight(kvb, (self.kv_lora_rank, h * (self.nope + self.v_dim)), dt,
+                                   self.kv_lora_rank ** -0.5),
+            "wo": normal_weight(ko, (h * self.v_dim, self.dim), dt, (h * self.v_dim) ** -0.5),
+        }
+
+    def _core(self, q, k, v):
+        """Causal softmax(q k^T scale) v on (..., H, T, .) operands."""
+        blocks, why = None, f"backend {jax.default_backend()}"
+        if jax.default_backend() == "tpu":
+            blocks, why = forward_blocks(q, k, v), "no block pair tiles and fits"
+        if blocks is not None:
+            return flash_forward(q, k, v, True, self.scale, blocks, name="mla_flash_fwd")
+        diagnostics.record_fallback("nn.mla", f"{why}: T={q.shape[-2]} {q.dtype}")
+        t = q.shape[-2]
+        s = contract("...qd,...kd->...qk", q, k) * jnp.float32(self.scale)
+        rows = jnp.arange(t, dtype=jnp.int32)
+        s = jnp.where(rows[:, None] >= rows[None, :], s, _NEG_INF)
+        p = jax.nn.softmax(s, axis=-1).astype(v.dtype)
+        return contract("...qk,...kd->...qd", p, v).astype(q.dtype)
+
+    def apply(self, params, x, *, key=None, train=False):
+        x = x.larray if isinstance(x, DNDarray) else x
+        h, dn, dr, dv = self.num_heads, self.nope, self.rope, self.v_dim
+        dt = x.dtype
+        with jax.named_scope("ht.nn.mla"):
+            c_q = self.q_norm.apply(
+                params["q_norm"], contract("...td,dr->...tr", x, params["wq_a"]).astype(dt))
+            # the rope columns of both projections, published as interleaved pairs, are
+            # taken even ones first: rotate_halves then turns the published pairs
+            wq_b = even_then_odd(params["wq_b"].reshape(self.q_lora_rank, h, dn + dr), dn)
+            q = contract("...tr,rhe->...hte", c_q, wq_b).astype(dt)
+            q = jnp.concatenate(
+                [q[..., :dn], rotate_halves(q[..., dn:], self.inv_freq, self.rope_magnitude)],
+                axis=-1)
+            kv = contract("...td,dr->...tr", x,
+                          even_then_odd(params["wkv_a"], self.kv_lora_rank)).astype(dt)
+            c_kv = self.kv_norm.apply(params["kv_norm"], kv[..., :self.kv_lora_rank])
+            k_rope = rotate_halves(kv[..., self.kv_lora_rank:], self.inv_freq,
+                                   self.rope_magnitude)
+            kv_h = contract("...tr,rhe->...hte", c_kv,
+                            params["wkv_b"].reshape(self.kv_lora_rank, h, dn + dv)).astype(dt)
+            k_rope = jnp.broadcast_to(k_rope[..., None, :, :], kv_h.shape[:-1] + (dr,))
+            k = jnp.concatenate([kv_h[..., :dn], k_rope], axis=-1)
+            o = self._core(q, k, kv_h[..., dn:])
+            return contract("...htv,hvd->...td", o,
+                            params["wo"].reshape(h, dv, self.dim)).astype(dt)
 
 
 def _keyed_dropout(x, p: float, key, train: bool):

@@ -39,6 +39,8 @@ __all__ = [
     "GroupNorm",
     "InstanceNorm2d",
     "LayerNorm",
+    "RMSNorm",
+    "GatedMLP",
     "ReLU",
     "ReLU6",
     "LeakyReLU",
@@ -75,6 +77,30 @@ __all__ = [
 
 def _to_value(x):
     return x.larray if isinstance(x, DNDarray) else x
+
+
+def contract(spec: str, x: jax.Array, w: jax.Array) -> jax.Array:
+    """``einsum(spec, x, w)`` accumulated and returned in float32, with the operands'
+    precision stated: bfloat16 operands ride the MXU as they are (one pass), float32
+    operands multiply at ``Precision.HIGHEST`` and not at the MXU default, which would
+    round them to bfloat16 first. The caller rounds the result to its activation type."""
+    exact = x.dtype == jnp.float32 or w.dtype == jnp.float32
+    return jnp.einsum(spec, x, w, preferred_element_type=jnp.float32,
+                      precision=jax.lax.Precision.HIGHEST if exact else None)
+
+
+def gated_silu(x: jax.Array, w_gate: jax.Array, w_up: jax.Array, w_down: jax.Array) -> jax.Array:
+    """``W_down(silu(W_gate x) * W_up x)`` on ``x`` (..., d), in ``x``'s type; the three
+    contractions accumulate in float32 (:func:`contract`)."""
+    gate = contract("...d,dh->...h", x, w_gate)
+    up = contract("...d,dh->...h", x, w_up)
+    h = (jax.nn.silu(gate) * up).astype(x.dtype)
+    return contract("...h,hd->...d", h, w_down).astype(x.dtype)
+
+
+def normal_weight(key, shape, dtype, scale: float) -> jax.Array:
+    """``N(0, scale^2)`` drawn in float32 and rounded to ``dtype``."""
+    return (jax.random.normal(key, shape, jnp.float32) * jnp.float32(scale)).astype(dtype)
 
 
 class Module:
@@ -459,6 +485,64 @@ class LayerNorm(Module):
         weight = params.get("weight") if self.elementwise_affine else None
         bias = params.get("bias") if self.elementwise_affine else None
         return F.layer_norm(x, self.normalized_shape, weight, bias, self.eps)
+
+
+class RMSNorm(Module):
+    """``x / sqrt(mean(x^2) + eps) * weight`` over the last axis, computed in float32 and
+    returned in the input's type (no mean subtracted, no bias). The weight starts at
+    one; ``init_std`` > 0 draws it as ``1 + init_std * N(0, 1)`` instead, for models that
+    run on seeded weights and want the weight's place in the equations to show."""
+
+    def __init__(self, dim: int, eps: float = 1e-6, init_std: float = 0.0):
+        self.dim = dim
+        self.eps = eps
+        self.init_std = init_std
+
+    def init(self, key):
+        w = jnp.ones((self.dim,), jnp.float32)
+        if self.init_std:
+            w = w + jnp.float32(self.init_std) * jax.random.normal(key, (self.dim,), jnp.float32)
+        return {"weight": w}
+
+    def apply(self, params, x, *, key=None, train=False):
+        v = _to_value(x)
+        v32 = v.astype(jnp.float32)
+        ms = jnp.mean(v32 * v32, axis=-1, keepdims=True)
+        out = (v32 * jax.lax.rsqrt(ms + jnp.float32(self.eps)) * params["weight"]).astype(v.dtype)
+        if isinstance(x, DNDarray):
+            from ..core._operations import wrap_result
+
+            keep = x.split if (x.split is not None and x.split < x.ndim - 1) else None
+            return wrap_result(out, x, keep)
+        return out
+
+
+class GatedMLP(Module):
+    """Gated-SiLU feed-forward ``W_down(silu(W_gate x) * W_up x)``, no bias. Parameters
+    are stored in ``dtype``; every contraction accumulates in float32 (:func:`contract`)
+    and activations stay in the input's type."""
+
+    def __init__(self, dim: int, hidden: int, dtype=jnp.float32):
+        self.dim = dim
+        self.hidden = hidden
+        self.dtype = jnp.dtype(dtype)
+
+    def init(self, key):
+        kg, ku, kd = jax.random.split(key, 3)
+        return {
+            "w_gate": normal_weight(kg, (self.dim, self.hidden), self.dtype, self.dim ** -0.5),
+            "w_up": normal_weight(ku, (self.dim, self.hidden), self.dtype, self.dim ** -0.5),
+            "w_down": normal_weight(kd, (self.hidden, self.dim), self.dtype, self.hidden ** -0.5),
+        }
+
+    def apply(self, params, x, *, key=None, train=False):
+        out = gated_silu(_to_value(x), params["w_gate"], params["w_up"], params["w_down"])
+        if isinstance(x, DNDarray):
+            from ..core._operations import wrap_result
+
+            keep = x.split if (x.split is not None and x.split < x.ndim - 1) else None
+            return wrap_result(out, x, keep)
+        return out
 
 
 class ReLU(Module):

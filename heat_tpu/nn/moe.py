@@ -1,0 +1,151 @@
+"""Token-routed experts: a sigmoid router with a selection bias, top-k without dropped
+tokens, a grouped product over the experts held here, and a shared expert.
+
+For every token ``u`` (d)::
+
+    s      = sigmoid(u W_g)                 float32, over all E experts
+    chosen = top_k(s + b)                   b: selection bias (auxiliary-loss-free
+                                            balancing: it moves the choice, not the weight)
+    w      = s[chosen] / sum(s[chosen]) * scaling
+    y      = sum_e w_e W_down,e (silu(W_gate,e u) * W_up,e u)  +  shared(u)
+
+**Which experts live here.** ``experts_held = (first, count)`` states what the one-integer
+``split`` of a DNDarray cannot: this instance holds the weights of experts
+``first .. first + count - 1`` of ``n_experts``. It routes over all ``n_experts``, computes
+its own experts' part of ``y`` for the tokens routed to them, and adds the shared expert
+(which every holder computes alike). What the other holders' experts would add is left
+out: summing the parts over all holders, with the shared expert counted once, gives the
+uncut layer. On one chip the layer runs without its exchange; nothing stands in for the
+absent chips.
+
+**No token is dropped.** The (token, expert) pairs are sorted by expert and every
+expert's group is padded to whole blocks of ``block_rows`` rows, so the buffer holds any
+imbalance (``T k + count * block_rows`` rows), and a loop over the held experts
+multiplies as many blocks as each really has: the cost follows the tokens, not a capacity.
+
+No reference counterpart (the reference has no expert layers).
+"""
+
+from __future__ import annotations
+
+from typing import Optional, Tuple
+
+import jax
+import jax.numpy as jnp
+from jax import lax
+
+from .modules import GatedMLP, Module, contract, gated_silu, normal_weight
+
+__all__ = ["MoE"]
+
+
+class MoE(Module):
+    """Routed gated-SiLU experts plus ``n_shared`` shared experts (one gated MLP of
+    ``n_shared * hidden``), on tokens ``(T, dim)``. ``apply`` returns
+    ``(y, {"chosen": (T, k) int32, "load": (count,) int32})``: the router's choices over
+    all experts and the rows each held expert multiplied.
+
+    Expert weights are stored stacked, ``(count, dim, hidden)`` and ``(count, hidden,
+    dim)``, in ``dtype``; the router's weight and its selection bias are float32.
+    """
+
+    def __init__(self, dim: int, hidden: int, n_experts: int, top_k: int, n_shared: int = 1,
+                 scaling: float = 1.0, experts_held: Optional[Tuple[int, int]] = None,
+                 block_rows: int = 512, dtype=jnp.float32):
+        first, count = experts_held if experts_held is not None else (0, n_experts)
+        if first < 0 or count < 1 or first + count > n_experts:
+            raise ValueError(f"experts_held {experts_held} lies outside 0..{n_experts}")
+        self.dim, self.hidden = dim, hidden
+        self.n_experts, self.top_k = n_experts, top_k
+        self.scaling = scaling
+        self.first, self.count = first, count
+        self.block_rows = block_rows
+        self.dtype = jnp.dtype(dtype)
+        self.shared = GatedMLP(dim, n_shared * hidden, dtype) if n_shared else None
+
+    def init(self, key):
+        k_router, k_bias, k_gate, k_up, k_down, k_shared = jax.random.split(key, 6)
+        e, d, h, dt = self.count, self.dim, self.hidden, self.dtype
+        params = {
+            "router": jax.random.normal(k_router, (d, self.n_experts), jnp.float32)
+            * jnp.float32(d ** -0.5),
+            # a selection bias as balancing would leave it: small against the scores' spread
+            "router_bias": 0.05 * jax.random.normal(k_bias, (self.n_experts,), jnp.float32),
+            "experts": {
+                "w_gate": normal_weight(k_gate, (e, d, h), dt, d ** -0.5),
+                "w_up": normal_weight(k_up, (e, d, h), dt, d ** -0.5),
+                "w_down": normal_weight(k_down, (e, h, d), dt, h ** -0.5),
+            },
+        }
+        if self.shared is not None:
+            params["shared"] = self.shared.init(k_shared)
+        return params
+
+    def route(self, params, u):
+        """``(chosen (T, k) int32, weights (T, k) float32)`` over all experts."""
+        scores = jax.nn.sigmoid(contract("td,de->te", u, params["router"]))
+        _, chosen = lax.top_k(scores + params["router_bias"], self.top_k)
+        w = jnp.take_along_axis(scores, chosen, axis=1)
+        w = w / jnp.sum(w, axis=1, keepdims=True) * jnp.float32(self.scaling)
+        return chosen.astype(jnp.int32), w
+
+    def _layout(self, chosen):
+        """Where each (token, expert) pair goes in the padded, expert-sorted buffer.
+        Returns ``slot`` (T k,) int32 (pairs of experts held elsewhere: the buffer's
+        length, which reads as nothing and writes nowhere), the token behind each buffer
+        row ``source`` (rows,) (padding: T, an all-zero row), each held expert's first row
+        and its number of blocks, and the load (count,)."""
+        t, k = chosen.shape
+        b, e = self.block_rows, self.count
+        rows = -(-t * k // b) * b + e * b
+        local = chosen.reshape(-1) - jnp.int32(self.first)
+        local = jnp.where((local >= 0) & (local < e), local, jnp.int32(e))
+        order = jnp.argsort(local, stable=True).astype(jnp.int32)
+        sorted_e = local[order]
+        edges = jnp.searchsorted(sorted_e, jnp.arange(e + 2, dtype=jnp.int32),
+                                 side="left").astype(jnp.int32)
+        load = edges[1:] - edges[:-1]  # the last entry counts the pairs held elsewhere
+        blocks = (load[:e] + (b - 1)) // b
+        first_row = jnp.concatenate([jnp.zeros((1,), jnp.int32),
+                                     jnp.cumsum(blocks * b, dtype=jnp.int32)])
+        first_row = first_row.at[e].set(rows)  # pairs held elsewhere land out of range
+        rank = jnp.arange(t * k, dtype=jnp.int32) - edges[sorted_e]
+        slot_sorted = jnp.where(sorted_e < e, first_row[sorted_e] + rank, jnp.int32(rows))
+        slot = jnp.zeros((t * k,), jnp.int32).at[order].set(slot_sorted)
+        source = jnp.full((rows,), t, jnp.int32).at[slot_sorted].set(order // k, mode="drop")
+        return slot, source, first_row[:e], blocks, load[:e]
+
+    def _experts(self, experts, xs, first_row, blocks):
+        """``xs`` (rows, dim): every held expert's blocks through its gated MLP."""
+        b = self.block_rows
+
+        def one_expert(e, ys):
+            weights = [lax.dynamic_index_in_dim(experts[name], e, 0, False)
+                       for name in ("w_gate", "w_up", "w_down")]
+
+            def one_block(i, ys):
+                row = first_row[e] + i * b
+                y = gated_silu(lax.dynamic_slice_in_dim(xs, row, b, 0), *weights)
+                return lax.dynamic_update_slice_in_dim(ys, y, row, 0)
+
+            return lax.fori_loop(0, blocks[e], one_block, ys)
+
+        return lax.fori_loop(0, self.count, one_expert, jnp.zeros_like(xs))
+
+    def apply(self, params, x, *, key=None, train=False):
+        if x.ndim != 2:
+            raise ValueError(f"MoE routes tokens of shape (T, dim); got {x.shape}")
+        t, k = x.shape[0], self.top_k
+        with jax.named_scope("ht.nn.moe"):
+            chosen, w = self.route(params, x)
+            slot, source, first_row, blocks, load = self._layout(chosen)
+            xs = jnp.take(x, source, axis=0, mode="fill", fill_value=0)  # padding: zeros
+            ys = self._experts(params["experts"], xs, first_row, blocks)
+            # a pair held elsewhere reads row 0 with weight 0
+            held = slot < ys.shape[0]
+            picked = ys[jnp.where(held, slot, 0)].reshape(t, k, self.dim)
+            w = jnp.where(held.reshape(t, k), w, 0.0)
+            y = jnp.sum(picked.astype(jnp.float32) * w[:, :, None], axis=1)
+            if self.shared is not None:
+                y = y + self.shared.apply(params["shared"], x).astype(jnp.float32)
+            return y.astype(x.dtype), {"chosen": chosen, "load": load}

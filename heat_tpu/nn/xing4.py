@@ -1,0 +1,262 @@
+"""The Xing4.0 architecture as a long-document scoring forward.
+
+Layers of latent attention (:class:`~.attention.MultiheadLatentAttention`) and a gated
+feed-forward (the leading ones dense, the rest token-routed experts, :class:`~.moe.MoE`),
+every sub-block on ``hc_mult`` residual streams mixed by manifold-constrained
+hyper-connections (:class:`~.hyper_connections.HyperConnection`), an untied head, and one
+multi-token-prediction module that predicts two tokens ahead from the main model's last
+hidden state. ``doc/source/xing4.rst`` writes the equations out and lists what is
+``assumed`` where the published configuration leaves a choice open.
+
+The request is *scoring*: the log-likelihood of a document's last ``continuation`` tokens
+given everything before them, as evaluation harnesses, rerankers and perplexity filters
+ask it. That is one whole causal forward with no key/value cache and no decode loop;
+only the positions that score the continuation go through the head.
+
+``model(tokens)`` runs through :meth:`Module.__call__` like every module, and the whole
+forward is **one compiled program a call** (``nn.xing4.traces`` counts its traces, as
+``spatial.cdist.traces`` does for ``cdist``).
+
+No reference counterpart.
+"""
+
+from __future__ import annotations
+
+import dataclasses
+from typing import NamedTuple, Optional, Tuple
+
+import numpy as np
+
+import jax
+import jax.numpy as jnp
+
+from ..core import diagnostics
+from .attention import MultiheadLatentAttention
+from .hyper_connections import HyperConnection
+from .modules import GatedMLP, Module, RMSNorm, _to_value, contract, normal_weight
+from .moe import MoE
+
+__all__ = ["Xing4", "Xing4Block", "Xing4Config", "Xing4Scores"]
+
+# the model runs on seeded weights here: norm weights are drawn round one, so that a
+# weight in the wrong place of an equation moves the logits
+NORM_INIT_STD = 0.1
+
+
+@dataclasses.dataclass(frozen=True)
+class Xing4Config:
+    """The published keys of the model's ``config.json`` that shape the forward."""
+
+    hidden_size: int
+    intermediate_size: int
+    moe_intermediate_size: int
+    num_hidden_layers: int
+    first_k_dense_replace: int
+    num_attention_heads: int
+    q_lora_rank: int
+    kv_lora_rank: int
+    qk_nope_head_dim: int
+    qk_rope_head_dim: int
+    v_head_dim: int
+    n_routed_experts: int
+    n_shared_experts: int
+    num_experts_per_tok: int
+    routed_scaling_factor: float
+    vocab_size: int
+    hc_mult: int
+    hc_sinkhorn_iters: int
+    hc_eps: float
+    mhc_h_res_clamp_min: float
+    mhc_h_res_clamp_max: float
+    rms_norm_eps: float
+    rope_theta: float
+    rope_scaling: Optional[dict] = None
+    num_nextn_predict_layers: int = 1
+
+    @classmethod
+    def from_dict(cls, config: dict) -> "Xing4Config":
+        """From a ``config.json`` dictionary; keys that do not shape the forward are
+        passed over, and a variant this module does not compute is refused."""
+        refused = {"scoring_func": "sigmoid", "topk_method": "noaux_tc", "n_group": 1,
+                   "topk_group": 1, "norm_topk_prob": True, "hidden_act": "silu",
+                   "attention_bias": False, "tie_word_embeddings": False,
+                   "moe_layer_freq": 1, "num_nextn_predict_layers": 1}
+        for key, only in refused.items():
+            if config.get(key, only) != only:
+                raise ValueError(f"Xing4 computes {key}={only!r} only; got {config[key]!r}")
+        names = {f.name for f in dataclasses.fields(cls)}
+        return cls(**{k: v for k, v in config.items() if k in names})
+
+
+class Xing4Scores(NamedTuple):
+    """What one scoring forward returns, all on the device. ``logits`` (c, vocab): the
+    main head at positions ``T-1-c .. T-2``, which score the last ``c`` tokens;
+    ``mtp_logits`` (c, vocab): the multi-token-prediction head at ``T-2-c .. T-3``, which
+    scores the same tokens from two back; the two log-likelihoods (float32 scalars);
+    ``chosen`` (expert layers + 1, T, k): every expert layer's routing, the module's last;
+    ``load`` (expert layers + 1, experts held): rows each held expert multiplied."""
+
+    logits: jax.Array
+    mtp_logits: jax.Array
+    loglik: jax.Array
+    mtp_loglik: jax.Array
+    chosen: jax.Array
+    load: jax.Array
+
+
+class Xing4Block(Module):
+    """One layer on streams ``(n, T, d)``: ``X <- HC(X, attention . norm)``, then
+    ``X <- HC(X, feed-forward . norm)``. ``apply`` returns ``(X, aux)``, ``aux`` the
+    expert layer's ``{"chosen", "load"}`` or None for a dense layer."""
+
+    def __init__(self, config: Xing4Config, dense: bool,
+                 experts_held: Optional[Tuple[int, int]] = None, dtype=jnp.bfloat16,
+                 block_rows: int = 512):
+        c = config
+        hc = dict(dim=c.hidden_size, streams=c.hc_mult, sinkhorn_iters=c.hc_sinkhorn_iters,
+                  eps=c.hc_eps, res_clamp=(c.mhc_h_res_clamp_min, c.mhc_h_res_clamp_max),
+                  norm_eps=c.rms_norm_eps, norm_init_std=NORM_INIT_STD)
+        self.attn_hc = HyperConnection(**hc)
+        self.attn_norm = RMSNorm(c.hidden_size, c.rms_norm_eps, NORM_INIT_STD)
+        self.attn = MultiheadLatentAttention(
+            c.hidden_size, c.num_attention_heads, c.q_lora_rank, c.kv_lora_rank,
+            c.qk_nope_head_dim, c.qk_rope_head_dim, c.v_head_dim, c.rope_theta,
+            c.rope_scaling, c.rms_norm_eps, dtype, NORM_INIT_STD)
+        self.ffn_hc = HyperConnection(**hc)
+        self.ffn_norm = RMSNorm(c.hidden_size, c.rms_norm_eps, NORM_INIT_STD)
+        if dense:
+            self.ffn = GatedMLP(c.hidden_size, c.intermediate_size, dtype)
+        else:
+            self.ffn = MoE(c.hidden_size, c.moe_intermediate_size, c.n_routed_experts,
+                           c.num_experts_per_tok, c.n_shared_experts,
+                           c.routed_scaling_factor, experts_held, block_rows, dtype)
+
+    def apply(self, params, x, *, key=None, train=False):
+        def attention(u):
+            u = self.attn_norm.apply(params["attn_norm"], u)
+            return self.attn.apply(params["attn"], u), None
+
+        def feed_forward(u):
+            out = self.ffn.apply(params["ffn"], self.ffn_norm.apply(params["ffn_norm"], u))
+            return out if isinstance(out, tuple) else (out, None)  # experts give (y, aux)
+
+        x, _ = self.attn_hc.apply(params["attn_hc"], (x, attention))
+        return self.ffn_hc.apply(params["ffn_hc"], (x, feed_forward))
+
+
+class Xing4(Module):
+    """``Xing4(config)(tokens)``: the scoring forward of one document ``tokens`` (T,)
+    int32, returning :class:`Xing4Scores`.
+
+    ``config`` is an :class:`Xing4Config` or the ``config.json`` dictionary;
+    ``continuation`` is the number of trailing tokens that are scored; ``experts_held =
+    (first, count)`` is the share of every expert layer that lives here (all by default,
+    see :class:`~.moe.MoE`); parameters are stored in ``dtype`` (norms, router and the
+    hyper-connection mappings float32) and activations follow it.
+    """
+
+    def __init__(self, config, continuation: int = 128,
+                 experts_held: Optional[Tuple[int, int]] = None, dtype=jnp.bfloat16,
+                 block_rows: int = 512):
+        if not isinstance(config, Xing4Config):
+            config = Xing4Config.from_dict(config)
+        self.config = c = config
+        self.continuation = continuation
+        self.dtype = jnp.dtype(dtype)
+        self.layers = [
+            Xing4Block(c, i < c.first_k_dense_replace, experts_held, dtype, block_rows)
+            for i in range(c.num_hidden_layers)
+        ]
+        self.norm = RMSNorm(c.hidden_size, c.rms_norm_eps, NORM_INIT_STD)
+        # the multi-token-prediction module: two norms, a projection of the joined
+        # (2d) vector, one expert layer, its own final norm; embedding and head are shared
+        self.mtp_enorm = RMSNorm(c.hidden_size, c.rms_norm_eps, NORM_INIT_STD)
+        self.mtp_hnorm = RMSNorm(c.hidden_size, c.rms_norm_eps, NORM_INIT_STD)
+        self.mtp_block = Xing4Block(c, False, experts_held, dtype, block_rows)
+        self.mtp_norm = RMSNorm(c.hidden_size, c.rms_norm_eps, NORM_INIT_STD)
+        self._program = jax.jit(self._forward)
+
+    def init(self, key):
+        c, dt = self.config, self.dtype
+        d = c.hidden_size
+        k_embed, k_head, k_proj, k_mtp, k_norm, k_en, k_hn, k_mn, *k_layers = jax.random.split(
+            key, 8 + len(self.layers))
+        return {
+            "embed": {"weight": normal_weight(k_embed, (c.vocab_size, d), dt, 1.0)},
+            "layers": [layer.init(k) for layer, k in zip(self.layers, k_layers)],
+            "norm": self.norm.init(k_norm),
+            "head": {"weight": normal_weight(k_head, (d, c.vocab_size), dt, d ** -0.5)},
+            "mtp": {
+                "enorm": self.mtp_enorm.init(k_en),
+                "hnorm": self.mtp_hnorm.init(k_hn),
+                "proj": normal_weight(k_proj, (2 * d, d), dt, (2 * d) ** -0.5),
+                "block": self.mtp_block.init(k_mtp),
+                "norm": self.mtp_norm.init(k_mn),
+            },
+        }
+
+    @staticmethod
+    def _sum_streams(x):
+        return sum(x[j].astype(jnp.float32) for j in range(x.shape[0])).astype(x.dtype)
+
+    def _score(self, norm, norm_params, head, h, targets):
+        """Head logits (float32) of the rows ``h`` and the targets' log-likelihood."""
+        logits = contract("td,dv->tv", norm.apply(norm_params, h), head["weight"])
+        logp = jax.nn.log_softmax(logits, axis=-1)
+        return logits, jnp.sum(jnp.take_along_axis(logp, targets[:, None], axis=1))
+
+    def _forward(self, params, tokens):
+        if diagnostics._enabled:
+            diagnostics.counter("nn.xing4.traces")  # trace time only
+        t, c = tokens.shape[0], self.continuation
+        tokens = tokens.astype(jnp.int32)
+        embed = params["embed"]["weight"]
+        targets = tokens[t - c:]
+        x = jnp.broadcast_to(embed[tokens][None], (self.config.hc_mult, t, embed.shape[1]))
+        routed = []
+        for block, p in zip(self.layers, params["layers"]):
+            x, aux = block.apply(p, x)
+            if aux is not None:
+                routed.append(aux)
+        h = self._sum_streams(x)
+        logits, loglik = self._score(self.norm, params["norm"], params["head"],
+                                     h[t - 1 - c:t - 1], targets)
+        with jax.named_scope("ht.nn.mtp"):
+            m = params["mtp"]
+            # position i joins the embedding of token i+1; the last position has none and
+            # takes token 0's: it is causal-last, so nothing reads it, and it is dropped
+            joined = jnp.concatenate(
+                [self.mtp_enorm.apply(m["enorm"], embed[jnp.roll(tokens, -1)]),
+                 self.mtp_hnorm.apply(m["hnorm"], h)], axis=-1)
+            hm = contract("te,ed->td", joined, m["proj"]).astype(h.dtype)
+            xm = jnp.broadcast_to(hm[None], x.shape)
+            xm, aux = self.mtp_block.apply(m["block"], xm)
+            routed.append(aux)
+            hm = self._sum_streams(xm)
+            mtp_logits, mtp_loglik = self._score(self.mtp_norm, m["norm"], params["head"],
+                                                 hm[t - 2 - c:t - 2], targets)
+        return Xing4Scores(logits, mtp_logits, loglik, mtp_loglik,
+                           jnp.stack([a["chosen"] for a in routed]),
+                           jnp.stack([a["load"] for a in routed]))
+
+    def apply(self, params, x, *, key=None, train=False):
+        if x.ndim != 1 or x.shape[0] < self.continuation + 3:
+            raise ValueError(
+                f"Xing4 scores one document of shape (T,), T >= continuation + 3 = "
+                f"{self.continuation + 3}; got {x.shape}")
+        return self._program(params, x)
+
+    def __call__(self, tokens, **kwargs):
+        return super().__call__(_to_value(tokens), **kwargs)
+
+    def readback(self, scores: Xing4Scores) -> Tuple[float, float]:
+        """Wait for the program and bring the two log-likelihoods to the host. With
+        diagnostics on, the expert layers' load is then counted from the auxiliary
+        output: ``nn.moe.tokens`` (rows the held experts multiplied, summed over the
+        expert layers) and ``nn.moe.load_max`` (the fullest expert's rows, likewise)."""
+        loglik, mtp_loglik = float(scores.loglik), float(scores.mtp_loglik)
+        if diagnostics._enabled:
+            load = np.asarray(scores.load)
+            diagnostics.counter("nn.moe.tokens", float(load.sum()))
+            diagnostics.counter("nn.moe.load_max", float(load.max(axis=1).sum()))
+        return loglik, mtp_loglik

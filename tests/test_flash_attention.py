@@ -10,6 +10,8 @@ import jax.numpy as jnp
 from heat_tpu.core.kernels.flash_attention import (
     _flash_pallas,
     flash_attention_reference,
+    flash_forward,
+    forward_blocks,
     use_flash,
 )
 
@@ -112,6 +114,62 @@ class TestFlashKernel:
         )[0]
         want = _dense_attention(q, k, v, mask=mask, is_causal=True, scale=0.125)
         np.testing.assert_allclose(np.asarray(got), np.asarray(want), rtol=2e-4, atol=2e-4)
+
+
+class TestFlashUnequalWidths:
+    """d_qk != d_v (latent attention: 192 against 128). Interpret mode under
+    ``default_matmul_precision("highest")``, so that the tolerance is float32's on any
+    device the suite runs on (ROADMAP D9)."""
+
+    @pytest.mark.parametrize("causal", [False, True])
+    @pytest.mark.parametrize("widths", [(192, 128), (64, 128), (128, 64)])
+    def test_interpret_parity(self, widths, causal):
+        d, dv = widths
+        rng = np.random.default_rng(21)
+        q = jnp.array(rng.standard_normal((2, 1024, d)), jnp.float32)
+        k = jnp.array(rng.standard_normal((2, 1024, d)), jnp.float32)
+        v = jnp.array(rng.standard_normal((2, 1024, dv)), jnp.float32)
+        with jax.default_matmul_precision("highest"):
+            got, lse = _flash_pallas(q, k, v, causal, 0.07, 512, 512, interpret=True,
+                                     name="mla_flash_fwd")
+            want = flash_attention_reference(q, k, v, causal, 0.07)
+        assert got.shape == want.shape == (2, 1024, dv) and lse.shape == (2, 1024)
+        np.testing.assert_allclose(np.asarray(got), np.asarray(want), rtol=1e-5, atol=1e-5)
+
+    def test_forward_entry_in_bfloat16(self):
+        rng = np.random.default_rng(22)
+        q = jnp.array(rng.standard_normal((2, 1024, 192)), jnp.bfloat16)
+        k = jnp.array(rng.standard_normal((2, 1024, 192)), jnp.bfloat16)
+        v = jnp.array(rng.standard_normal((2, 1024, 128)), jnp.bfloat16)
+        blocks = forward_blocks(q, k, v)
+        assert blocks == (512, 1024)
+        got = flash_forward(q, k, v, True, 0.07, blocks, name="mla_flash_fwd", interpret=True)
+        want = flash_attention_reference(q, k, v, True, 0.07)
+        assert got.dtype == jnp.bfloat16 and got.shape == (2, 1024, 128)
+        np.testing.assert_allclose(np.asarray(got, np.float32), np.asarray(want, np.float32),
+                                   rtol=2e-2, atol=2e-2)
+
+    def test_blocks_at_the_cell_shape(self):
+        """32 heads of 32,768 tokens at 192 / 128 in bfloat16: (1024, 1024) overflows
+        Mosaic's 16 MiB default scope (the chip's compiler refused it), (512, 1024) is
+        taken; 64 x 32 pairs lie inside the SMEM bound. float64 and ragged lengths: none."""
+        q = jax.ShapeDtypeStruct((32, 32768, 192), jnp.bfloat16)
+        v = jax.ShapeDtypeStruct((32, 32768, 128), jnp.bfloat16)
+        assert forward_blocks(q, q, v) == (512, 1024)
+        ragged = jax.ShapeDtypeStruct((32, 32767, 192), jnp.bfloat16)
+        assert forward_blocks(ragged, ragged, jax.ShapeDtypeStruct((32, 32767, 128), jnp.bfloat16)) is None
+        wide = jax.ShapeDtypeStruct((1, 1024, 192), jnp.float64)
+        assert forward_blocks(wide, wide, wide) is None
+
+    def test_gradient_is_refused(self):
+        """The backward kernels take one head width: the custom_vjp's backward says so."""
+        from heat_tpu.core.kernels.flash_attention import _bwd
+
+        q = jnp.ones((1, 512, 64), jnp.float32)
+        v = jnp.ones((1, 512, 128), jnp.float32)
+        residuals = (q, q, v, v, jnp.ones((1, 512), jnp.float32), None)
+        with pytest.raises(NotImplementedError, match="one head width"):
+            _bwd(True, 0.1, residuals, v)
 
 
 class TestFlashBackward:
