@@ -195,8 +195,10 @@ def phase_matmul(n: int = 32768, block: int = 256, platform: str = "tpu") -> dic
 def phase_kmeans(n: int = 10_000_000, d: int = 64, k: int = 8, iters: int = 5,
                  slab: int = 65536, platform: str = "tpu", interpret: bool = False) -> dict:
     """North-star 3: ``ht.cluster.KMeans.fit``. The Lloyd program must carry the
-    fused Pallas step (its gates fall back to jnp silently), and the kernel must
-    agree with ``fused_assign_update_reference`` on a sampled slab."""
+    fused Pallas step (a gate that declines is counted as ``fallback.cluster.kmeans``,
+    but the fit still succeeds), and the kernel must agree with
+    ``fused_assign_update_reference`` on a sampled slab. 10M rows are no multiple of
+    the kernel's block: the tail is masked in the kernel, nothing is padded."""
     import jax
     import jax.numpy as jnp
     import numpy as np

@@ -125,8 +125,10 @@ class _KCluster(ClusteringMixin, BaseEstimator):
     def _fused_step(self, x: DNDarray):
         """Optional fused assignment+update (Pallas) for the Lloyd body.
 
-        Returns ``fn(xv, centers) -> (labels, sums, counts, sse)`` or ``None`` to use
-        the generic jnp body. Subclasses override where a kernel exists (KMeans)."""
+        Returns ``fn(xv, centers, with_labels)`` giving ``(labels, sums, counts, sse)``
+        or, with ``with_labels=False``, ``(sums, counts)``; a ``str`` saying why a fit
+        the kernel is meant for falls to the generic jnp body; or ``None`` where no
+        kernel applies. Subclasses override where a kernel exists (KMeans)."""
         return None
 
     def fit(self, x: DNDarray):
@@ -165,11 +167,17 @@ class _KCluster(ClusteringMixin, BaseEstimator):
         """The jitted whole-fit Lloyd program, cached per
         (estimator class, k, max_iter, tol, metric, fused?) so repeated fits hit XLA's
         compilation cache instead of re-tracing a fresh closure every call."""
-        fused = self._fused_step(x) if x.split in (None, 0) else None
+        fused = self._fused_step(x)
+        if callable(fused) and x.split not in (None, 0):
+            fused = f"split={x.split}"
+        declined = fused if isinstance(fused, str) else None
+        if declined:
+            fused = None
         # the fused closure bakes in the comm's mesh/axis (shard_map variant), so the
-        # cache key must carry that configuration, not just "fused or not"
+        # cache key must carry that configuration, not just "fused or not"; a declined
+        # fit's program records its reason, so the reason is its key
         if fused is None:
-            fused_kind = None
+            fused_kind = declined
         elif x.split is None or x.comm.size == 1:
             fused_kind = "plain"
         else:
@@ -189,6 +197,7 @@ class _KCluster(ClusteringMixin, BaseEstimator):
         import jax
         from jax import lax
 
+        from ..core.kernels import kmeans as kmeans_kernel
         from ..spatial.distance import _pairwise
 
         metric_kind = self._metric_kind
@@ -197,16 +206,27 @@ class _KCluster(ClusteringMixin, BaseEstimator):
 
         @jax.jit
         def lloyd(xv, centers0):
+            # trace time only: how often the program was traced, and why a float32 fit
+            # on a TPU did not get the kernel (nothing per call, nothing in the program)
+            diagnostics.counter("cluster.fit.traces")
+            step, why = fused, declined
+            if step is not None:
+                why = kmeans_kernel.decline_reason(xv.shape[1], centers0.shape[0])
+                step = None if why else step
+            if why:
+                diagnostics.record_fallback("cluster.kmeans", why)
+
             def cond(state):
                 i, _, shift = state
                 return jnp.logical_and(i < max_iter, shift > tol)
 
             def body(state):
                 i, centers, _ = state
-                if fused is not None:
+                if step is not None:
                     # one streaming pass: distances, argmin, and the segment sums
-                    # never leave VMEM (core/kernels/kmeans.py)
-                    _, sums, counts, _ = fused(xv, centers)
+                    # never leave VMEM, and nothing of n elements is written
+                    # (core/kernels/kmeans.py)
+                    sums, counts = step(xv, centers, False)
                     new = jnp.where(
                         counts[:, None] > 0,
                         (sums / jnp.maximum(counts[:, None], 1.0)).astype(centers.dtype),
@@ -222,8 +242,8 @@ class _KCluster(ClusteringMixin, BaseEstimator):
             i, centers, _ = lax.while_loop(
                 cond, body, (jnp.int32(0), centers0, jnp.array(jnp.inf, centers0.dtype))
             )
-            if fused is not None:
-                labels, _, _, inertia = fused(xv, centers)
+            if step is not None:
+                labels, _, _, inertia = step(xv, centers, True)
             else:
                 d = _pairwise(xv, centers, metric_kind)
                 labels = jnp.argmin(d, axis=1)

@@ -1,36 +1,107 @@
 """Pallas kernel tests: the fused KMeans assignment must agree with its jnp reference
-(validated in interpreter mode so the same test runs on the CPU mesh)."""
+(validated in interpreter mode so the same test runs on the CPU mesh), the Lloyd
+program must carry the kernel in the form each place needs, and the kernel must compile
+for a described v5e at the block its gate picks."""
+
+import functools
+import os
 
 import numpy as np
+import pytest
 
 import jax
 import jax.numpy as jnp
 
 import heat_tpu as ht
+from heat_tpu.cluster import _kcluster
 from heat_tpu.core.kernels import fused_assign_update, fused_assign_update_reference
-from heat_tpu.core.kernels.kmeans import _fused_pallas
+from heat_tpu.core.kernels import kmeans as kmeans_kernel
+from heat_tpu.core.kernels.kmeans import _block_n, _fused_pallas
 from heat_tpu.testing import TestCase
+
+SHAPES = [(1024, 64, 8), (130, 10, 3), (1500, 7, 5), (1000, 64, 8), (4096 + 37, 64, 8)]
+
+
+def _data(n, d, k, seed=0):
+    rng = np.random.default_rng(seed)
+    x = jnp.asarray(rng.standard_normal((n, d)).astype(np.float32))
+    c = jnp.asarray(rng.standard_normal((k, d)).astype(np.float32))
+    return x, c
+
+
+def _near_ties(x, c, rel=1e-5):
+    """Rows whose two smallest reference distances lie within ``rel`` of each other:
+    there the kernel, which leaves |x|^2 out of the argmin, may round the other way."""
+    x, c = np.asarray(x, np.float64), np.asarray(c, np.float64)
+    d2 = np.sort(((x[:, None, :] - c[None, :, :]) ** 2).sum(-1), axis=1)
+    return (d2[:, 1] - d2[:, 0]) <= rel * np.maximum(d2[:, 1], 1e-30)
+
+
+def _assert_matches_reference(x, c, got):
+    l0, s0, n0, e0 = fused_assign_update_reference(x, c)
+    l1, s1, n1, e1 = got
+    differ = np.asarray(l0) != np.asarray(l1)
+    assert not np.any(differ & ~_near_ties(x, c))
+    if not differ.any():
+        np.testing.assert_array_equal(np.asarray(n0), np.asarray(n1))
+    np.testing.assert_allclose(np.asarray(s0), np.asarray(s1), rtol=1e-4, atol=1e-3)
+    np.testing.assert_allclose(float(e0), float(e1), rtol=1e-4)
+
+
+@pytest.mark.parametrize("block_n", [128, None], ids=["bn128", "bn_default"])
+@pytest.mark.parametrize("n,d,k", SHAPES, ids=lambda v: str(v))
+def test_interpreted_kernel_matches_reference(n, d, k, block_n):
+    """Aligned, smaller than a block, ragged (not a multiple of the block), with k and d
+    off the (8, 128) tile: labels, sums, counts and sse against the jnp reference."""
+    x, c = _data(n, d, k)
+    _assert_matches_reference(x, c, _fused_pallas(x, c, block_n=block_n, interpret=True))
+
+
+@pytest.mark.parametrize("n,d,k", SHAPES, ids=lambda v: str(v))
+def test_loop_form_equals_full_form(n, d, k):
+    """The form a Lloyd iteration calls returns the full form's (sums, counts), bit for bit."""
+    x, c = _data(n, d, k, seed=1)
+    _, s1, n1, _ = _fused_pallas(x, c, block_n=128, interpret=True)
+    s2, n2 = _fused_pallas(x, c, with_labels=False, block_n=128, interpret=True)
+    np.testing.assert_array_equal(np.asarray(s1), np.asarray(s2))
+    np.testing.assert_array_equal(np.asarray(n1), np.asarray(n2))
+
+
+def test_nan_beyond_a_ragged_tail_reaches_nothing():
+    """The last block of a ragged n reaches past the operand; the interpreter fills that
+    part with NaN (asserted here, as the premise), the chip with whatever lies there.
+    Sums, counts and sse stay finite and equal to the reference."""
+    import jax.experimental.pallas as pl
+
+    def copy(x_ref, o_ref):
+        o_ref[:] = x_ref[:]
+
+    block = pl.BlockSpec((8, 128), lambda i: (0, i))
+    probe = pl.pallas_call(copy, grid=(2,), in_specs=[block], out_specs=block,
+                           out_shape=jax.ShapeDtypeStruct((8, 256), jnp.float32),
+                           interpret=True)(jnp.ones((8, 130), jnp.float32))
+    assert np.isnan(np.asarray(probe)[:, 130:]).all()
+
+    x, c = _data(1000, 64, 8, seed=2)
+    for form in (True, False):
+        got = _fused_pallas(x, c, with_labels=form, block_n=512, interpret=True)
+        assert all(np.isfinite(np.asarray(v)).all() for v in got)
+    _assert_matches_reference(x, c, _fused_pallas(x, c, block_n=512, interpret=True))
+    assert float(jnp.sum(_fused_pallas(x, c, with_labels=False, block_n=512, interpret=True)[1])) == 1000
+
+
+def test_identical_centroids_take_the_first_index():
+    """NumPy's tie rule: of two equal centroids the lower index gets every row."""
+    x, c = _data(700, 10, 4, seed=3)
+    c = c.at[2].set(c[0])
+    labels, _, counts, _ = _fused_pallas(x, c, block_n=256, interpret=True)
+    assert not np.any(np.asarray(labels) == 2)
+    assert float(counts[2]) == 0.0
+    np.testing.assert_array_equal(np.asarray(labels),
+                                  np.asarray(fused_assign_update_reference(x, c)[0]))
 
 
 class TestFusedAssignUpdate(TestCase):
-    def _check(self, n, d, k, seed=0):
-        rng = np.random.default_rng(seed)
-        x = jnp.asarray(rng.standard_normal((n, d)).astype(np.float32))
-        c = jnp.asarray(rng.standard_normal((k, d)).astype(np.float32))
-        l0, s0, n0, e0 = fused_assign_update_reference(x, c)
-        l1, s1, n1, e1 = _fused_pallas(x, c, interpret=True)
-        np.testing.assert_array_equal(np.asarray(l0), np.asarray(l1))
-        np.testing.assert_array_equal(np.asarray(n0), np.asarray(n1))
-        np.testing.assert_allclose(np.asarray(s0), np.asarray(s1), rtol=1e-4, atol=1e-3)
-        np.testing.assert_allclose(float(e0), float(e1), rtol=1e-4)
-
-    def test_aligned(self):
-        self._check(1024, 64, 8)
-
-    def test_ragged_and_small(self):
-        self._check(130, 10, 3)  # n < block, unpadded d/k
-        self._check(1500, 7, 5)  # n needs padding
-
     def test_reference_semantics(self):
         """The reference itself matches a plain numpy computation."""
         rng = np.random.default_rng(1)
@@ -48,13 +119,15 @@ class TestFusedAssignUpdate(TestCase):
             )
 
     def test_dispatcher_fallback(self):
-        """On non-TPU backends the dispatcher returns the jnp reference results."""
+        """On non-TPU backends the dispatcher returns the jnp reference results, in
+        either form."""
         if jax.default_backend() == "tpu":
             self.skipTest("fallback path is the non-TPU branch")
-        rng = np.random.default_rng(2)
-        x = jnp.asarray(rng.standard_normal((300, 8)).astype(np.float32))
-        c = jnp.asarray(rng.standard_normal((5, 8)).astype(np.float32))
-        for a, b in zip(fused_assign_update(x, c), fused_assign_update_reference(x, c)):
+        x, c = _data(300, 8, 5, seed=2)
+        ref = fused_assign_update_reference(x, c)
+        for a, b in zip(fused_assign_update(x, c), ref):
+            np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=1e-6)
+        for a, b in zip(fused_assign_update(x, c, with_labels=False), ref[1:3]):
             np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=1e-6)
 
     def test_kmeans_unchanged_on_cpu(self):
@@ -67,6 +140,160 @@ class TestFusedAssignUpdate(TestCase):
         km.fit(x)
         got = np.sort(km.cluster_centers_.numpy(), axis=0)
         np.testing.assert_allclose(got, np.sort(centers, axis=0), atol=0.2)
+
+
+# ------------------------------------------------- the Lloyd program on the CPU mesh
+@pytest.fixture
+def fused_interpret(monkeypatch):
+    """``KMeans.fit`` takes the fused step here, its kernel interpreted; the Lloyd cache
+    starts empty and the counters are on."""
+    monkeypatch.setattr(kmeans_kernel, "available", lambda interpret=False: True)
+    monkeypatch.setattr(kmeans_kernel, "fused_assign_update",
+                        functools.partial(kmeans_kernel.fused_assign_update, interpret=True))
+    monkeypatch.setattr(_kcluster, "_LLOYD_CACHE", {})
+    was_on = ht.diagnostics.enabled()
+    ht.diagnostics.enable()
+    ht.diagnostics.reset()
+    yield
+    ht.diagnostics.reset()
+    if not was_on:
+        ht.diagnostics.disable()
+
+
+def _counter(name):
+    return ht.diagnostics.report()["counters"].get(name, 0)
+
+
+def _blobs(n, d=4, k=3, seed=3):
+    rng = np.random.default_rng(seed)
+    centers = rng.normal(0, 10, (k, d)).astype(np.float32)
+    return centers[rng.integers(0, k, n)] + rng.normal(0, 0.3, (n, d)).astype(np.float32), centers
+
+
+def _pallas_calls(jaxpr, in_loop=False):
+    """``(equation, inside a while?)`` of every ``pallas_call`` under ``jaxpr``, through
+    pjit, shard_map and the loop's own body."""
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            yield eqn, in_loop
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            yield from _pallas_calls(sub, in_loop or eqn.primitive.name == "while")
+
+
+@pytest.mark.parametrize("split", [None, 0])
+def test_the_loop_writes_no_labels_and_the_last_call_does(fused_interpret, split):
+    """In the Lloyd program the kernel inside the ``while`` body has no output of a
+    row's size; the call after the loop returns the labels. The fit agrees with the
+    generic path's on well separated blobs."""
+    n = 128 * ht.get_comm().size * 2
+    data, centers = _blobs(n)
+    x = ht.array(data, split=split)
+    km = ht.cluster.KMeans(n_clusters=3, init="kmeans++", max_iter=50, random_state=0)
+    km.fit(x)
+    np.testing.assert_allclose(np.sort(km.cluster_centers_.numpy(), axis=0),
+                               np.sort(centers, axis=0), atol=0.2)
+    assert km.labels_.shape == (n,)
+
+    rows = n if split is None else n // ht.get_comm().size
+    jaxpr = jax.make_jaxpr(km._lloyd_fn(x))(x.larray, km.cluster_centers_.larray).jaxpr
+    calls = list(_pallas_calls(jaxpr))
+    inside = [eqn for eqn, in_loop in calls if in_loop]
+    after = [eqn for eqn, in_loop in calls if not in_loop]
+    assert len(inside) == 1 and len(after) == 1
+    assert all(int(np.prod(v.aval.shape)) < rows for v in inside[0].outvars)
+    assert any(v.aval.shape == (1, rows) and v.aval.dtype == jnp.int32 for v in after[0].outvars)
+
+
+def test_traces_are_counted_once_for_one_shape(fused_interpret):
+    data, _ = _blobs(512)
+    x = ht.array(data, split=None)
+    for _ in range(2):
+        ht.cluster.KMeans(n_clusters=3, init="random", max_iter=5, random_state=0).fit(x)
+    assert _counter("cluster.fit.traces") == 1
+    assert _counter("fallback.cluster.kmeans") == 0
+    ht.cluster.KMeans(n_clusters=3, init="random", max_iter=5, random_state=0).fit(
+        ht.array(data[:256], split=None))
+    assert _counter("cluster.fit.traces") == 2
+
+
+@pytest.mark.parametrize("case", ["vmem", "ragged_shards", "float64"])
+def test_a_declined_float32_fit_says_why(fused_interpret, case):
+    """A gate that sends a float32 fit to the jnp body records ``fallback.cluster.kmeans``
+    with its reason, at trace time; a float64 fit was never the kernel's and records none."""
+    size = ht.get_comm().size
+    if case == "vmem":  # no 128-row block of 20,000 features fits the kernel's budget
+        x = ht.array(np.random.default_rng(0).normal(size=(32, 20000)).astype(np.float32))
+    elif case == "ragged_shards":
+        if size == 1:
+            pytest.skip("one device has no ragged shard")
+        x = ht.array(_blobs(128 * size + 1)[0], split=0)
+    else:
+        x = ht.array(_blobs(256)[0].astype(np.float64), split=None)
+    km = ht.cluster.KMeans(n_clusters=3, init="random", max_iter=2, random_state=0)
+    km.fit(x)
+    km.fit(x)  # the second fit traces nothing and records nothing
+    assert _counter("cluster.fit.traces") == 1
+    events = ht.diagnostics.report()["fallback_events"]
+    if case == "float64":
+        assert _counter("fallback.cluster.kmeans") == 0
+    else:
+        assert _counter("fallback.cluster.kmeans") == 1
+        assert events[-1]["site"] == "cluster.kmeans"
+        assert ("VMEM" if case == "vmem" else "ragged shards") in events[-1]["reason"]
+
+
+# ------------------------- the kernel compiled for a described v5e (no chip needed)
+@pytest.fixture(scope="module")
+def one_chip():
+    """A described v5e device to compile for; the TPU compiler is loaded by the first
+    test that asks, never at import (one process at a time may hold libtpu)."""
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+    from jax.sharding import SingleDeviceSharding
+
+    try:
+        topo = topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 -- no TPU compiler here: nothing to test
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    # an AOT compile for an absent chip is written to the persistent cache but cannot
+    # be read back without one: keep it out
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", True)
+    compilation_cache.reset_cache()
+
+
+@pytest.mark.parametrize("d,k", [(64, 8), (7, 5), (128, 256), (1024, 16), (4096, 8), (16, 1024)],
+                         ids=lambda v: str(v))
+def test_mosaic_compiles_the_block_the_gate_picks(one_chip, d, k):
+    """Both forms, ragged n, at ``_block_n(d, k)``: the gate says no before Mosaic does,
+    and the operand enters as a bitcast (no copy of n x d elements in the program)."""
+    bn = _block_n(d, k)
+    assert bn is not None
+    n = 3 * bn + 37
+    x = jax.ShapeDtypeStruct((n, d), jnp.float32, sharding=one_chip)
+    c = jax.ShapeDtypeStruct((k, d), jnp.float32, sharding=one_chip)
+    for with_labels in (False, True):
+        # the serving configuration sets the process-wide default to "highest": the
+        # kernel states the precision of each contraction and must compile under it
+        with jax.default_matmul_precision("highest" if with_labels else "default"):
+            compiled = jax.jit(functools.partial(_fused_pallas, with_labels=with_labels)).lower(x, c).compile()
+        text = compiled.as_text()
+        assert "tpu_custom_call" in text
+        assert compiled.memory_analysis().temp_size_in_bytes < 4 * n
+
+
+def test_the_gate_declines_what_mosaic_would_refuse(one_chip):
+    assert _block_n(64, 8) == 8192  # the benchmark's shape: a 2 MiB block, 2,048 steps a pass
+    for d, k in [(20000, 8), (1024, 1024)]:
+        assert _block_n(d, k) is None
+        assert "VMEM" in kmeans_kernel.decline_reason(d, k)
+        x = jax.ShapeDtypeStruct((3 * 128 + 37, d), jnp.float32, sharding=one_chip)
+        c = jax.ShapeDtypeStruct((k, d), jnp.float32, sharding=one_chip)
+        with pytest.raises(Exception, match="(?i)vmem|memory"):
+            jax.jit(functools.partial(_fused_pallas, block_n=128)).lower(x, c).compile()
 
 
 if __name__ == "__main__":
