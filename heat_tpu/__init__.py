@@ -10,12 +10,6 @@ hand-written collective choreography. Usage mirrors the reference::
     x.sum()
 """
 
-import jax as _jax
-
-# float64/complex128/int64 availability (the reference supports f64 via torch); the
-# *default* float stays float32 — factories pass explicit dtypes everywhere.
-_jax.config.update("jax_enable_x64", True)
-
 # The reference computes every matmul in full fp32/fp64 (torch on CPU/GPU). TPU MXUs
 # default to bf16-input passes — fast, and the right default for the framework's bulk
 # compute path. fp32-sensitive algorithms (QR, hSVD, CG/Lanczos, cdist's quadratic

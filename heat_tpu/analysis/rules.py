@@ -128,6 +128,17 @@ RULES = {
         "belong inside functions. "
         "tests/test_analysis.py proves the same contract dynamically."
     ),
+    "import-backend-touch": (
+        "A process can join a jax.distributed job only while it has no XLA "
+        "backend. heat_tpu.core therefore calls _bootstrap.run() before it "
+        "imports anything else, and the modules that load before that call "
+        "(_bootstrap and what it imports at module level) make no "
+        "module-level call of jax.devices / default_backend / local_devices "
+        "/ device_count / process_count / process_index / "
+        "distributed.initialize. One early import broke every multi-process "
+        "launch from PR 21 to PR 28. tests/test_analysis.py imports the "
+        "package with the backend factory patched to prove the same order."
+    ),
     "silent-except": (
         "except Exception without re-raise or a diagnostics.record_fallback/"
         "record_resilience_event/fallback_after_failure call swallows "

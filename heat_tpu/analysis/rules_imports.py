@@ -10,6 +10,14 @@ fine (``resilience`` imports ``diagnostics``); anything else — ``jax``,
 ``numpy``, the package itself — at module level is an error. Imports inside
 function bodies are the sanctioned lazy form and are not flagged (unless the
 function is a traced body — that is ``trace-lazy-import``'s job).
+
+``import-backend-touch`` guards the order of the package bring-up
+(``core/_bootstrap.py``): ``heat_tpu.core`` calls ``_bootstrap.run()`` before
+it imports anything else, and no module that loads before that call has
+joined ``jax.distributed`` (``_bootstrap`` and what it imports at module
+level) may create the XLA backend at module level. The runtime twin in
+``tests/test_analysis.py`` imports the package with JAX's backend factory
+patched to raise until the distributed client exists.
 """
 
 from __future__ import annotations
@@ -17,7 +25,7 @@ from __future__ import annotations
 import ast
 from typing import List, Set
 
-from .engine import Finding, ModuleIndex, Universe, is_stdlib
+from .engine import Finding, ModuleIndex, Universe, dotted_chain, is_stdlib
 
 # The stdlib-only-at-load set (module docstrings state the contract).
 STDLIB_ONLY: Set[str] = {
@@ -61,7 +69,7 @@ def _toplevel_imports(mod: ModuleIndex):
 
 
 def run(uni: Universe) -> List[Finding]:
-    out: List[Finding] = []
+    out: List[Finding] = _run_bring_up(uni)
     for name in sorted(STDLIB_ONLY | {
         m for m in uni.modules if m.startswith(_ANALYSIS_PREFIX)
     }):
@@ -102,4 +110,69 @@ def _check_import(uni: Universe, mod: ModuleIndex, node: ast.AST) -> List[Findin
             f"{mod.name} is stdlib-only at module load but imports "
             f"{target!r} at top level",
         ))
+    return out
+
+
+_BOOTSTRAP = "heat_tpu.core._bootstrap"
+# jax.<chain>() calls that create the XLA backend (or must come before it)
+_BACKEND_CALLS = {
+    ("devices",), ("default_backend",), ("local_devices",), ("device_count",),
+    ("local_device_count",), ("process_count",), ("process_index",),
+    ("distributed", "initialize"),
+}
+
+
+def _load_time_calls(mod: ModuleIndex):
+    """Call nodes that run when the module loads (function bodies excluded)."""
+    stack = list(mod.tree.body)
+    while stack:
+        node = stack.pop()
+        if isinstance(node, (ast.FunctionDef, ast.AsyncFunctionDef, ast.Lambda)):
+            continue
+        if isinstance(node, ast.Call):
+            yield node
+        stack.extend(ast.iter_child_nodes(node))
+
+
+def _run_bring_up(uni: Universe) -> List[Finding]:
+    core = uni.modules.get("heat_tpu.core")
+    if core is None or _BOOTSTRAP not in uni.modules:
+        return []
+    out: List[Finding] = []
+    body = [s for s in core.tree.body
+            if not (isinstance(s, ast.Expr) and isinstance(s.value, ast.Constant))]
+    head = body[:2] + [None, None]
+    call = getattr(head[1], "value", None)
+    if not (isinstance(head[0], ast.ImportFrom)
+            and [a.name for a in head[0].names] == ["_bootstrap"]
+            and isinstance(call, ast.Call)
+            and dotted_chain(call.func) == ("_bootstrap", "run")):
+        out.append(core.finding(
+            "import-backend-touch", head[0] or core.tree,
+            "heat_tpu.core must import _bootstrap and call _bootstrap.run() "
+            "before it imports anything else",
+        ))
+    early, todo = {"heat_tpu", "heat_tpu.core"}, [_BOOTSTRAP]
+    while todo:  # what _bootstrap pulls in at module level loads before run()
+        name = todo.pop()
+        if name in early or name not in uni.modules:
+            continue
+        early.add(name)
+        for node in _toplevel_imports(uni.modules[name]):
+            if isinstance(node, ast.Import):
+                todo.extend(alias.name for alias in node.names)
+            elif (target := uni.modules[name]._resolve_from(node)) is not None:
+                todo.append(target)
+                todo.extend(f"{target}.{alias.name}" for alias in node.names)
+    for name in sorted(early & set(uni.modules)):
+        mod = uni.modules[name]
+        for call in _load_time_calls(mod):
+            chain = dotted_chain(call.func)
+            if (chain and mod.module_aliases.get(chain[0]) == "jax"
+                    and chain[1:] in _BACKEND_CALLS):
+                out.append(mod.finding(
+                    "import-backend-touch", call,
+                    f"{name} loads before the bring-up has joined "
+                    f"jax.distributed but calls {'.'.join(chain)}() at module level",
+                ))
     return out

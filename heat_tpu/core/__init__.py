@@ -1,5 +1,10 @@
 """heat_tpu core: distributed n-D arrays over JAX/XLA (reference heat/core/__init__.py)."""
 
+from . import _bootstrap
+
+# the bring-up: nothing above this call may touch the XLA backend (``import-backend-touch``)
+_bootstrap.run()
+
 from . import diagnostics
 from . import profiler
 from . import forensics

@@ -42,12 +42,8 @@ module closes that gap with two cooperating layers (ISSUE 15):
    records the manifest (and artifacts) from a warm process.
 
 **JAX's own persistent compilation cache** is always on and is placed once,
-at package import (:func:`_place_jax_cache`): where
-``JAX_COMPILATION_CACHE_DIR`` is set the operator has placed it and no code
-touches ``jax_compilation_cache_dir``; otherwise it lives at
-:data:`JAX_CACHE_DIR`, one fixed path inside the checkout.  The directory is
-part of JAX's cache key, so it is never a temp name, a pid or a time.  JAX's
-own size/time thresholds decide what persists.  ``HEAT_TPU_EXEC_CACHE`` is
+by the package bring-up (``_bootstrap.place_jax_cache``).  JAX's own
+size/time thresholds decide what persists.  ``HEAT_TPU_EXEC_CACHE`` is
 memoised at import; :func:`reload` (called from ``ht.reload_env_knobs`` /
 ``clear_executor_cache``) is the documented re-read point for in-process
 flips.
@@ -79,7 +75,7 @@ from jax.experimental import serialize_executable as _se
 from . import diagnostics, resilience
 
 __all__ = [
-    "CompileCacheCorrupt", "JAX_CACHE_DIR", "armed", "cache_dir", "reload",
+    "CompileCacheCorrupt", "armed", "cache_dir", "reload",
     "load_program", "executor_save_warmup", "executor_warmup",
 ]
 
@@ -104,21 +100,6 @@ _lock = threading.Lock()
 _dir: Optional[str] = None
 _index: Optional[Dict[str, Any]] = None   # fingerprint -> entry (lazy-loaded)
 _index_rejected = False                   # corrupt index: stop retrying reads
-
-#: where JAX's persistent compilation cache lives unless the operator placed it
-#: with ``JAX_COMPILATION_CACHE_DIR``: ``<checkout>/.jax_cache`` (gitignored)
-JAX_CACHE_DIR = os.path.join(
-    os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__)))),
-    ".jax_cache",
-)
-
-
-def _place_jax_cache() -> None:
-    """Place JAX's persistent compilation cache (module docstring): nothing is
-    set in code where ``JAX_COMPILATION_CACHE_DIR`` is set, :data:`JAX_CACHE_DIR`
-    otherwise."""
-    if not os.environ.get("JAX_COMPILATION_CACHE_DIR"):
-        jax.config.update("jax_compilation_cache_dir", JAX_CACHE_DIR)
 
 
 def reload() -> None:
@@ -616,5 +597,4 @@ def _aot_load_count() -> int:
 
 # memoise the knobs at import (a fresh process needs nothing extra; in-process
 # flips re-read through reload(), wired into ht.reload_env_knobs)
-_place_jax_cache()
 reload()

@@ -18,25 +18,24 @@ remains of the communication layer is therefore small and explicit:
   usable *inside* ``jax.shard_map`` blocks for algorithms with explicit communication
   schedules (hSVD merge tree, ring cdist, TSQR).
 
-Multi-host bootstrap is ``jax.distributed.initialize`` instead of ``mpirun`` — see
-:func:`initialize`.
+Multi-host bootstrap is ``jax.distributed.initialize`` instead of ``mpirun``, driven
+by the environment contract of ``_bootstrap`` at import — see :func:`initialize`.
 """
 
 from __future__ import annotations
 
 import math
+import os
 import time
 from typing import List, Optional, Sequence, Tuple
 
 import numpy as np
 
-import os
-
 import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec
 
-from . import diagnostics, forensics, profiler, resilience, supervision, telemetry
+from . import _bootstrap, diagnostics, forensics, profiler, resilience, supervision, telemetry
 from .devices import require_device_dtype
 
 
@@ -96,48 +95,6 @@ def _guarded_run(site, fn, *args, **kwargs):
     if resilience._active:
         return resilience.guard(site, fn, *args, **kwargs)
     return fn(*args, **kwargs)
-
-# Multi-controller bootstrap must run BEFORE anything touches the XLA backend —
-# and importing heat_tpu itself does (the COMM_WORLD mesh below calls
-# jax.devices()). The launcher therefore passes the coordination parameters by
-# environment, the TPU-native analogue of mpirun's environment contract:
-#
-#   HEAT_TPU_COORDINATOR_ADDRESS=host:port \
-#   HEAT_TPU_NUM_PROCESSES=N HEAT_TPU_PROCESS_ID=i python program.py
-#
-# Programs that want to call :func:`initialize` explicitly must do so before
-# importing heat_tpu (i.e. call jax.distributed.initialize themselves).
-if os.environ.get("HEAT_TPU_COORDINATOR_ADDRESS"):
-    _missing = [
-        name
-        for name in ("HEAT_TPU_NUM_PROCESSES", "HEAT_TPU_PROCESS_ID")
-        if not os.environ.get(name)
-    ]
-    if _missing:
-        raise RuntimeError(
-            "HEAT_TPU_COORDINATOR_ADDRESS is set but "
-            f"{' and '.join(_missing)} {'is' if len(_missing) == 1 else 'are'} not; "
-            "the multi-controller launch contract needs all three of "
-            "HEAT_TPU_COORDINATOR_ADDRESS, HEAT_TPU_NUM_PROCESSES, "
-            "HEAT_TPU_PROCESS_ID"
-        )
-    if jax._src.distributed.global_state.client is None:  # not already initialized
-        if supervision.enabled():
-            # the supervised runtime: identical observable bootstrap, but
-            # XLA's fail-stop error propagation is disabled — peer-failure
-            # detection, typed delivery, and elastic restart belong to
-            # ht.supervision (see its module header)
-            supervision.bootstrap_distributed(
-                os.environ["HEAT_TPU_COORDINATOR_ADDRESS"],
-                int(os.environ["HEAT_TPU_NUM_PROCESSES"]),
-                int(os.environ["HEAT_TPU_PROCESS_ID"]),
-            )
-        else:
-            jax.distributed.initialize(
-                coordinator_address=os.environ["HEAT_TPU_COORDINATOR_ADDRESS"],
-                num_processes=int(os.environ["HEAT_TPU_NUM_PROCESSES"]),
-                process_id=int(os.environ["HEAT_TPU_PROCESS_ID"]),
-            )
 
 __all__ = [
     "Communication",
@@ -732,15 +689,11 @@ def _pad_reshard(
     return _guarded("comm.reshard", fn, array)
 
 
-# Every bootstrap (import, then each explicit initialize() — and each elastic
-# restart) gets its own barrier id + KV namespace: coordination KV keys are
-# namespace-scoped per use, and SPMD symmetry keeps the counter in step on
-# every process, so a re-init re-anchors instead of failing the handshake.
-# The wait budget is the unified HEAT_TPU_COORD_TIMEOUT_MS knob
-# (supervision.coord_timeout_ms — replacing the old hardcoded 60 s here and
-# 600 s in checkpoint), and every wait goes through the supervised wrappers:
-# bounded, sentinel-abortable, and typed (resilience.CoordinationTimeout /
-# PeerFailed) instead of an opaque backend error.
+# Every build_world() gets its own barrier id + KV namespace: coordination KV keys
+# are namespace-scoped per use, and SPMD symmetry keeps the counter in step on every
+# process, so a re-join re-anchors instead of failing the handshake. Every wait goes
+# through the supervised wrappers: bounded (supervision.coord_timeout_ms),
+# sentinel-abortable, and typed (resilience.CoordinationTimeout / PeerFailed).
 _handshake_generation = 0
 
 
@@ -760,19 +713,13 @@ def _telemetry_bootstrap() -> None:
     and this process's rank is stamped for ``rank``-targeted fault plans."""
     global _handshake_generation
     try:
-        telemetry.set_process_info(jax.process_index(), jax.process_count())
-        resilience.set_fault_rank(jax.process_index())
-        if (
-            jax.process_count() > 1
-            and os.environ.get("HEAT_TPU_TELEMETRY_HANDSHAKE") != "0"
-        ):
-            client = jax._src.distributed.global_state.client
-            if client is None:
-                raise RuntimeError("jax.distributed client not initialized")
-            co = supervision.ClientCoordinator(client)
+        index, nprocs = jax.process_index(), jax.process_count()
+        telemetry.set_process_info(index, nprocs)
+        resilience.set_fault_rank(index)
+        if nprocs > 1 and os.environ.get("HEAT_TPU_TELEMETRY_HANDSHAKE") != "0":
+            co = supervision._require_coordinator()
             gen = _handshake_generation
-            _handshake_generation += 1  # ht: ignore[lock-racing-increment] -- bootstrap-only: runs at module import and inside initialize(), both single-threaded launch paths; SPMD symmetry (not thread-safety) is what keeps the counter aligned
-            index = jax.process_index()
+            _handshake_generation += 1  # ht: ignore[lock-racing-increment] -- bootstrap-only: build_world() runs at package import, in initialize() and in the elastic restart, all single-threaded launch paths; SPMD symmetry (not thread-safety) is what keeps the counter aligned
             # boot-time liveness wait, capped at the old 60 s handshake
             # budget: the supervision plane is not armed yet (auto_arm runs
             # after the handshake), so a peer that died pre-handshake cannot
@@ -784,7 +731,7 @@ def _telemetry_bootstrap() -> None:
             boot_ms = min(supervision.coord_timeout_ms(), 60_000)
             supervision.kv_barrier(
                 f"heat_tpu/telemetry/clock/{gen}",
-                nprocs=jax.process_count(), rank=index, timeout_ms=boot_ms,
+                nprocs=nprocs, rank=index, timeout_ms=boot_ms,
                 site="telemetry.handshake", coordinator=co,
             )
             anchor = time.monotonic_ns()
@@ -794,7 +741,7 @@ def _telemetry_bootstrap() -> None:
                     f"heat_tpu/telemetry/anchor/{gen}/{i}", boot_ms,
                     site="telemetry.handshake", coordinator=co,
                 ))
-                for i in range(jax.process_count())
+                for i in range(nprocs)
             ]
             telemetry.record_clock_anchor(anchor, anchors)
     except Exception as exc:
@@ -808,18 +755,24 @@ def _telemetry_bootstrap() -> None:
 
 
 # --------------------------------------------------------------------------- singletons
-COMM_WORLD: MeshCommunication = MeshCommunication()
+COMM_WORLD: MeshCommunication
 """World communicator over all visible devices (reference ``MPI_WORLD`` ``communication.py:2013``)."""
 
-COMM_SELF: MeshCommunication = MeshCommunication(jax.devices()[:1])
+COMM_SELF: MeshCommunication
 """Single-device communicator (reference ``MPI_SELF`` ``communication.py:2014``)."""
 
-# The env-contract bootstrap (module top) has already initialised
-# jax.distributed by this point, so rank identity and the clock handshake can
-# be stamped into the telemetry plane for every launch path.
-_telemetry_bootstrap()
 
-__default_comm = COMM_WORLD
+def build_world() -> None:
+    """(Re)build what this module derives from the world: the communicators, the
+    padding programs compiled for the old mesh, and the telemetry stamp / clock
+    handshake (which ends in ``supervision.auto_arm()``). Steps (d) and (e) of
+    ``_bootstrap.run()``, and the tail of :func:`initialize` and of an elastic restart."""
+    global COMM_WORLD, COMM_SELF, __default_comm
+    COMM_WORLD = MeshCommunication()
+    COMM_SELF = MeshCommunication(jax.devices()[:1])
+    __default_comm = COMM_WORLD
+    _pad_cache.clear()
+    _telemetry_bootstrap()
 
 
 def get_comm() -> MeshCommunication:
@@ -847,15 +800,16 @@ def sanitize_comm(comm: Optional[Communication]) -> MeshCommunication:
 
 
 def initialize(**kwargs) -> None:
-    """Multi-host bootstrap: ``jax.distributed.initialize`` replaces the mpirun launcher
-    (reference launches via ``mpirun -np N python script.py``, ``scripts/heat_test.py:1-9``).
+    """Join a ``jax.distributed`` job (keywords of ``jax.distributed.initialize``,
+    which replaces the reference's ``mpirun -np N python script.py``) and rebuild the
+    world singletons over it: steps (c) to (e) of ``_bootstrap.run``.
 
-    NOTE: must run before anything initialises the XLA backend — and importing
-    ``heat_tpu`` does. The supported launch paths are therefore (a) the
-    ``HEAT_TPU_COORDINATOR_ADDRESS`` / ``HEAT_TPU_NUM_PROCESSES`` /
-    ``HEAT_TPU_PROCESS_ID`` environment contract, honoured automatically at
-    import (see module header), or (b) calling ``jax.distributed.initialize``
-    yourself before the first ``import heat_tpu``.
+    This is NOT how a job is started: a process can join only while it has no XLA
+    backend, and ``import heat_tpu`` creates one (a ``RuntimeError`` here says so). The
+    launch path is the ``HEAT_TPU_COORDINATOR_ADDRESS`` / ``HEAT_TPU_NUM_PROCESSES`` /
+    ``HEAT_TPU_PROCESS_ID`` environment, honoured at import (``_bootstrap``). This call
+    is for the re-join after ``supervision.teardown_distributed`` dropped the backend
+    (the elastic restart); ``_bootstrap.join`` says when the runtime is supervised.
 
     Multi-controller contract (every process runs the same program, SPMD):
 
@@ -867,25 +821,6 @@ def initialize(**kwargs) -> None:
       ``ht.load*`` reads the file on every process (shared filesystem assumed, like
       the reference's MPI-IO setups) and populates only addressable shards;
     - per-process ingest of pre-distributed data uses ``ht.array(..., is_split=k)``.
-
-    With the supervision plane enabled (the default) and the full explicit
-    coordination triple given, the runtime is built in SUPERVISED mode
-    (``supervision.bootstrap_distributed``): observably identical, but peer
-    failures deliver typed errors instead of XLA's process-terminating
-    fail-stop, and elastic restart (``ht.resilience.run_supervised``) becomes
-    possible. Auto-detected launches (TPU/Slurm args omitted) keep the stock
-    ``jax.distributed.initialize`` path.
     """
-    explicit = {"coordinator_address", "num_processes", "process_id"}
-    if supervision.enabled() and explicit.issubset(kwargs):
-        supervision.bootstrap_distributed(
-            kwargs["coordinator_address"], int(kwargs["num_processes"]),
-            int(kwargs["process_id"]),
-        )
-    else:
-        jax.distributed.initialize(**kwargs)
-    global COMM_WORLD, COMM_SELF, __default_comm
-    COMM_WORLD = MeshCommunication()
-    COMM_SELF = MeshCommunication(jax.devices()[:1])
-    __default_comm = COMM_WORLD
-    _telemetry_bootstrap()
+    _bootstrap.join(**kwargs)
+    build_world()

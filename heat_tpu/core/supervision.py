@@ -1134,25 +1134,22 @@ def _drain_scheduler(timeout_s: float) -> None:
         raise
 
 
-def _reanchor_framework() -> None:
-    """Rebuild every world-size-derived singleton after a re-init: the
-    communicators, the executor's program/signature caches and memoised
-    process-count, the checkpoint coordination counters, and the telemetry
-    identity/clock handshake for the new generation."""
-    import jax
-
+def _reanchor_framework(spec: Optional[dict]) -> None:
+    """After the teardown: join the new generation's job (``spec``; None stays
+    single-process), which rebuilds communication's world and ends in
+    :func:`auto_arm`; then drop what else the old world left: the executor's
+    caches and memoised process-count, the checkpoint coordination counters."""
     from . import _executor, checkpoint, communication
 
-    communication.COMM_WORLD = communication.MeshCommunication()
-    communication.COMM_SELF = communication.MeshCommunication(jax.devices()[:1])
-    communication.use_comm(None)
-    communication._pad_cache.clear()
+    if spec is not None:
+        communication.initialize(**spec)
+    else:
+        communication.build_world()
     _executor.clear_executor_cache()
     _executor._single_controller = None
     with checkpoint._state_lock:
         checkpoint._coord_seq = 0
         checkpoint._coord_my_keys.clear()
-    communication._telemetry_bootstrap()
     _executor._get_scheduler().reopen()
 
 
@@ -1179,13 +1176,8 @@ def elastic_restart(exc: BaseException, *, reinit=None,
     spec = reinit(exc) if reinit is not None else None
     if had_client:
         teardown_distributed(clean=False)
-    if spec is not None:
-        bootstrap_distributed(
-            spec["coordinator_address"], int(spec["num_processes"]),
-            int(spec["process_id"]),
-        )
     if had_client or spec is not None:
-        _reanchor_framework()  # ends in the telemetry bootstrap → auto_arm()
+        _reanchor_framework(spec)
     else:
         from . import _executor
 

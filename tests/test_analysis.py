@@ -16,9 +16,11 @@ Three layers, per the checker's own contract:
   violations (an unlocked write to locked diagnostics state; a top-level
   ``import jax`` in ``resilience.py``) must fail with the right rule ids.
 
-Plus the runtime twin of the import contract: a subprocess loads every
+Plus the runtime twins of the import contracts: a subprocess loads every
 stdlib-only module by file path under a ``sys.meta_path`` hook that raises on
-any ``jax``/``numpy``/``jaxlib`` import, proving the contract dynamically.
+any ``jax``/``numpy``/``jaxlib`` import, and another imports the package under
+the multi-controller launch contract with JAX's backend factory patched to
+raise until the process has joined ``jax.distributed``.
 """
 
 from __future__ import annotations
@@ -314,6 +316,62 @@ class TestImportContractRule(unittest.TestCase):
                 diagnostics = None
         """})
         self.assertNotIn("import-nonstdlib", rule_ids(good))
+
+
+class TestBringUpOrderRule(unittest.TestCase):
+    """``import-backend-touch``: the static twin of
+    ``TestRuntimeImportContract.test_no_backend_touch_before_distributed_join``."""
+
+    DEVICES = """
+        import jax
+
+        _default_platform = jax.default_backend()
+    """
+    BOOTSTRAP = """
+        import jax
+
+        def run():
+            jax.distributed.initialize()
+            from . import devices
+    """
+    CORE_INIT = """
+        from . import _bootstrap
+
+        _bootstrap.run()
+
+        from . import devices
+    """
+
+    def test_early_module_touching_backend_fails(self):
+        # the PR 21 shape: a module the bring-up loads before the join reads
+        # the backend at module level
+        bad = run_fixture({
+            "core/__init__.py": self.CORE_INIT,
+            "core/_bootstrap.py": "from .devices import x\n" + textwrap.dedent(self.BOOTSTRAP),
+            "core/devices.py": self.DEVICES,
+        })
+        hits = [f for f in bad if f.rule == "import-backend-touch"]
+        self.assertEqual([(f.path, f.line) for f in hits],
+                         [("heat_tpu/core/devices.py", 4)], bad)
+        good = run_fixture({
+            "core/__init__.py": self.CORE_INIT,
+            "core/_bootstrap.py": self.BOOTSTRAP,
+            "core/devices.py": self.DEVICES,  # imported after run(): may read it
+        })
+        self.assertNotIn("import-backend-touch", rule_ids(good))
+
+    def test_import_before_the_bring_up_call_fails(self):
+        bad = run_fixture({
+            "core/__init__.py": """
+                from . import devices
+                from . import _bootstrap
+
+                _bootstrap.run()
+            """,
+            "core/_bootstrap.py": self.BOOTSTRAP,
+            "core/devices.py": self.DEVICES,
+        })
+        self.assertIn("import-backend-touch", rule_ids(bad))
 
 
 class TestFallbackRule(unittest.TestCase):
@@ -733,6 +791,59 @@ class TestRuntimeImportContract(unittest.TestCase):
         for rel in ("diagnostics.py", "profiler.py", "resilience.py",
                     "_scheduler.py", "telemetry.py"):
             self.assertIn(rel, proc.stdout)
+
+
+    def test_no_backend_touch_before_distributed_join(self):
+        """The dynamic twin of ``import-backend-touch``: with the launch
+        contract set for a one-process job, ``import heat_tpu`` under a
+        backend factory that raises until ``jax.distributed``'s client exists.
+        A failure's traceback names the module and line that came too early."""
+        code = textwrap.dedent("""
+            import sys
+            import traceback
+
+            import jax
+            from jax._src import distributed, xla_bridge
+
+            real_backends = xla_bridge.backends
+
+            def guarded_backends():
+                if distributed.global_state.client is None:
+                    raise RuntimeError(
+                        "XLA backend created before jax.distributed was joined"
+                    )
+                return real_backends()
+
+            xla_bridge.backends = guarded_backends
+            try:
+                import heat_tpu
+            except BaseException:
+                traceback.print_exc()
+                sys.exit(1)
+            assert jax.process_count() == 1 and heat_tpu.COMM_WORLD.size >= 1
+            print("BRING_UP_ORDER_OK")
+        """)
+        import socket
+
+        with socket.socket() as sock:
+            sock.bind(("localhost", 0))
+            port = sock.getsockname()[1]
+        env = dict(os.environ)
+        env.update(
+            JAX_PLATFORMS="cpu",
+            HEAT_TPU_COORDINATOR_ADDRESS=f"localhost:{port}",
+            HEAT_TPU_NUM_PROCESSES="1",
+            HEAT_TPU_PROCESS_ID="0",
+        )
+        proc = subprocess.run(
+            [sys.executable, "-c", code], cwd=REPO_ROOT,
+            capture_output=True, text=True, timeout=300, env=env,
+        )
+        self.assertEqual(
+            proc.returncode, 0,
+            f"bring-up order broken:\n{proc.stderr[-3000:]}",
+        )
+        self.assertIn("BRING_UP_ORDER_OK", proc.stdout)
 
 
 class TestCLI(unittest.TestCase):

@@ -330,3 +330,28 @@ class TestHierarchicalCollectives:
         dup = hcomm.Split()
         assert dup.is_hierarchical
         assert dup.n_nodes == hcomm.n_nodes
+
+
+class TestBringUp:
+    """The launch contract of ``core/_bootstrap.py`` as a live process sees it
+    (``tests/test_multiprocess.py`` launches real jobs through it)."""
+
+    def test_initialize_after_import_names_the_contract(self):
+        world = get_comm()
+        with pytest.raises(RuntimeError, match="HEAT_TPU_COORDINATOR_ADDRESS"):
+            ht.initialize(coordinator_address="localhost:1", num_processes=2, process_id=0)
+        assert get_comm() is world and jax.process_count() == 1
+
+    @pytest.mark.parametrize("given", [{}, {"HEAT_TPU_NUM_PROCESSES": "2"},
+                                       {"HEAT_TPU_PROCESS_ID": "0"}])
+    def test_partial_launch_contract_is_an_error(self, monkeypatch, given):
+        from heat_tpu.core import _bootstrap
+
+        for name in _bootstrap._CONTRACT[1:]:
+            monkeypatch.delenv(name, raising=False)
+        monkeypatch.setenv("HEAT_TPU_COORDINATOR_ADDRESS", "localhost:1")
+        for name, value in given.items():
+            monkeypatch.setenv(name, value)
+        missing = [n for n in _bootstrap._CONTRACT[1:] if n not in given]
+        with pytest.raises(RuntimeError, match=" and ".join(missing) + " (is|are) not"):
+            _bootstrap.run()

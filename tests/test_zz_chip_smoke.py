@@ -14,7 +14,7 @@ import pytest
 import jax
 
 import heat_tpu as ht
-from heat_tpu.core import _compile_cache, _executor, diagnostics, resilience
+from heat_tpu.core import _bootstrap, _executor, diagnostics, resilience
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, REPO)
@@ -155,20 +155,20 @@ class TestBenchRefusals:
 
 class TestCompileCachePlacement:
     def test_fixed_path_inside_the_checkout(self):
-        assert _compile_cache.JAX_CACHE_DIR == os.path.join(REPO, ".jax_cache")
+        assert _bootstrap.JAX_CACHE_DIR == os.path.join(REPO, ".jax_cache")
         with open(os.path.join(REPO, ".gitignore")) as f:
             assert ".jax_cache/" in f.read().split()
 
     def test_this_process_follows_the_rule(self):
         placed = os.environ.get("JAX_COMPILATION_CACHE_DIR")
-        assert jax.config.jax_compilation_cache_dir == (placed or _compile_cache.JAX_CACHE_DIR)
+        assert jax.config.jax_compilation_cache_dir == (placed or _bootstrap.JAX_CACHE_DIR)
 
     def test_env_set_means_code_sets_nothing(self, monkeypatch):
         calls = []
         monkeypatch.setattr(jax.config, "update", lambda *a: calls.append(a))
         monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/somewhere/else")
-        _compile_cache._place_jax_cache()
+        _bootstrap.place_jax_cache()
         assert calls == []
         monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
-        _compile_cache._place_jax_cache()
-        assert calls == [("jax_compilation_cache_dir", _compile_cache.JAX_CACHE_DIR)]
+        _bootstrap.place_jax_cache()
+        assert calls == [("jax_compilation_cache_dir", _bootstrap.JAX_CACHE_DIR)]
