@@ -22,7 +22,6 @@ interpret mode).
 """
 
 import json
-import os
 import sys
 import time
 
@@ -244,8 +243,7 @@ def phase_attention(b: int = 8, h: int = 16, t: int = 4096, d: int = 64,
                     interpret: bool = False) -> dict:
     """Attention through ``scaled_dot_product_attention`` on batch-split DNDarrays:
     causal forward, ``jax.grad`` through it (both backward kernels), the shared
-    bool-mask variant and the pipelined forward — each compared on a slice with
-    the XLA path."""
+    bool-mask variant — each compared on a slice with the XLA path."""
     import jax
     import jax.numpy as jnp
 
@@ -295,15 +293,6 @@ def phase_attention(b: int = 8, h: int = 16, t: int = 4096, d: int = 64,
     outm = _placed(sdpa(qd, kd, vd, attn_mask=mask), platform)
     info["masked"] = _close(outm.larray[sl], xla_path(q[sl], k[sl], v[sl], mask, False),
                             rel, "masked forward")
-
-    # the pipelined forward is off by default; it is selected at trace time
-    os.environ["HEAT_TPU_FLASH_PIPELINE"] = "1"
-    try:
-        outp = sdpa(q[sl], k[sl], v[sl], is_causal=True)
-    finally:
-        del os.environ["HEAT_TPU_FLASH_PIPELINE"]
-    info["pipelined"] = _close(outp, xla_path(q[sl], k[sl], v[sl], None, True), rel,
-                               "pipelined causal forward")
     return info
 
 

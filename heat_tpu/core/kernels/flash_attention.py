@@ -1,13 +1,31 @@
-"""Flash-attention Pallas kernel (TPU).
+"""Flash-attention Pallas kernels (TPU).
 
 The XLA blockwise path in ``heat_tpu/nn/attention.py`` materialises the (T, T)
 score matrix in HBM — at T=4096, B·H=128 that is ~8 GB of f32 traffic and the op
-runs HBM-bound at a few TFLOP/s. This kernel streams k/v through VMEM with the
-standard online-softmax recurrence: for each query block the k/v blocks are visited
-sequentially, the (bq, bk) score tile lives only in VMEM, and the rescaled output
+runs HBM-bound at a few TFLOP/s. The forward kernel streams k/v through VMEM with
+the standard online-softmax recurrence: for each query block the k/v blocks are
+visited sequentially, the score tile lives only in VMEM, and the rescaled output
 accumulator is written to HBM once. Causal masking skips whole k-blocks above the
-diagonal (the loop's trip count is data-independent per q-block, so the causal
-kernel does ~half the work instead of masking all of it).
+diagonal (the pair list simply does not hold them, so the causal kernel does ~half
+the steps instead of masking all of them).
+
+**Geometry of the forward (PR 30).** A grid step takes one ``(bq, bk)`` block pair
+(what the DMA moves) and walks it as ``(bq / br) x (bk / bs)`` sub-tiles in ONE
+straight-line region: ``s = q_r·k_cᵀ``, the online-softmax update of the row chunk's
+state, ``p·v_c``. Row chunks share no state and a sub-tile's ``q·k`` does not wait for
+its predecessor's ``exp``, so Mosaic's scheduler issues one sub-tile's contractions
+under another's VPU pass: at ``(1024, 1024)`` blocks with ``(256, 512)`` sub-tiles the
+static schedule of the plain step is MXU-bound from its first bundle to its last. The
+softmax state is kept lane-dense, ``(bq, 128)`` with every lane the row's value, so no
+step pays a lane broadcast. ``br = bq, bs = bk`` is the serial form (the MXU phase and
+the VPU phase of a step one after the other); :func:`_sub_tiles` chooses from the
+block shape, no switch. **Bound and share** (v5e, d_qk 192 / d_v 128, bf16, T 32,768,
+32 heads; ``doc/source/flash_attention_perf.rst``): the kernel is compute-bound (K/V
+re-reads hide behind the double buffer), and the MXU takes the 192-wide contraction as
+two 128-deep passes, so a step does the work of widths 256 + 128 where its FLOP count
+has 192 + 128: 83% of the MXU peak is the ceiling of ``mla_flash_roofline_share`` at
+these widths. Measured (PR 30, device trace): 77.3 ms a call, 142 TFLOP/s, 72% of 197,
+from 113.2 ms and 49%.
 
 Backward: the ``jax.custom_vjp`` backward is also Pallas — the forward saves the
 (O, LSE) residuals, ``_dq_kernel`` streams k/v per query block and ``_dkv_kernel``
@@ -27,27 +45,42 @@ import math
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax import lax
+
+from .. import diagnostics
 
 __all__ = ["flash_attention", "flash_attention_reference", "flash_forward", "forward_blocks"]
 
 _NEG_INF = float(jnp.finfo(jnp.float32).min)
+_LANES = 128
 
-# Forward tile-size preference, per input itemsize: the (bq, bk) score/probability
-# tiles are f32 regardless of input dtype (2 × 4·bq·bk bytes resident), so f32
-# inputs take a smaller tile. Measured on v5e at b8·h16·t4096·d64: larger bk
-# amortizes the per-step softmax-state update — (1024, 1024) bf16 is ~1.6× faster
-# than (512, 512). Shapes that only divide 512 fall back to 512-blocks rather than
-# losing the flash path entirely.
+# Forward block preference, per input itemsize, largest first: larger bq halves the
+# K/V re-reads and the grid steps, larger bk the accumulator rescales a score element.
+# Since the step walks its block in sub-tiles, the live f32 tiles no longer grow with
+# the block, and (1024, 1024) fits Mosaic's default scope at every width the gates
+# admit. Shapes that only divide 512 fall back to 512-blocks rather than losing the
+# flash path entirely.
 _FWD_BLOCK_PREFS = {
     2: ((1024, 1024), (512, 1024), (1024, 512), (512, 512)),
     4: ((512, 1024), (512, 512)),
 }
+# Sub-tile of a grid step: query rows of an independent chunk, keys of one pass of the
+# online-softmax recurrence. Read off Mosaic's static schedule at (192, 128) bf16: 256
+# rows keep a key sub-tile's weights in the MXU for 16 pushes of operand rows; 512 keys
+# halve the cross-lane reductions of 256 (each sub-tile pays one row-max and one
+# row-sum per 8 rows on the XLU).
+_SUB_ROWS = 256
+_SUB_KEYS = 512
 _BWD_BQ = 512
 _BWD_BK = 512
 # scalar-prefetch schedule bound: the flattened pair list is O((T/b)²) int32
 # entries shipped to SMEM — cap it well below SMEM capacity
 _MAX_PAIRS = 8192
+# what a grid step may hold by the footprint model, of Mosaic's default scope of 16 MiB:
+# the quarter left over is for what the model cannot see (at blocks of 2,048 Mosaic
+# asks for a tenth more than the model counts; at the preferred blocks for less)
+_VMEM_BUDGET = 12 * 2**20
 
 
 def _env_vmem_limit():
@@ -63,6 +96,13 @@ def _env_vmem_limit():
     except ValueError:
         return None
     return v if v > 0 else None
+
+
+def _vmem_budget() -> int:
+    """What the gates let a grid step hold: the hand-tuning knob when set (the same
+    value _compiler_params forwards to Mosaic, so block-size experiments that lift the
+    VMEM budget actually reach the flash path), else :data:`_VMEM_BUDGET`."""
+    return _env_vmem_limit() or _VMEM_BUDGET
 
 
 def _compiler_params(pltpu):
@@ -97,14 +137,46 @@ def _env_blocks(default_bq: int, default_bk: int):
     return bq, bk
 
 
+def _sub_tiles(bq: int, bk: int) -> tuple:
+    """``(br, bs)``: the sub-tile in which a grid step walks its ``(bq, bk)`` block.
+    A block no larger than the sub-tile, or one it does not divide, is walked whole
+    (the serial form)."""
+    br = _SUB_ROWS if bq > _SUB_ROWS and bq % _SUB_ROWS == 0 else bq
+    bs = _SUB_KEYS if bk > _SUB_KEYS and bk % _SUB_KEYS == 0 else bk
+    return br, bs
+
+
+def _fwd_footprint(bq: int, bk: int, d: int, dv: int, itemsize: int,
+                   with_bias: bool = False) -> int:
+    """Bytes of VMEM a forward grid step holds at blocks ``(bq, bk)``: the one
+    footprint model, which :func:`_fits` and :func:`forward_blocks` both gate on.
+    Counted: the q / k / v / out blocks double-buffered (last dimension padded to 128
+    lanes), the double-buffered LSE block and the running max / sum (one value a row,
+    128 lanes each), the f32 accumulator of v's width, a streamed f32 bias block
+    double-buffered, and the live tiles of the step's sub-tiles: f32 scores, f32
+    probabilities and the probabilities in v's type, twice where the step has more
+    than one sub-tile (one under the VPU while the next is under the MXU). Against the
+    least ``vmem_limit_bytes`` Mosaic accepts (AOT, v5e, PR 30) the model reads high at
+    the preferred blocks (8.0 MiB for 5.0 at (1024, 1024), 192 / 128, bf16; 15.0 for
+    14.4 with a bias) and low beyond them (13.5 for 14.9 at (2048, 2048))."""
+    br, bs = _sub_tiles(bq, bk)
+
+    def pad(n):
+        return -(-n // _LANES) * _LANES
+
+    blocks = 2 * itemsize * (bq * pad(d) + bk * pad(d) + bk * pad(dv) + bq * pad(dv))
+    state = 4 * bq * (pad(dv) + 4 * _LANES)
+    tiles = (8 + itemsize) * br * bs * (1 if (br, bs) == (bq, bk) else 2)
+    bias = 8 * bq * bk if with_bias else 0
+    return blocks + state + tiles + bias
+
+
 def _fwd_blocks(dtype, tq: int, tk: int, with_bias: bool = False) -> tuple:
     """Largest preferred (bq, bk) that tiles (tq, tk) evenly, else the smallest
     preference (whose divisibility _fits re-checks and may reject). A streamed
     bias adds a double-buffered f32 (bq, bk) block, so biased bf16 runs use the
     smaller f32 tile preferences."""
-    size = 4 if (with_bias or _pipeline_enabled()) else jnp.dtype(dtype).itemsize
-    # (the pipelined kernel keeps an extra f32 (bq, bk) score buffer resident, so
-    # it takes the smaller-tile preference table like biased runs do)
+    size = 4 if with_bias else jnp.dtype(dtype).itemsize
     prefs = _FWD_BLOCK_PREFS.get(size, ((512, 512),))
     ebq, ebk = _env_blocks(0, 0)
     if ebq and tq % ebq == 0 and tk % ebk == 0:  # on-chip tuning override
@@ -132,43 +204,37 @@ def flash_attention_reference(q, k, v, causal: bool = False, scale=None):
     return (o / jnp.maximum(l, 1e-30)).astype(q.dtype)
 
 
-def _online_softmax_update(s, vb, acc_ref, m_ref, l_ref, has_bias: bool):
-    """One tile of the online-softmax recurrence, shared by the plain and
-    pipelined forward kernels (a numerical change here reaches both)."""
-    m = m_ref[...]
-    m_blk = jnp.max(s, axis=1, keepdims=True)
-    m_new = jnp.maximum(m, m_blk)
-    # a bias can mask a whole row of the block (all -inf): keep the exps finite —
-    # the row's l stays 0 and its output finalizes to 0 like the dense path
-    m_safe = jnp.maximum(m_new, _NEG_INF / 2) if has_bias else m_new
-    p_tile = jnp.exp(s - m_safe)
-    corr = jnp.exp(m - m_safe)
-    l_ref[...] = l_ref[...] * corr + jnp.sum(p_tile, axis=1, keepdims=True)
-    # probabilities ride the MXU in the value dtype (standard flash practice;
-    # p ∈ [0,1] so the bf16 round-off is bounded), accumulation stays f32
-    acc_ref[...] = acc_ref[...] * corr + lax.dot_general(
-        p_tile.astype(vb.dtype), vb, (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32,
-    )
-    m_ref[...] = m_new
+def _lanes(x, n: int):
+    """A lane-dense ``(rows, 128)`` state value (every lane the row's value) at ``n``
+    lanes: whole vregs side by side, or a prefix of one."""
+    if n % _LANES == 0:
+        return x if n == _LANES else jnp.tile(x, (1, n // _LANES))
+    if n < _LANES:
+        return x[:, :n]
+    return jnp.broadcast_to(x[:, :1], (x.shape[0], n))
 
 
 def _kernel(im_ref, jm_ref, flags_ref, q_ref, k_ref, v_ref, *refs,
-            scale: float, bq: int, bk: int, has_bias: bool = False):
-    """One (q-block, k-block) tile of the online-softmax recurrence.
+            scale: float, bq: int, bk: int, br: int, bs: int, has_bias: bool = False):
+    """One (q-block, k-block) pair of the online-softmax recurrence, walked as
+    ``(bq / br) x (bk / bs)`` sub-tiles in one straight-line region.
 
     The grid is the *flattened list of contributing (i, j) pairs* (splash-style):
     for causal attention the blocks strictly above the diagonal are not idle grid
     steps — they simply aren't in the list, so the causal kernel really does half
     the steps. Scalar-prefetched maps give each step its (i, j); flags mark the
     first/last step of each q-row sweep (init / finalize) and whether the block
-    straddles the diagonal (only those pay the iota/where mask — fully-below
-    blocks skip it).
+    straddles the diagonal. The steps below the diagonal, all but one a row, are ONE
+    basic block: every sub-tile's two contractions and softmax pass lie in it with
+    no branch between them, which is what lets the scheduler put the ``exp`` pass of
+    one sub-tile under the contractions of the next. A straddling step has a region
+    of its own: the same sub-tiles with the iota/where mask, each behind a scalar
+    test that skips it when it lies wholly above the diagonal.
 
     Pallas double-buffers the k/v block DMA against compute because the kv pair
     index advances with the grid. MXU inputs stay in the input dtype (bf16 runs
     at full MXU rate — forcing f32 here quarters throughput); softmax state and
-    the output accumulator are f32.
+    the output accumulator are f32, the running max and sum lane-dense (bq, 128).
     """
     import jax.experimental.pallas as pl
 
@@ -178,9 +244,13 @@ def _kernel(im_ref, jm_ref, flags_ref, q_ref, k_ref, v_ref, *refs,
         o_ref, lse_ref, acc_ref, m_ref, l_ref = refs
 
     p = pl.program_id(1)
-    d = q_ref.shape[2]
+    dv = v_ref.shape[2]
     flags = flags_ref[p]
     is_first, is_last, needs_mask = flags & 1, flags & 2, flags & 4
+    row0, col0 = im_ref[p] * bq, jm_ref[p] * bk
+    # 16-bit operands are one MXU pass whatever the process-wide default says (Mosaic
+    # refuses them at "highest"); float32 operands follow the caller's context
+    precision = lax.Precision.DEFAULT if q_ref.dtype.itemsize < 4 else None
 
     @pl.when(is_first != 0)
     def _init():
@@ -188,140 +258,56 @@ def _kernel(im_ref, jm_ref, flags_ref, q_ref, k_ref, v_ref, *refs,
         m_ref[...] = jnp.full_like(m_ref, _NEG_INF)
         l_ref[...] = jnp.zeros_like(l_ref)
 
-    q = q_ref[0]  # (bq, d), input dtype
-    kb = k_ref[0]
-    vb = v_ref[0]
-    s = (
-        lax.dot_general(q, kb, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32)
-        * scale
-    )  # (bq, bk) f32
-    if has_bias:
-        s = s + bias_ref[...]
+    def _tile(r: int, c: int, masked: bool):
+        rows, cols = pl.ds(r * br, br), pl.ds(c * bs, bs)
+        vb = v_ref[0, cols, :]
+        s = lax.dot_general(
+            q_ref[0, rows, :], k_ref[0, cols, :], (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32, precision=precision,
+        ) * scale  # (br, bs) f32
+        if has_bias:
+            s = s + bias_ref[rows, cols]
+        if masked:
+            ri = row0 + r * br + lax.broadcasted_iota(jnp.int32, (br, bs), 0)
+            ci = col0 + c * bs + lax.broadcasted_iota(jnp.int32, (br, bs), 1)
+            s = jnp.where(ri >= ci, s, _NEG_INF)
+        m = m_ref[rows, :]
+        m_new = jnp.maximum(m, jnp.max(s, axis=1, keepdims=True))
+        # a bias can mask a whole row of the block (all -inf): keep the exps finite —
+        # the row's l stays 0 and its output finalizes to 0 like the dense path
+        m_safe = jnp.maximum(m_new, _NEG_INF / 2) if has_bias else m_new
+        p_tile = jnp.exp(s - _lanes(m_safe, bs))
+        corr = jnp.exp(m - m_safe)
+        l_ref[rows, :] = l_ref[rows, :] * corr + jnp.sum(p_tile, axis=1, keepdims=True)
+        # probabilities ride the MXU in the value dtype (standard flash practice;
+        # p ∈ [0,1] so the bf16 round-off is bounded), accumulation stays f32
+        acc_ref[rows, :] = acc_ref[rows, :] * _lanes(corr, dv) + lax.dot_general(
+            p_tile.astype(vb.dtype), vb, (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32, precision=precision,
+        )
+        m_ref[rows, :] = m_new
 
-    def _update(s):
-        _online_softmax_update(s, vb, acc_ref, m_ref, l_ref, has_bias)
+    sub_tiles = [(r, c) for r in range(bq // br) for c in range(bk // bs)]
 
-    # only diagonal-straddling blocks pay the iota/where mask; fully-below
-    # blocks take the plain branch — pl.when predication, not a lane-wise select,
-    # so the mask cost really is skipped for them
     @pl.when(needs_mask != 0)
     def _masked():
-        rows = im_ref[p] * bq + lax.broadcasted_iota(jnp.int32, (bq, bk), 0)
-        cols = jm_ref[p] * bk + lax.broadcasted_iota(jnp.int32, (bq, bk), 1)
-        _update(jnp.where(rows >= cols, s, _NEG_INF))
+        for r, c in sub_tiles:
+            # a sub-tile wholly above the diagonal reaches no row: skipped
+            live = col0 + c * bs <= row0 + (r + 1) * br - 1
+            pl.when(live)(functools.partial(_tile, r, c, True))
 
     @pl.when(needs_mask == 0)
     def _plain():
-        _update(s)
+        for r, c in sub_tiles:
+            _tile(r, c, False)
 
     @pl.when(is_last != 0)
     def _finalize():
-        l = l_ref[...]
+        l = l_ref[:, :1]
         o_ref[0] = (acc_ref[...] / jnp.maximum(l, 1e-30)).astype(o_ref.dtype)
         # log-sum-exp residual for the backward pass: L = m + log(l); the clamp
         # keeps fully-masked rows finite so the backward's exp(s - L) is 0, not NaN
-        lse_ref[0] = jnp.maximum(m_ref[...], _NEG_INF / 2) + jnp.log(jnp.maximum(l, 1e-30))
-
-
-def _pipeline_enabled() -> bool:
-    """HEAT_TPU_FLASH_PIPELINE=1 selects the one-step-skewed forward kernel: each
-    grid step computes QK for pair p while running exp/PV for pair p−1 — the two
-    chains share no data, so Mosaic's scheduler can issue the VPU exp pass
-    concurrently with the MXU matmuls instead of serialising them (the overlap
-    the ceiling analysis in doc/source/flash_attention_perf.rst identifies as the
-    gap between the ~33 and ~49 TFLOP/s bounds). Off by default until measured
-    on hardware; read at trace time (same caveat as _env_blocks)."""
-
-    return os.environ.get("HEAT_TPU_FLASH_PIPELINE") == "1"  # ht: ignore[trace-env-read] -- documented trace-time tuning knob (see docstring): kernel block geometry is necessarily a compile-time constant; re-tune in a fresh process
-
-
-def _kernel_pipelined(im_ref, jm_ref, flags_ref, q_ref, k_ref, v_ref, *refs,
-                      scale: float, bq: int, bk: int, has_bias: bool = False):
-    """One-step software pipeline over the flattened pair grid.
-
-    Step p holds TWO independent chains: (a) exp + rescale + PV for the score
-    tile the previous step left in ``s_ref`` (consumes the LAGGED v block the
-    index map streams), and (b) the QK matmul for pair p, written to ``s_ref``
-    afterwards. A flush step (flag bit 8) per q-row consumes the row's final
-    tile and finalizes — it has no QK phase, so every step needs only one v
-    block. ``s_prev`` is loaded before (b) overwrites the buffer."""
-    import jax.experimental.pallas as pl
-
-    if has_bias:
-        bias_ref, o_ref, lse_ref, acc_ref, m_ref, l_ref, s_ref = refs
-    else:
-        o_ref, lse_ref, acc_ref, m_ref, l_ref, s_ref = refs
-
-    p = pl.program_id(1)
-    flags = flags_ref[p]
-    is_first, is_last, is_flush = flags & 1, flags & 2, flags & 8
-    p_prev = jnp.maximum(p - 1, 0)
-    prev_mask = flags_ref[p_prev] & 4
-
-    @pl.when(is_first != 0)
-    def _init():
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-        m_ref[...] = jnp.full_like(m_ref, _NEG_INF)
-        l_ref[...] = jnp.zeros_like(l_ref)
-
-    s_prev = s_ref[...]  # loaded before this step's QK overwrites the buffer
-    vb = v_ref[0]  # v block of the PREVIOUS pair (lagged index map)
-
-    def _update(s):
-        _online_softmax_update(s, vb, acc_ref, m_ref, l_ref, has_bias)
-
-    @pl.when((is_first == 0) & (prev_mask != 0))
-    def _prev_masked():
-        rows = im_ref[p_prev] * bq + lax.broadcasted_iota(jnp.int32, (bq, bk), 0)
-        cols = jm_ref[p_prev] * bk + lax.broadcasted_iota(jnp.int32, (bq, bk), 1)
-        _update(jnp.where(rows >= cols, s_prev, _NEG_INF))
-
-    @pl.when((is_first == 0) & (prev_mask == 0))
-    def _prev_plain():
-        _update(s_prev)
-
-    @pl.when(is_flush == 0)
-    def _qk():
-        s = (
-            lax.dot_general(
-                q_ref[0], k_ref[0], (((1,), (1,)), ((), ())),
-                preferred_element_type=jnp.float32,
-            )
-            * scale
-        )
-        if has_bias:
-            s = s + bias_ref[...]
-        s_ref[...] = s
-
-    @pl.when(is_last != 0)
-    def _finalize():
-        l = l_ref[...]
-        o_ref[0] = (acc_ref[...] / jnp.maximum(l, 1e-30)).astype(o_ref.dtype)
-        lse_ref[0] = jnp.maximum(m_ref[...], _NEG_INF / 2) + jnp.log(jnp.maximum(l, 1e-30))
-
-
-def _pair_schedule_pipelined(nq: int, nk: int, bq: int, bk: int, causal: bool):
-    """Derived from :func:`_pair_schedule` (single-sourced pair set): finalize
-    (bit 2) moves off the real pairs onto one flush step (bits 2|8) appended per
-    q-row. The flush's (i, j) repeats the row's last pair so the k/v index maps
-    stay in range."""
-    import numpy as np
-
-    im, jm, flags = _pair_schedule(nq, nk, bq, bk, causal)
-    out_im, out_jm, out_fl = [], [], []
-    for i, j, f in zip(im.tolist(), jm.tolist(), flags.tolist()):
-        out_im.append(i)
-        out_jm.append(j)
-        out_fl.append(f & ~2)
-        if f & 2:  # last pair of the row: append its flush step
-            out_im.append(i)
-            out_jm.append(j)
-            out_fl.append(2 | 8)
-    return (
-        np.asarray(out_im, np.int32),
-        np.asarray(out_jm, np.int32),
-        np.asarray(out_fl, np.int32),
-    )
+        lse_ref[0] = jnp.maximum(m_ref[:, :1], _NEG_INF / 2) + jnp.log(jnp.maximum(l, 1e-30))
 
 
 def _pair_schedule(nq: int, nk: int, bq: int, bk: int, causal: bool):
@@ -342,20 +328,19 @@ def _pair_schedule(nq: int, nk: int, bq: int, bk: int, causal: bool):
             im.append(i)
             jm.append(j)
             flags.append(f)
-    import numpy as np
-
     return np.asarray(im, np.int32), np.asarray(jm, np.int32), np.asarray(flags, np.int32)
 
 
 @functools.partial(
     jax.jit,
-    static_argnames=("causal", "scale", "bq", "bk", "interpret", "pipelined", "name"),
+    static_argnames=("causal", "scale", "bq", "bk", "interpret", "sub", "name"),
 )
 def _flash_pallas(q, k, v, causal: bool, scale: float, bq: int, bk: int,
-                  interpret: bool = False, bias=None, pipelined: bool = False,
-                  name=None):
+                  interpret: bool = False, bias=None, sub=None, name=None):
     """q, k: (..., T, d); v: (..., Tk, dv) with its own width (dv != d is the latent-
-    attention case: 192 against 128). ``name`` names the Pallas call in a device trace."""
+    attention case: 192 against 128). ``sub`` is the step's sub-tile ``(br, bs)``, by
+    default what :func:`_sub_tiles` reads off the blocks. ``name`` names the Pallas call
+    in a device trace."""
     import jax.experimental.pallas as pl  # ht: ignore[trace-lazy-import] -- pallas imports deferred so CPU-only processes never pay them; runs once per compile, imports nothing of heat_tpu
     from jax.experimental.pallas import tpu as pltpu  # ht: ignore[trace-lazy-import] -- pallas imports deferred so CPU-only processes never pay them; runs once per compile, imports nothing of heat_tpu
 
@@ -367,22 +352,16 @@ def _flash_pallas(q, k, v, causal: bool, scale: float, bq: int, bk: int,
         kr = k.reshape(bh, tk, d)
         vr = v.reshape(bh, tk, dv)
         has_bias = bias is not None
+        br, bs = _sub_tiles(bq, bk) if sub is None else sub
+        if diagnostics._enabled:  # trace time only: which schedule this trace's steps take
+            diagnostics.counter(
+                "kernels.flash.fwd." + ("serial" if (br, bs) == (bq, bk) else "overlapped"))
 
-        schedule = _pair_schedule_pipelined if pipelined else _pair_schedule
-        im, jm, flags = schedule(tq // bq, tk // bk, bq, bk, causal)
-        npairs = len(im)
-
-        if pipelined:
-            # the exp/PV chain consumes the PREVIOUS pair's v block
-            v_spec = pl.BlockSpec(
-                (1, bk, dv), lambda b, p, im, jm, fl: (b, jm[jnp.maximum(p - 1, 0)], 0)
-            )
-        else:
-            v_spec = pl.BlockSpec((1, bk, dv), lambda b, p, im, jm, fl: (b, jm[p], 0))
+        im, jm, flags = _pair_schedule(tq // bq, tk // bk, bq, bk, causal)
         in_specs = [
             pl.BlockSpec((1, bq, d), lambda b, p, im, jm, fl: (b, im[p], 0)),
             pl.BlockSpec((1, bk, d), lambda b, p, im, jm, fl: (b, jm[p], 0)),
-            v_spec,
+            pl.BlockSpec((1, bk, dv), lambda b, p, im, jm, fl: (b, jm[p], 0)),
         ]
         inputs = [qr, kr, vr]
         if has_bias:
@@ -392,26 +371,23 @@ def _flash_pallas(q, k, v, causal: bool, scale: float, bq: int, bk: int,
                 pl.BlockSpec((bq, bk), lambda b, p, im, jm, fl: (im[p], jm[p]))
             )
             inputs.append(bias.astype(jnp.float32))
-        scratch_shapes = [
-            pltpu.VMEM((bq, dv), jnp.float32),
-            pltpu.VMEM((bq, 1), jnp.float32),
-            pltpu.VMEM((bq, 1), jnp.float32),
-        ]
-        if pipelined:
-            scratch_shapes.append(pltpu.VMEM((bq, bk), jnp.float32))  # skewed scores
         grid_spec = pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=3,
-            grid=(bh, npairs),
+            grid=(bh, len(im)),
             in_specs=in_specs,
             out_specs=[
                 pl.BlockSpec((1, bq, dv), lambda b, p, im, jm, fl: (b, im[p], 0)),
                 pl.BlockSpec((1, bq, 1), lambda b, p, im, jm, fl: (b, im[p], 0)),
             ],
-            scratch_shapes=scratch_shapes,
+            scratch_shapes=[
+                pltpu.VMEM((bq, dv), jnp.float32),
+                pltpu.VMEM((bq, _LANES), jnp.float32),
+                pltpu.VMEM((bq, _LANES), jnp.float32),
+            ],
         )
-        kern = _kernel_pipelined if pipelined else _kernel
         out, lse = pl.pallas_call(
-            functools.partial(kern, scale=scale, bq=bq, bk=bk, has_bias=has_bias),
+            functools.partial(_kernel, scale=scale, bq=bq, bk=bk, br=br, bs=bs,
+                              has_bias=has_bias),
             grid_spec=grid_spec,
             out_shape=[
                 jax.ShapeDtypeStruct((bh, tq, dv), q.dtype),
@@ -572,8 +548,6 @@ def _pair_schedule_kv(nq: int, nk: int, bq: int, bk: int, causal: bool):
             jm.append(j)
             im.append(i)
             flags.append(f)
-    import numpy as np
-
     return np.asarray(jm, np.int32), np.asarray(im, np.int32), np.asarray(flags, np.int32)
 
 
@@ -679,10 +653,11 @@ def _flash_bwd_pallas(q, k, v, o, do, lse, causal: bool, scale: float, bq: int,
 
 
 def _fits(q, k, bq: int, bk: int, with_bias: bool = False) -> bool:
-    """VMEM gate: forward and backward all stream blocks through the grid now, so
-    residency is O(bq·bk) regardless of T — the gate only enforces even tiling
-    and a sane per-step footprint. One head width ``d`` for q, k and v: a narrower
-    v goes through :func:`forward_blocks`."""
+    """VMEM gate of the training entry: forward and backward all stream blocks through
+    the grid, so residency is O(block) regardless of T — the gate only enforces even
+    tiling, pair lists that fit SMEM and a sane per-step footprint (the forward's by
+    :func:`_fwd_footprint`). One head width ``d`` for q, k and v: a narrower v goes
+    through :func:`forward_blocks`."""
     tq, d = q.shape[-2], q.shape[-1]
     tk = k.shape[-2]
     if tq % bq or tk % bk:
@@ -690,53 +665,36 @@ def _fits(q, k, bq: int, bk: int, with_bias: bool = False) -> bool:
     if tq % _BWD_BQ or tk % _BWD_BK:
         return False
     # the flattened pair schedules are O((T/b)²) int32 scalar-prefetch entries
-    # living in SMEM — bound them (bwd uses the fixed _BWD blocks, check both);
-    # the pipelined schedule appends one flush step per q-row
-    fwd_steps = (tq // bq) * (tk // bk)
-    if _pipeline_enabled():
-        fwd_steps += tq // bq
-    if fwd_steps > _MAX_PAIRS:
+    # living in SMEM — bound them (bwd uses the fixed _BWD blocks, check both)
+    if (tq // bq) * (tk // bk) > _MAX_PAIRS:
         return False
     if (tq // _BWD_BQ) * (tk // _BWD_BK) > _MAX_PAIRS:
         return False
     itemsize = jnp.dtype(q.dtype).itemsize
-    # per-step residency: s + p tiles (f32), accumulator, double-buffered blocks,
-    # plus a double-buffered f32 bias block when a mask streams through
-    bias_fwd = 8 * bq * bk if with_bias else 0
-    if _pipeline_enabled():
-        bias_fwd += 4 * bq * bk  # the skewed score buffer stays resident
+    # backward per-step residency: s + p tiles (f32), accumulators, double-buffered
+    # blocks, plus a double-buffered f32 bias block when a mask streams through
     bias_bwd = 8 * _BWD_BQ * _BWD_BK if with_bias else 0
-    fwd = 8 * bq * bk + 4 * bq * d + 2 * (bq + 2 * bk) * d * itemsize * 2 + bias_fwd
     bwd = 8 * _BWD_BQ * _BWD_BK + 8 * _BWD_BK * d \
         + 2 * (_BWD_BQ + 2 * _BWD_BK) * d * itemsize * 2 + bias_bwd
-    # the same knob _compiler_params forwards to Mosaic, so block-size
-    # experiments that lift the VMEM budget actually reach the flash path
-    limit = _env_vmem_limit() or 12 * 2**20
-    return max(fwd, bwd) <= limit
+    return max(_fwd_footprint(bq, bk, d, d, itemsize, with_bias), bwd) <= _vmem_budget()
 
 
 def forward_blocks(q, k, v):
     """The largest preferred ``(bq, bk)`` with which the forward kernel alone runs
     ``q, k: (..., T, d)``, ``v: (..., Tk, dv)``, or None: the sequence does not tile,
     the pair list outgrows SMEM, a type Mosaic does not take, or no block pair fits the
-    VMEM budget. Counted per grid step: the f32 score and probability tiles and the
-    probabilities once more in v's type, the f32 accumulator of v's width, the running
-    max / sum scratch and the double-buffered LSE block (one column each, padded to 128
-    lanes), and the q / k / v / out blocks double-buffered. At d = 192, dv = 128 in
-    bfloat16 that is 8.2 MiB for (512, 1024) and 15 MiB for (1024, 1024), which Mosaic
-    refuses under its 16 MiB default scope (16.37 MiB with its own temporaries)."""
+    VMEM budget by :func:`_fwd_footprint` (12 MiB of Mosaic's 16 MiB default scope).
+    At d = 192, dv = 128 in bfloat16 and 32,768 tokens that is (1024, 1024), walked in
+    (256, 512) sub-tiles: 528 steps a head, 6.0 MiB by the model."""
     tq, d = q.shape[-2], q.shape[-1]
     tk, dv = k.shape[-2], v.shape[-1]
     if any(t.dtype not in (jnp.float32, jnp.bfloat16, jnp.float16) for t in (q, k, v)):
         return None
     itemsize = jnp.dtype(q.dtype).itemsize
-    limit = _env_vmem_limit() or 12 * 2**20
     for bq, bk in _FWD_BLOCK_PREFS.get(itemsize, ((512, 512),)):
         if tq % bq or tk % bk or (tq // bq) * (tk // bk) > _MAX_PAIRS:
             continue
-        tiles = (8 + itemsize) * bq * bk + 4 * bq * dv + 4 * 4 * bq * 128
-        blocks = (bq * d + bk * d + bk * dv + bq * dv) * itemsize * 2
-        if tiles + blocks <= limit:
+        if _fwd_footprint(bq, bk, d, dv, itemsize) <= _vmem_budget():
             return bq, bk
     return None
 
@@ -779,8 +737,7 @@ def flash_attention(q, k, v, causal: bool = False, scale=None, mask=None):
     s = (1.0 / math.sqrt(q.shape[-1])) if scale is None else scale
     bias = _as_bias(mask)
     blocks = _fwd_blocks(q.dtype, q.shape[-2], k.shape[-2], with_bias=bias is not None)
-    out, _ = _flash_pallas(q, k, v, causal, float(s), *blocks, bias=bias,
-                            pipelined=_pipeline_enabled())
+    out, _ = _flash_pallas(q, k, v, causal, float(s), *blocks, bias=bias)
     return out
 
 
@@ -788,8 +745,7 @@ def _fwd(q, k, v, causal, scale, mask):
     s = (1.0 / math.sqrt(q.shape[-1])) if scale is None else scale
     bias = _as_bias(mask)
     blocks = _fwd_blocks(q.dtype, q.shape[-2], k.shape[-2], with_bias=bias is not None)
-    out, lse = _flash_pallas(q, k, v, causal, float(s), *blocks, bias=bias,
-                              pipelined=_pipeline_enabled())
+    out, lse = _flash_pallas(q, k, v, causal, float(s), *blocks, bias=bias)
     return out, (q, k, v, out, lse, mask)
 
 

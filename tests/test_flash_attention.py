@@ -142,7 +142,7 @@ class TestFlashUnequalWidths:
         k = jnp.array(rng.standard_normal((2, 1024, 192)), jnp.bfloat16)
         v = jnp.array(rng.standard_normal((2, 1024, 128)), jnp.bfloat16)
         blocks = forward_blocks(q, k, v)
-        assert blocks == (512, 1024)
+        assert blocks == (1024, 1024)
         got = flash_forward(q, k, v, True, 0.07, blocks, name="mla_flash_fwd", interpret=True)
         want = flash_attention_reference(q, k, v, True, 0.07)
         assert got.dtype == jnp.bfloat16 and got.shape == (2, 1024, 128)
@@ -150,12 +150,13 @@ class TestFlashUnequalWidths:
                                    rtol=2e-2, atol=2e-2)
 
     def test_blocks_at_the_cell_shape(self):
-        """32 heads of 32,768 tokens at 192 / 128 in bfloat16: (1024, 1024) overflows
-        Mosaic's 16 MiB default scope (the chip's compiler refused it), (512, 1024) is
-        taken; 64 x 32 pairs lie inside the SMEM bound. float64 and ragged lengths: none."""
+        """32 heads of 32,768 tokens at 192 / 128 in bfloat16: since the step walks its
+        block in (256, 512) sub-tiles, (1024, 1024) fits Mosaic's 16 MiB default scope
+        (tests/test_kernels.py compiles it for a described v5e); 32 x 32 pairs lie
+        inside the SMEM bound. float64 and ragged lengths: none."""
         q = jax.ShapeDtypeStruct((32, 32768, 192), jnp.bfloat16)
         v = jax.ShapeDtypeStruct((32, 32768, 128), jnp.bfloat16)
-        assert forward_blocks(q, q, v) == (512, 1024)
+        assert forward_blocks(q, q, v) == (1024, 1024)
         ragged = jax.ShapeDtypeStruct((32, 32767, 192), jnp.bfloat16)
         assert forward_blocks(ragged, ragged, jax.ShapeDtypeStruct((32, 32767, 128), jnp.bfloat16)) is None
         wide = jax.ShapeDtypeStruct((1, 1024, 192), jnp.float64)
@@ -170,6 +171,81 @@ class TestFlashUnequalWidths:
         residuals = (q, q, v, v, jnp.ones((1, 512), jnp.float32), None)
         with pytest.raises(NotImplementedError, match="one head width"):
             _bwd(True, 0.1, residuals, v)
+
+
+class TestSubTiledForward:
+    """The forward walks a (bq, bk) block as (bq / br) x (bk / bs) sub-tiles in one
+    region (PR 30). Blocks (256, 512) with sub-tiles (128, 128): the straddling block
+    of query rows 256..511 has, for its first row chunk, two key sub-tiles wholly
+    below the diagonal, one on it and one wholly above."""
+
+    BLOCKS, SUB = (256, 512), (128, 128)
+
+    @pytest.mark.parametrize("causal", [False, True])
+    @pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32])
+    @pytest.mark.parametrize("widths", [(192, 128), (64, 64), (128, 64)])
+    def test_interpret_parity(self, widths, dtype, causal):
+        d, dv = widths
+        rng = np.random.default_rng(31)
+        q = jnp.array(rng.standard_normal((2, 1024, d)), dtype)
+        k = jnp.array(rng.standard_normal((2, 1024, d)), dtype)
+        v = jnp.array(rng.standard_normal((2, 1024, dv)), dtype)
+        with jax.default_matmul_precision("highest"):
+            got, lse = _flash_pallas(q, k, v, causal, 0.07, *self.BLOCKS, interpret=True,
+                                     sub=self.SUB)
+            whole, lse_whole = _flash_pallas(q, k, v, causal, 0.07, *self.BLOCKS,
+                                             interpret=True, sub=self.BLOCKS)
+            want = flash_attention_reference(q, k, v, causal, 0.07)
+        assert got.shape == want.shape == (2, 1024, dv) and got.dtype == dtype
+        tol = 2e-2 if dtype == jnp.bfloat16 else 1e-5
+        np.testing.assert_allclose(np.asarray(got, np.float32), np.asarray(want, np.float32),
+                                   rtol=tol, atol=tol)
+        # the residual the backward reads does not depend on how the block is walked
+        np.testing.assert_allclose(np.asarray(lse), np.asarray(lse_whole), rtol=1e-5, atol=1e-5)
+
+    def test_a_sub_tile_above_the_diagonal_reaches_nothing(self):
+        """NaN keys and values from position 640 on. Query rows 512..639 are the first
+        row chunk of the block pair (2, 1), whose key sub-tiles from 640 on lie wholly
+        above the diagonal: a sub-tile that was computed and masked would still carry
+        0 x NaN into the accumulator; one that is skipped carries nothing."""
+        rng = np.random.default_rng(32)
+        q, k, v = (jnp.array(rng.standard_normal((1, 1024, 64)), jnp.float32) for _ in range(3))
+        poisoned_k = k.at[:, 640:].set(jnp.nan)
+        poisoned_v = v.at[:, 640:].set(jnp.nan)
+        clean, _ = _flash_pallas(q, k, v, True, 0.125, *self.BLOCKS, interpret=True, sub=self.SUB)
+        got, lse = _flash_pallas(q, poisoned_k, poisoned_v, True, 0.125, *self.BLOCKS,
+                                 interpret=True, sub=self.SUB)
+        assert bool(jnp.all(jnp.isfinite(got[:, :640]))) and bool(jnp.all(jnp.isfinite(lse[:, :640])))
+        np.testing.assert_allclose(np.asarray(got[:, :640]), np.asarray(clean[:, :640]),
+                                   rtol=1e-6, atol=1e-6)
+
+    def test_the_two_counters_count_traces(self):
+        """``kernels.flash.fwd.overlapped`` / ``.serial``: one a trace, by the schedule the
+        trace took; a second call of a traced shape counts nothing."""
+        import heat_tpu as ht
+
+        q = jnp.ones((1, 1024, 32), jnp.float32)
+        was_on = ht.diagnostics.enabled()
+        ht.diagnostics.enable()
+        ht.diagnostics.reset()
+        try:
+            def counts():
+                c = ht.diagnostics.report()["counters"]
+                return (c.get("kernels.flash.fwd.overlapped", 0), c.get("kernels.flash.fwd.serial", 0))
+
+            # a scale no other test uses: these are fresh traces whatever ran before
+            _flash_pallas(q, q, q, True, 0.3125, 512, 1024, interpret=True)  # (256, 512) by rule
+            assert counts() == (1, 0)
+            _flash_pallas(q, q, q, True, 0.3125, 512, 1024, interpret=True)
+            assert counts() == (1, 0)
+            _flash_pallas(q, q, q, True, 0.3125, 128, 128, interpret=True)  # walked whole
+            assert counts() == (1, 1)
+            _flash_pallas(q, q, q, True, 0.3125, 128, 128, interpret=True)
+            assert counts() == (1, 1)
+        finally:
+            ht.diagnostics.reset()
+            if not was_on:
+                ht.diagnostics.disable()
 
 
 class TestFlashBackward:

@@ -1,7 +1,8 @@
 """Pallas kernel tests: the fused KMeans assignment must agree with its jnp reference
 (validated in interpreter mode so the same test runs on the CPU mesh), the Lloyd
 program must carry the kernel in the form each place needs, and the kernel must compile
-for a described v5e at the block its gate picks."""
+for a described v5e at the block its gate picks. The flash forward compiles there too,
+at the latent-attention cell's shape and the blocks its gate picks."""
 
 import functools
 import os
@@ -15,6 +16,7 @@ import jax.numpy as jnp
 import heat_tpu as ht
 from heat_tpu.cluster import _kcluster
 from heat_tpu.core.kernels import fused_assign_update, fused_assign_update_reference
+from heat_tpu.core.kernels import flash_attention as flash_kernel
 from heat_tpu.core.kernels import kmeans as kmeans_kernel
 from heat_tpu.core.kernels.kmeans import _block_n, _fused_pallas
 from heat_tpu.testing import TestCase
@@ -294,6 +296,47 @@ def test_the_gate_declines_what_mosaic_would_refuse(one_chip):
         c = jax.ShapeDtypeStruct((k, d), jnp.float32, sharding=one_chip)
         with pytest.raises(Exception, match="(?i)vmem|memory"):
             jax.jit(functools.partial(_fused_pallas, block_n=128)).lower(x, c).compile()
+
+
+@pytest.mark.parametrize("precision", ["default", "highest"])
+def test_mosaic_compiles_the_flash_forward_at_the_cell_shape(one_chip, precision):
+    """``xing4-score-32k``'s attention core, bf16[32, 32768, 192] against [.., 128], at the
+    blocks ``forward_blocks`` picks and Mosaic's default VMEM scope. Also under a
+    process-wide "highest" (the serving configuration sets it): the kernel states one
+    MXU pass for 16-bit operands, which Mosaic would otherwise refuse."""
+    q = jax.ShapeDtypeStruct((32, 32768, 192), jnp.bfloat16, sharding=one_chip)
+    v = jax.ShapeDtypeStruct((32, 32768, 128), jnp.bfloat16, sharding=one_chip)
+    blocks = flash_kernel.forward_blocks(q, q, v)
+    assert blocks == (1024, 1024) and flash_kernel._sub_tiles(*blocks) == (256, 512)
+    from jax.experimental.pallas import tpu as pltpu
+
+    assert flash_kernel._compiler_params(pltpu).vmem_limit_bytes is None
+    with jax.default_matmul_precision(precision):
+        compiled = jax.jit(lambda q, k, v: flash_kernel.flash_forward(
+            q, k, v, True, 0.07, blocks, name="mla_flash_fwd")).lower(q, q, v).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text and "mla_flash_fwd" in text
+
+
+def test_the_flash_gates_decline_what_mosaic_would_refuse(one_chip):
+    """One footprint model behind both gates: a streamed bias at (1024, 1024) is 15 MiB
+    by the model, over the 12 MiB budget, and Mosaic does refuse it under its 16 MiB
+    scope; the block pairs the gates pick for the same operands compile."""
+    t = 4096
+    q = jax.ShapeDtypeStruct((8, 16, t, 64), jnp.bfloat16, sharding=one_chip)
+    bias = jax.ShapeDtypeStruct((t, t), jnp.float32, sharding=one_chip)
+    assert flash_kernel._fwd_footprint(1024, 1024, 64, 64, 2, with_bias=True) > flash_kernel._VMEM_BUDGET
+    assert not flash_kernel._fits(q, q, 1024, 1024, with_bias=True)
+    with pytest.raises(Exception, match="(?i)vmem|memory"):
+        jax.jit(lambda q, k, v, b: flash_kernel._flash_pallas(
+            q, k, v, False, 0.125, 1024, 1024, bias=b)[0]).lower(q, q, q, bias).compile()
+    for with_bias in (False, True):
+        blocks = flash_kernel._fwd_blocks(q.dtype, t, t, with_bias)
+        assert flash_kernel._fits(q, q, *blocks, with_bias=with_bias)
+        assert flash_kernel._fwd_footprint(*blocks, 64, 64, 2, with_bias) <= flash_kernel._VMEM_BUDGET
+        jax.jit(lambda q, k, v, b: flash_kernel._flash_pallas(
+            q, k, v, False, 0.125, *blocks, bias=b)[0]).lower(
+                q, q, q, bias if with_bias else None).compile()
 
 
 if __name__ == "__main__":
