@@ -27,6 +27,18 @@ has 192 + 128: 83% of the MXU peak is the ceiling of ``mla_flash_roofline_share`
 these widths. Measured (PR 30, device trace): 77.3 ms a call, 142 TFLOP/s, 72% of 197,
 from 113.2 ms and 49%.
 
+**Window and grouped heads (PR 31, the forward only).** Under a ``window`` a row sweep lists
+only the key blocks that meet the band ``i - window < j <= i`` (at 32,768 tokens, a window
+of 2,048 and (1024, 1024) blocks: 93 steps a head for the causal 528); the blocks that
+straddle the diagonal or the band's lower edge are masked, sub-tile by sub-tile, the same
+forward body. k and v may have fewer heads than q: grid row ``b`` reads key/value head
+``b // (Hq / Hkv)`` through the block index map, and nothing is repeated in HBM (on the
+chip the call takes the same time as over repeated heads). Measured at d = d_v = 128,
+32 / 4 heads, T 32,768 (device trace inside ``trinity-score-32k``): causal
+(``gqa_flash_fwd``) 54.1 ms a call, 163 TFLOP/s, 82% of 197; band (``swa_flash_fwd``)
+10.2 ms, 53% by the band's own pairs: two of a sweep's three steps are masked steps,
+whose sub-tiles run in regions of their own.
+
 Backward: the ``jax.custom_vjp`` backward is also Pallas — the forward saves the
 (O, LSE) residuals, ``_dq_kernel`` streams k/v per query block and ``_dkv_kernel``
 streams q/dO per key block, each recomputing its probability tile from the LSE
@@ -215,7 +227,8 @@ def _lanes(x, n: int):
 
 
 def _kernel(im_ref, jm_ref, flags_ref, q_ref, k_ref, v_ref, *refs,
-            scale: float, bq: int, bk: int, br: int, bs: int, has_bias: bool = False):
+            scale: float, bq: int, bk: int, br: int, bs: int, has_bias: bool = False,
+            window=None):
     """One (q-block, k-block) pair of the online-softmax recurrence, walked as
     ``(bq / br) x (bk / bs)`` sub-tiles in one straight-line region.
 
@@ -224,12 +237,14 @@ def _kernel(im_ref, jm_ref, flags_ref, q_ref, k_ref, v_ref, *refs,
     steps — they simply aren't in the list, so the causal kernel really does half
     the steps. Scalar-prefetched maps give each step its (i, j); flags mark the
     first/last step of each q-row sweep (init / finalize) and whether the block
-    straddles the diagonal. The steps below the diagonal, all but one a row, are ONE
-    basic block: every sub-tile's two contractions and softmax pass lie in it with
-    no branch between them, which is what lets the scheduler put the ``exp`` pass of
-    one sub-tile under the contractions of the next. A straddling step has a region
-    of its own: the same sub-tiles with the iota/where mask, each behind a scalar
-    test that skips it when it lies wholly above the diagonal.
+    straddles an edge of what a row may see: the diagonal or, under a ``window``
+    (row ``i`` sees keys ``i - window < j <= i``), the band's lower edge. The steps that
+    straddle neither, all but one a causal row, are ONE basic block: every sub-tile's
+    two contractions and softmax pass lie in it with no branch between them, which is
+    what lets the scheduler put the ``exp`` pass of one sub-tile under the contractions
+    of the next. A straddling step has a region of its own: the same sub-tiles with the
+    iota/where mask, each behind a scalar test that skips it when it lies wholly above
+    the diagonal or wholly below the band.
 
     Pallas double-buffers the k/v block DMA against compute because the kv pair
     index advances with the grid. MXU inputs stay in the input dtype (bf16 runs
@@ -246,7 +261,8 @@ def _kernel(im_ref, jm_ref, flags_ref, q_ref, k_ref, v_ref, *refs,
     p = pl.program_id(1)
     dv = v_ref.shape[2]
     flags = flags_ref[p]
-    is_first, is_last, needs_mask = flags & 1, flags & 2, flags & 4
+    is_first, is_last = flags & 1, flags & 2
+    needs_mask = flags & (4 if window is None else 4 | 8)
     row0, col0 = im_ref[p] * bq, jm_ref[p] * bk
     # 16-bit operands are one MXU pass whatever the process-wide default says (Mosaic
     # refuses them at "highest"); float32 operands follow the caller's context
@@ -270,7 +286,10 @@ def _kernel(im_ref, jm_ref, flags_ref, q_ref, k_ref, v_ref, *refs,
         if masked:
             ri = row0 + r * br + lax.broadcasted_iota(jnp.int32, (br, bs), 0)
             ci = col0 + c * bs + lax.broadcasted_iota(jnp.int32, (br, bs), 1)
-            s = jnp.where(ri >= ci, s, _NEG_INF)
+            keep = ri >= ci
+            if window is not None:
+                keep &= ri - ci < window
+            s = jnp.where(keep, s, _NEG_INF)
         m = m_ref[rows, :]
         m_new = jnp.maximum(m, jnp.max(s, axis=1, keepdims=True))
         # a bias can mask a whole row of the block (all -inf): keep the exps finite —
@@ -292,8 +311,11 @@ def _kernel(im_ref, jm_ref, flags_ref, q_ref, k_ref, v_ref, *refs,
     @pl.when(needs_mask != 0)
     def _masked():
         for r, c in sub_tiles:
-            # a sub-tile wholly above the diagonal reaches no row: skipped
+            # a sub-tile wholly above the diagonal reaches no row: skipped; so is one
+            # wholly below the band (its last key is out of its first row's window)
             live = col0 + c * bs <= row0 + (r + 1) * br - 1
+            if window is not None:
+                live &= col0 + (c + 1) * bs - 1 > row0 + r * br - window
             pl.when(live)(functools.partial(_tile, r, c, True))
 
     @pl.when(needs_mask == 0)
@@ -310,21 +332,45 @@ def _kernel(im_ref, jm_ref, flags_ref, q_ref, k_ref, v_ref, *refs,
         lse_ref[0] = jnp.maximum(m_ref[:, :1], _NEG_INF / 2) + jnp.log(jnp.maximum(l, 1e-30))
 
 
-def _pair_schedule(nq: int, nk: int, bq: int, bk: int, causal: bool):
+def _kv_group(q, k, v) -> int:
+    """Query heads per key/value head: 1, or ``Hq // Hkv`` where k and v have fewer heads
+    on axis -3 and every other leading axis agrees."""
+    if k.shape[:-2] == q.shape[:-2] == v.shape[:-2]:
+        return 1
+    if (q.ndim < 3 or k.ndim != q.ndim or k.shape[:-2] != v.shape[:-2]
+            or k.shape[:-3] != q.shape[:-3] or q.shape[-3] % k.shape[-3]):
+        raise ValueError(f"key/value heads {k.shape[:-2]} / {v.shape[:-2]} do not group the "
+                         f"query's {q.shape[:-2]}")
+    return q.shape[-3] // k.shape[-3]
+
+
+def _pair_schedule(nq: int, nk: int, bq: int, bk: int, causal: bool, window=None):
     """Flattened (i, j) visit list + per-step flag bits (1=first of row sweep,
-    2=last of row sweep, 4=diagonal-straddling → mask). Causal keeps only blocks
-    with any (row ≥ col); mask is needed only when the block's last col exceeds
-    the block's first row."""
+    2=last of row sweep, 4=diagonal-straddling → mask, 8=straddling the band's lower
+    edge → mask). Causal keeps only blocks with any (row ≥ col); mask is needed only
+    when the block's last col exceeds the block's first row. Under a ``window`` (causal
+    only) a row sees ``row - window < col <= row``: a row sweep lists the key blocks
+    that meet that band and skips the rest, and a block whose first col is at or under
+    its last row's lower limit straddles the band's lower edge."""
+    if window is not None and (not causal or window < 1):
+        raise ValueError(f"a window is a causal band of at least one key; got causal="
+                         f"{causal}, window={window}")
     im, jm, flags = [], [], []
     for i in range(nq):
+        row_lo, row_hi = i * bq, i * bq + bq - 1
         js = [
             j for j in range(nk)
-            if not causal or j * bk <= i * bq + bq - 1
+            if (not causal or j * bk <= row_hi)
+            and (window is None or j * bk + bk - 1 > row_lo - window)
         ]
+        if not js:  # an unvisited output block would hold uninitialised memory
+            raise ValueError(f"no key block meets the window of query rows {row_lo}..{row_hi}")
         for idx, j in enumerate(js):
             f = (1 if idx == 0 else 0) | (2 if idx == len(js) - 1 else 0)
-            if causal and (j * bk + bk - 1 > i * bq):
+            if causal and (j * bk + bk - 1 > row_lo):
                 f |= 4
+            if window is not None and j * bk <= row_hi - window:
+                f |= 8
             im.append(i)
             jm.append(j)
             flags.append(f)
@@ -333,14 +379,17 @@ def _pair_schedule(nq: int, nk: int, bq: int, bk: int, causal: bool):
 
 @functools.partial(
     jax.jit,
-    static_argnames=("causal", "scale", "bq", "bk", "interpret", "sub", "name"),
+    static_argnames=("causal", "scale", "bq", "bk", "interpret", "sub", "name", "window"),
 )
 def _flash_pallas(q, k, v, causal: bool, scale: float, bq: int, bk: int,
-                  interpret: bool = False, bias=None, sub=None, name=None):
+                  interpret: bool = False, bias=None, sub=None, name=None, window=None):
     """q, k: (..., T, d); v: (..., Tk, dv) with its own width (dv != d is the latent-
-    attention case: 192 against 128). ``sub`` is the step's sub-tile ``(br, bs)``, by
-    default what :func:`_sub_tiles` reads off the blocks. ``name`` names the Pallas call
-    in a device trace."""
+    attention case: 192 against 128). k and v may have fewer heads (axis -3) than q,
+    ``Hq = rep * Hkv``: query head ``h`` reads key/value head ``h // rep`` through the
+    block index map, and nothing is repeated in HBM. ``window`` (causal only): row ``i``
+    sees keys ``i - window < j <= i``, and the blocks outside that band are not visited.
+    ``sub`` is the step's sub-tile ``(br, bs)``, by default what :func:`_sub_tiles`
+    reads off the blocks. ``name`` names the Pallas call in a device trace."""
     import jax.experimental.pallas as pl  # ht: ignore[trace-lazy-import] -- pallas imports deferred so CPU-only processes never pay them; runs once per compile, imports nothing of heat_tpu
     from jax.experimental.pallas import tpu as pltpu  # ht: ignore[trace-lazy-import] -- pallas imports deferred so CPU-only processes never pay them; runs once per compile, imports nothing of heat_tpu
 
@@ -348,20 +397,28 @@ def _flash_pallas(q, k, v, causal: bool, scale: float, bq: int, bk: int,
         *batch, tq, d = q.shape
         tk, dv = k.shape[-2], v.shape[-1]
         bh = math.prod(batch) if batch else 1
+        rep = _kv_group(q, k, v)
         qr = q.reshape(bh, tq, d)
-        kr = k.reshape(bh, tk, d)
-        vr = v.reshape(bh, tk, dv)
+        kr = k.reshape(bh // rep, tk, d)
+        vr = v.reshape(bh // rep, tk, dv)
         has_bias = bias is not None
         br, bs = _sub_tiles(bq, bk) if sub is None else sub
+
+        im, jm, flags = _pair_schedule(tq // bq, tk // bk, bq, bk, causal, window)
         if diagnostics._enabled:  # trace time only: which schedule this trace's steps take
             diagnostics.counter(
                 "kernels.flash.fwd." + ("serial" if (br, bs) == (bq, bk) else "overlapped"))
+            # block pairs the schedule lists against all of them: what causal and band skip
+            diagnostics.counter("kernels.flash.fwd.pairs_visited", len(im))
+            diagnostics.counter("kernels.flash.fwd.pairs_dense", (tq // bq) * (tk // bk))
 
-        im, jm, flags = _pair_schedule(tq // bq, tk // bk, bq, bk, causal)
+        def kv_map(b, p, im, jm, fl):  # grid row b is (batch, query head)
+            return (b if rep == 1 else b // rep), jm[p], 0
+
         in_specs = [
             pl.BlockSpec((1, bq, d), lambda b, p, im, jm, fl: (b, im[p], 0)),
-            pl.BlockSpec((1, bk, d), lambda b, p, im, jm, fl: (b, jm[p], 0)),
-            pl.BlockSpec((1, bk, dv), lambda b, p, im, jm, fl: (b, jm[p], 0)),
+            pl.BlockSpec((1, bk, d), kv_map),
+            pl.BlockSpec((1, bk, dv), kv_map),
         ]
         inputs = [qr, kr, vr]
         if has_bias:
@@ -387,7 +444,7 @@ def _flash_pallas(q, k, v, causal: bool, scale: float, bq: int, bk: int,
         )
         out, lse = pl.pallas_call(
             functools.partial(_kernel, scale=scale, bq=bq, bk=bk, br=br, bs=bs,
-                              has_bias=has_bias),
+                              has_bias=has_bias, window=window),
             grid_spec=grid_spec,
             out_shape=[
                 jax.ShapeDtypeStruct((bh, tq, dv), q.dtype),
@@ -681,11 +738,15 @@ def _fits(q, k, bq: int, bk: int, with_bias: bool = False) -> bool:
 
 def forward_blocks(q, k, v):
     """The largest preferred ``(bq, bk)`` with which the forward kernel alone runs
-    ``q, k: (..., T, d)``, ``v: (..., Tk, dv)``, or None: the sequence does not tile,
-    the pair list outgrows SMEM, a type Mosaic does not take, or no block pair fits the
-    VMEM budget by :func:`_fwd_footprint` (12 MiB of Mosaic's 16 MiB default scope).
-    At d = 192, dv = 128 in bfloat16 and 32,768 tokens that is (1024, 1024), walked in
-    (256, 512) sub-tiles: 528 steps a head, 6.0 MiB by the model."""
+    ``q, k: (..., T, d)``, ``v: (..., Tk, dv)`` (k and v with q's heads or a divisor of
+    them), or None: the sequence does not tile, the pair list outgrows SMEM, a type
+    Mosaic does not take, or no block pair fits the VMEM budget by
+    :func:`_fwd_footprint` (12 MiB of Mosaic's 16 MiB default scope). At d = 192,
+    dv = 128 in bfloat16 and 32,768 tokens that is (1024, 1024), walked in (256, 512)
+    sub-tiles: 528 steps a head, 6.0 MiB by the model. The same preference serves a
+    window: at d = d_v = 128 under a band of 2,048 keys (1024, 1024) was the fastest of ten
+    block pairs on the chip (11.2 ms a call; key blocks of 512 12.5, of 256 18.9: PERF.md,
+    PR 31), because a row sweep's masked steps, not its skipped keys, set the time."""
     tq, d = q.shape[-2], q.shape[-1]
     tk, dv = k.shape[-2], v.shape[-1]
     if any(t.dtype not in (jnp.float32, jnp.bfloat16, jnp.float16) for t in (q, k, v)):
@@ -700,12 +761,14 @@ def forward_blocks(q, k, v):
 
 
 def flash_forward(q, k, v, causal: bool, scale: float, blocks, name=None,
-                  interpret: bool = False):
+                  interpret: bool = False, window=None):
     """The forward kernel alone, for inference paths: v may be narrower or wider than
-    q and k, ``blocks`` is what :func:`forward_blocks` chose, ``name`` names the Pallas
-    call in device traces. No gradient is defined on this entry."""
+    q and k, k and v may have fewer heads than q (grouped heads, taken where they lie),
+    ``window`` keeps row ``i`` to keys ``i - window < j <= i`` and skips the blocks
+    outside that band, ``blocks`` is what :func:`forward_blocks` chose, ``name`` names
+    the Pallas call in device traces. No gradient is defined on this entry."""
     out, _ = _flash_pallas(q, k, v, causal, float(scale), *blocks, interpret=interpret,
-                           name=name)
+                           name=name, window=window)
     return out
 
 
@@ -719,8 +782,8 @@ def _as_bias(mask):
     return mask.astype(jnp.float32)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
-def flash_attention(q, k, v, causal: bool = False, scale=None, mask=None):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 6))
+def flash_attention(q, k, v, causal: bool = False, scale=None, mask=None, window=None):
     """Exact attention with the flash (streaming-VMEM) forward on TPU.
 
     q: (..., Tq, D), k/v: (..., Tk, D); Tq/Tk must be multiples of the block
@@ -732,25 +795,34 @@ def flash_attention(q, k, v, causal: bool = False, scale=None, mask=None):
     kernels over the saved (O, LSE) residuals). All three kernels stream blocks
     through a flattened pair grid, so VMEM residency is O(block²) regardless of
     T — arbitrarily long sequences fit, and the (T, T) matrix never exists in
-    HBM.
+    HBM. The forward also takes a causal ``window`` and k / v with fewer heads than q
+    (see :func:`flash_forward`); the backward computes neither and says so.
     """
+    return _fwd(q, k, v, causal, scale, mask, window)[0]
+
+
+def _fwd(q, k, v, causal, scale, mask, window):
     s = (1.0 / math.sqrt(q.shape[-1])) if scale is None else scale
     bias = _as_bias(mask)
     blocks = _fwd_blocks(q.dtype, q.shape[-2], k.shape[-2], with_bias=bias is not None)
-    out, _ = _flash_pallas(q, k, v, causal, float(s), *blocks, bias=bias)
-    return out
-
-
-def _fwd(q, k, v, causal, scale, mask):
-    s = (1.0 / math.sqrt(q.shape[-1])) if scale is None else scale
-    bias = _as_bias(mask)
-    blocks = _fwd_blocks(q.dtype, q.shape[-2], k.shape[-2], with_bias=bias is not None)
-    out, lse = _flash_pallas(q, k, v, causal, float(s), *blocks, bias=bias)
+    out, lse = _flash_pallas(q, k, v, causal, float(s), *blocks, bias=bias, window=window)
     return out, (q, k, v, out, lse, mask)
 
 
-def _bwd(causal, scale, res, g):
+def _bwd(causal, scale, window, res, g):
     q, k, v, out, lse, mask = res
+    if window is not None:
+        # the backward kernels walk the causal schedule: they would return the gradient
+        # of the unwindowed attention
+        raise NotImplementedError(
+            f"the flash backward kernels do not take a window (got window={window}): "
+            "their schedule and mask are causal or dense only"
+        )
+    if k.shape[:-2] != q.shape[:-2]:
+        raise NotImplementedError(
+            "the flash backward kernels take as many key/value heads as query heads; "
+            f"got {k.shape[:-2]} for {q.shape[:-2]} (grouped heads: the forward only)"
+        )
     if v.shape[-1] != q.shape[-1]:
         raise NotImplementedError(
             "the flash backward kernels take one head width for q, k and v; "

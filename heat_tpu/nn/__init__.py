@@ -8,5 +8,7 @@ from .attention import *
 from .recurrent import *
 from .hyper_connections import *
 from .moe import *
+from .scoring import *
 from .xing4 import *
-from . import attention, data_parallel, functional, hyper_connections, modules, moe, recurrent, xing4
+from .trinity import *
+from . import attention, data_parallel, functional, hyper_connections, modules, moe, recurrent, scoring, trinity, xing4

@@ -58,6 +58,7 @@ __all__ = [
     "ulysses_attention",
     "MultiheadAttention",
     "MultiheadLatentAttention",
+    "GroupedQueryAttention",
     "yarn_inv_freq",
     "rotate_halves",
     "even_then_odd",
@@ -842,6 +843,98 @@ class MultiheadLatentAttention(Module):
             o = self._core(q, k, kv_h[..., dn:])
             return contract("...htv,hvd->...td", o,
                             params["wo"].reshape(h, dv, self.dim)).astype(dt)
+
+
+class GroupedQueryAttention(Module):
+    """Causal self-attention over grouped heads, with the per-head norm, the window and
+    the output gate that current open models put round them.
+
+    ``q = x W_q`` (``num_heads`` heads of ``head_dim``), ``k = x W_k`` and ``v = x W_v``
+    (``num_kv_heads`` heads), no bias. q and k are RMS-normed over the head's width, one
+    weight vector for all heads. With ``rope_theta`` rotary positions
+    turn all of a head's dimensions, halves convention (``x[:d/2]`` with ``x[d/2:]``);
+    without, the layer has no positions at all. Query head ``h`` reads key/value head
+    ``h // (num_heads // num_kv_heads)``. Row ``i`` sees keys ``j <= i`` and, with
+    ``window``, ``i - j < window``. The concatenated heads are multiplied by
+    ``sigmoid(x W_g)`` elementwise before ``W_o``. Input ``(..., T, dim)``, positions
+    ``0..T-1`` on axis -2.
+
+    This is the whole-sequence forward (scoring, prefill): no key/value cache. On TPU the
+    core runs in the flash Pallas kernel, which reads each key/value head where it lies
+    (nothing is repeated in HBM) and, under a window, visits only the key blocks that
+    meet the band; the call is named ``swa_flash_fwd`` (window) or ``gqa_flash_fwd``
+    (full) in device traces. Where the kernel does not apply (another backend, a sequence
+    that does not tile) the XLA path runs, its band mask built from two ``iota``
+    comparisons, and ``record_fallback("nn.gqa", ...)`` says why. Parameters are stored in
+    ``dtype`` (norm weights float32); contractions accumulate in float32, and the norms,
+    the softmax and the gate's sigmoid are float32.
+    """
+
+    def __init__(self, dim: int, num_heads: int, num_kv_heads: int, head_dim: int,
+                 window: Optional[int] = None, rope_theta: Optional[float] = None,
+                 eps: float = 1e-6, dtype=jnp.float32, norm_init_std: float = 0.0):
+        if num_heads % num_kv_heads:
+            raise ValueError(f"{num_kv_heads} key/value heads do not group {num_heads} heads")
+        if window is not None and window < 1:
+            raise ValueError(f"a window holds at least the row's own key; got {window}")
+        self.dim, self.head_dim = dim, head_dim
+        self.num_heads, self.num_kv_heads = num_heads, num_kv_heads
+        self.window = window
+        self.inv_freq = None if rope_theta is None else yarn_inv_freq(head_dim, rope_theta, None)
+        self.scale = head_dim ** -0.5
+        self.dtype = jnp.dtype(dtype)
+        self.q_norm = RMSNorm(head_dim, eps, norm_init_std)
+        self.k_norm = RMSNorm(head_dim, eps, norm_init_std)
+
+    def init(self, key):
+        kq, kk, kv, kg, ko, kqn, kkn = jax.random.split(key, 7)
+        d, hd, dt = self.dim, self.head_dim, self.dtype
+        wide, narrow = self.num_heads * hd, self.num_kv_heads * hd
+        return {
+            "wq": normal_weight(kq, (d, wide), dt, d ** -0.5),
+            "wk": normal_weight(kk, (d, narrow), dt, d ** -0.5),
+            "wv": normal_weight(kv, (d, narrow), dt, d ** -0.5),
+            "wg": normal_weight(kg, (d, wide), dt, d ** -0.5),
+            "wo": normal_weight(ko, (wide, d), dt, wide ** -0.5),
+            "q_norm": self.q_norm.init(kqn),
+            "k_norm": self.k_norm.init(kkn),
+        }
+
+    def _core(self, q, k, v):
+        """Causal (and windowed) softmax(q k^T scale) v on q (..., H, T, d) and k, v
+        (..., Hkv, T, d)."""
+        blocks, why = None, f"backend {jax.default_backend()}"
+        if jax.default_backend() == "tpu":
+            blocks, why = forward_blocks(q, k, v), "no block pair tiles and fits"
+        if blocks is not None:
+            name = "gqa_flash_fwd" if self.window is None else "swa_flash_fwd"
+            return flash_forward(q, k, v, True, self.scale, blocks, name=name,
+                                 window=self.window)
+        diagnostics.record_fallback("nn.gqa", f"{why}: T={q.shape[-2]} {q.dtype}")
+        t, g = q.shape[-2], self.num_kv_heads
+        qg = q.reshape(q.shape[:-3] + (g, self.num_heads // g) + q.shape[-2:])
+        s = contract("...grqd,...gkd->...grqk", qg, k) * jnp.float32(self.scale)
+        gap = lax.broadcasted_iota(jnp.int32, (t, t), 0) - lax.broadcasted_iota(jnp.int32, (t, t), 1)
+        keep = gap >= 0 if self.window is None else (gap >= 0) & (gap < self.window)
+        p = jax.nn.softmax(jnp.where(keep, s, _NEG_INF), axis=-1).astype(v.dtype)
+        return contract("...grqk,...gkd->...grqd", p, v).astype(q.dtype).reshape(q.shape)
+
+    def apply(self, params, x, *, key=None, train=False):
+        x = x.larray if isinstance(x, DNDarray) else x
+        h, g, hd, dt = self.num_heads, self.num_kv_heads, self.head_dim, x.dtype
+        with jax.named_scope("ht.nn.gqa"):
+            q = contract("...td,dhe->...hte", x, params["wq"].reshape(self.dim, h, hd)).astype(dt)
+            k = contract("...td,dhe->...hte", x, params["wk"].reshape(self.dim, g, hd)).astype(dt)
+            v = contract("...td,dhe->...hte", x, params["wv"].reshape(self.dim, g, hd)).astype(dt)
+            q = self.q_norm.apply(params["q_norm"], q)
+            k = self.k_norm.apply(params["k_norm"], k)
+            if self.inv_freq is not None:
+                q, k = rotate_halves(q, self.inv_freq), rotate_halves(k, self.inv_freq)
+            gate = jax.nn.sigmoid(contract("...td,dhe->...hte", x,
+                                           params["wg"].reshape(self.dim, h, hd)))
+            o = (self._core(q, k, v).astype(jnp.float32) * gate).astype(dt)
+            return contract("...hte,hed->...td", o,
+                            params["wo"].reshape(h, hd, self.dim)).astype(dt)
 
 
 def _keyed_dropout(x, p: float, key, train: bool):

@@ -8,14 +8,11 @@ multi-token-prediction module that predicts two tokens ahead from the main model
 hidden state. ``doc/source/xing4.rst`` writes the equations out and lists what is
 ``assumed`` where the published configuration leaves a choice open.
 
-The request is *scoring*: the log-likelihood of a document's last ``continuation`` tokens
-given everything before them, as evaluation harnesses, rerankers and perplexity filters
-ask it. That is one whole causal forward with no key/value cache and no decode loop;
-only the positions that score the continuation go through the head.
-
-``model(tokens)`` runs through :meth:`Module.__call__` like every module, and the whole
-forward is **one compiled program a call** (``nn.xing4.traces`` counts its traces, as
-``spatial.cdist.traces`` does for ``cdist``).
+The request is *scoring* (:mod:`.scoring`, which this model shares with
+:class:`~.trinity.Trinity`): ``model(tokens)`` runs through :meth:`Module.__call__` like
+every module, the whole forward is **one compiled program a call** (``nn.xing4.traces``
+counts its traces), and only the positions that score the continuation go through the
+head.
 
 No reference counterpart.
 """
@@ -25,16 +22,14 @@ from __future__ import annotations
 import dataclasses
 from typing import NamedTuple, Optional, Tuple
 
-import numpy as np
-
 import jax
 import jax.numpy as jnp
 
-from ..core import diagnostics
 from .attention import MultiheadLatentAttention
 from .hyper_connections import HyperConnection
-from .modules import GatedMLP, Module, RMSNorm, _to_value, contract, normal_weight
+from .modules import GatedMLP, Module, RMSNorm, contract, normal_weight
 from .moe import MoE
+from .scoring import ScoringForward, score
 
 __all__ = ["Xing4", "Xing4Block", "Xing4Config", "Xing4Scores"]
 
@@ -144,7 +139,7 @@ class Xing4Block(Module):
         return self.ffn_hc.apply(params["ffn_hc"], (x, feed_forward))
 
 
-class Xing4(Module):
+class Xing4(ScoringForward):
     """``Xing4(config)(tokens)``: the scoring forward of one document ``tokens`` (T,)
     int32, returning :class:`Xing4Scores`.
 
@@ -154,6 +149,10 @@ class Xing4(Module):
     see :class:`~.moe.MoE`); parameters are stored in ``dtype`` (norms, router and the
     hyper-connection mappings float32) and activations follow it.
     """
+
+    traces = "nn.xing4.traces"
+    logliks = ("loglik", "mtp_loglik")
+    ahead = 2  # the multi-token-prediction head scores from two back
 
     def __init__(self, config, continuation: int = 128,
                  experts_held: Optional[Tuple[int, int]] = None, dtype=jnp.bfloat16,
@@ -174,7 +173,6 @@ class Xing4(Module):
         self.mtp_hnorm = RMSNorm(c.hidden_size, c.rms_norm_eps, NORM_INIT_STD)
         self.mtp_block = Xing4Block(c, False, experts_held, dtype, block_rows)
         self.mtp_norm = RMSNorm(c.hidden_size, c.rms_norm_eps, NORM_INIT_STD)
-        self._program = jax.jit(self._forward)
 
     def init(self, key):
         c, dt = self.config, self.dtype
@@ -199,17 +197,8 @@ class Xing4(Module):
     def _sum_streams(x):
         return sum(x[j].astype(jnp.float32) for j in range(x.shape[0])).astype(x.dtype)
 
-    def _score(self, norm, norm_params, head, h, targets):
-        """Head logits (float32) of the rows ``h`` and the targets' log-likelihood."""
-        logits = contract("td,dv->tv", norm.apply(norm_params, h), head["weight"])
-        logp = jax.nn.log_softmax(logits, axis=-1)
-        return logits, jnp.sum(jnp.take_along_axis(logp, targets[:, None], axis=1))
-
-    def _forward(self, params, tokens):
-        if diagnostics._enabled:
-            diagnostics.counter("nn.xing4.traces")  # trace time only
+    def _document(self, params, tokens):
         t, c = tokens.shape[0], self.continuation
-        tokens = tokens.astype(jnp.int32)
         embed = params["embed"]["weight"]
         targets = tokens[t - c:]
         x = jnp.broadcast_to(embed[tokens][None], (self.config.hc_mult, t, embed.shape[1]))
@@ -219,8 +208,7 @@ class Xing4(Module):
             if aux is not None:
                 routed.append(aux)
         h = self._sum_streams(x)
-        logits, loglik = self._score(self.norm, params["norm"], params["head"],
-                                     h[t - 1 - c:t - 1], targets)
+        logits, loglik = score(self.norm, params["norm"], params["head"], h, targets)
         with jax.named_scope("ht.nn.mtp"):
             m = params["mtp"]
             # position i joins the embedding of token i+1; the last position has none and
@@ -233,30 +221,8 @@ class Xing4(Module):
             xm, aux = self.mtp_block.apply(m["block"], xm)
             routed.append(aux)
             hm = self._sum_streams(xm)
-            mtp_logits, mtp_loglik = self._score(self.mtp_norm, m["norm"], params["head"],
-                                                 hm[t - 2 - c:t - 2], targets)
+            mtp_logits, mtp_loglik = score(self.mtp_norm, m["norm"], params["head"], hm,
+                                           targets, ahead=2)
         return Xing4Scores(logits, mtp_logits, loglik, mtp_loglik,
                            jnp.stack([a["chosen"] for a in routed]),
                            jnp.stack([a["load"] for a in routed]))
-
-    def apply(self, params, x, *, key=None, train=False):
-        if x.ndim != 1 or x.shape[0] < self.continuation + 3:
-            raise ValueError(
-                f"Xing4 scores one document of shape (T,), T >= continuation + 3 = "
-                f"{self.continuation + 3}; got {x.shape}")
-        return self._program(params, x)
-
-    def __call__(self, tokens, **kwargs):
-        return super().__call__(_to_value(tokens), **kwargs)
-
-    def readback(self, scores: Xing4Scores) -> Tuple[float, float]:
-        """Wait for the program and bring the two log-likelihoods to the host. With
-        diagnostics on, the expert layers' load is then counted from the auxiliary
-        output: ``nn.moe.tokens`` (rows the held experts multiplied, summed over the
-        expert layers) and ``nn.moe.load_max`` (the fullest expert's rows, likewise)."""
-        loglik, mtp_loglik = float(scores.loglik), float(scores.mtp_loglik)
-        if diagnostics._enabled:
-            load = np.asarray(scores.load)
-            diagnostics.counter("nn.moe.tokens", float(load.sum()))
-            diagnostics.counter("nn.moe.load_max", float(load.max(axis=1).sum()))
-        return loglik, mtp_loglik

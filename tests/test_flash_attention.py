@@ -170,7 +170,7 @@ class TestFlashUnequalWidths:
         v = jnp.ones((1, 512, 128), jnp.float32)
         residuals = (q, q, v, v, jnp.ones((1, 512), jnp.float32), None)
         with pytest.raises(NotImplementedError, match="one head width"):
-            _bwd(True, 0.1, residuals, v)
+            _bwd(True, 0.1, None, residuals, v)
 
 
 class TestSubTiledForward:

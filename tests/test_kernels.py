@@ -318,6 +318,29 @@ def test_mosaic_compiles_the_flash_forward_at_the_cell_shape(one_chip, precision
     assert "tpu_custom_call" in text and "mla_flash_fwd" in text
 
 
+@pytest.mark.parametrize("window,name", [(2048, "swa_flash_fwd"), (None, "gqa_flash_fwd")])
+def test_mosaic_compiles_the_grouped_forward_at_the_trinity_cell_shape(one_chip, window, name):
+    """``trinity-score-32k``'s attention cores: bf16 q [32, 32768, 128] over 4 key/value heads
+    taken where they lie, under the band of 2,048 keys and causal, at the blocks
+    ``forward_blocks`` picks and Mosaic's default VMEM scope; no repeated key/value operand
+    (a ``[32, 32768, 128]`` k or v) enters the program."""
+    q = jax.ShapeDtypeStruct((32, 32768, 128), jnp.bfloat16, sharding=one_chip)
+    kv = jax.ShapeDtypeStruct((4, 32768, 128), jnp.bfloat16, sharding=one_chip)
+    blocks = flash_kernel.forward_blocks(q, kv, kv)
+    assert blocks == (1024, 1024)
+    visited = len(flash_kernel._pair_schedule(32768 // blocks[0], 32768 // blocks[1], *blocks,
+                                              True, window)[0])
+    dense = (32768 // blocks[0]) * (32768 // blocks[1])
+    assert visited < dense / 8 if window else visited > dense / 2
+    compiled = jax.jit(lambda q, k, v: flash_kernel.flash_forward(
+        q, k, v, True, 128 ** -0.5, blocks, name=name, window=window)).lower(q, kv, kv).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text and name in text
+    # the program's one temporary is the kernel's second output, the log-sum-exp that this
+    # entry drops (f32[32, 32768, 1], the 1 padded to 128 lanes): no copy of k or v
+    assert compiled.memory_analysis().temp_size_in_bytes < 32 * 32768 * 128 * 4 + 2**20
+
+
 def test_the_flash_gates_decline_what_mosaic_would_refuse(one_chip):
     """One footprint model behind both gates: a streamed bias at (1024, 1024) is 15 MiB
     by the model, over the 12 MiB budget, and Mosaic does refuse it under its 16 MiB
