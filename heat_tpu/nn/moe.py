@@ -20,8 +20,19 @@ absent chips.
 
 **No token is dropped.** The (token, expert) pairs are sorted by expert and every
 expert's group is padded to whole blocks of ``block_rows`` rows, so the buffer holds any
-imbalance (``T k + count * block_rows`` rows), and a loop over the held experts
-multiplies as many blocks as each really has: the cost follows the tokens, not a capacity.
+imbalance (``T k + count * block_rows`` rows) and a block belongs to exactly one expert.
+Only the blocks in use are multiplied: the cost follows the tokens, not a capacity.
+
+**The products.** On a TPU the held experts' gated products are one grouped Pallas call
+over the sorted buffer (``core/kernels/grouped_matmul.py``, ``moe_grouped_fwd`` in a device
+trace): its grid is the buffer's blocks, a prefetched map names each block's expert, an
+expert's weights stay on the chip over its blocks, and the rows come through ``source`` by
+DMA, so the buffer's input side is never written to HBM. It writes the blocks in use and
+nothing else; a padding row reads some token's row, since no pair's slot points at it.
+Where the kernel's gate declines (another backend, another type, widths or ``block_rows``
+off the tiles, an expert too large for VMEM) the same blocks go through a ``jnp`` loop
+over the held experts, and ``record_fallback("nn.moe", why)`` says why. Either way a pair
+held elsewhere contributes exactly 0, by a select and never by a product with 0.
 
 No reference counterpart (the reference has no expert layers).
 """
@@ -34,6 +45,8 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from ..core import diagnostics
+from ..core.kernels import grouped_matmul
 from .modules import GatedMLP, Module, contract, gated_silu, normal_weight
 
 __all__ = ["MoE"]
@@ -93,8 +106,8 @@ class MoE(Module):
         """Where each (token, expert) pair goes in the padded, expert-sorted buffer.
         Returns ``slot`` (T k,) int32 (pairs of experts held elsewhere: the buffer's
         length, which reads as nothing and writes nowhere), the token behind each buffer
-        row ``source`` (rows,) (padding: T, an all-zero row), each held expert's first row
-        and its number of blocks, and the load (count,)."""
+        row ``source`` (rows,) (padding: token 0, whose product no slot reads), each held
+        expert's first row and its number of blocks, and the load (count,)."""
         t, k = chosen.shape
         b, e = self.block_rows, self.count
         rows = -(-t * k // b) * b + e * b
@@ -112,12 +125,22 @@ class MoE(Module):
         rank = jnp.arange(t * k, dtype=jnp.int32) - edges[sorted_e]
         slot_sorted = jnp.where(sorted_e < e, first_row[sorted_e] + rank, jnp.int32(rows))
         slot = jnp.zeros((t * k,), jnp.int32).at[order].set(slot_sorted)
-        source = jnp.full((rows,), t, jnp.int32).at[slot_sorted].set(order // k, mode="drop")
+        source = jnp.zeros((rows,), jnp.int32).at[slot_sorted].set(order // k, mode="drop")
         return slot, source, first_row[:e], blocks, load[:e]
 
-    def _experts(self, experts, xs, first_row, blocks):
-        """``xs`` (rows, dim): every held expert's blocks through its gated MLP."""
-        b = self.block_rows
+    def _experts(self, experts, x, source, first_row, blocks):
+        """The sorted buffer ``x[source]`` (rows, dim): every held expert's blocks through
+        its gated MLP. Rows of blocks not in use come back as anything (the kernel) or as
+        0 (the loop)."""
+        b, rows = self.block_rows, source.shape[0]
+        why = (grouped_matmul.decline_reason(x, rows, experts["w_gate"], experts["w_down"], b)
+               if grouped_matmul.available() else f"backend {jax.default_backend()}")
+        if why is None:
+            return grouped_matmul.grouped_gated_silu(
+                x, source, experts["w_gate"], experts["w_up"], experts["w_down"],
+                *grouped_matmul.block_map(blocks, rows // b), block_rows=b)
+        diagnostics.record_fallback("nn.moe", why)
+        xs = jnp.take(x, source, axis=0, mode="clip")
 
         def one_expert(e, ys):
             weights = [lax.dynamic_index_in_dim(experts[name], e, 0, False)
@@ -139,13 +162,15 @@ class MoE(Module):
         with jax.named_scope("ht.nn.moe"):
             chosen, w = self.route(params, x)
             slot, source, first_row, blocks, load = self._layout(chosen)
-            xs = jnp.take(x, source, axis=0, mode="fill", fill_value=0)  # padding: zeros
-            ys = self._experts(params["experts"], xs, first_row, blocks)
-            # a pair held elsewhere reads row 0 with weight 0
+            ys = self._experts(params["experts"], x, source, first_row, blocks)
+            # a pair held elsewhere adds exactly 0: selected, since the row it would read may
+            # never have been written. Pairs are taken rank-major, (k, T), so that the
+            # weighted sum runs over the leading axis and not over a sublane-padded k
+            slot = slot.reshape(t, k).T.reshape(-1)
             held = slot < ys.shape[0]
-            picked = ys[jnp.where(held, slot, 0)].reshape(t, k, self.dim)
-            w = jnp.where(held.reshape(t, k), w, 0.0)
-            y = jnp.sum(picked.astype(jnp.float32) * w[:, :, None], axis=1)
+            picked = jnp.where(held[:, None], ys[jnp.where(held, slot, 0)], 0)
+            picked = picked.reshape(k, t, self.dim)
+            y = jnp.sum(picked.astype(jnp.float32) * w.T[:, :, None], axis=0)
             if self.shared is not None:
                 y = y + self.shared.apply(params["shared"], x).astype(jnp.float32)
             return y.astype(x.dtype), {"chosen": chosen, "load": load}

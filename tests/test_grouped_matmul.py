@@ -1,0 +1,255 @@
+"""The grouped expert kernel (``core/kernels/grouped_matmul.py``) inside ``ht.nn.MoE``, interpreted
+on the CPU at tile-aligned toy shapes: against the ``jnp`` loop it replaces on a TPU, against
+both plain references, over loads that no balanced router would give, with the rows it never
+wrote poisoned; its gate's reasons; its two counters. (It compiles for a described v5e at both
+cells' shapes in ``tests/test_kernels.py``, the one file that loads the TPU compiler.)"""
+
+import numpy as np
+import pytest
+
+import jax
+import jax.numpy as jnp
+
+import heat_tpu as ht
+from heat_tpu.core.kernels import grouped_matmul
+
+import reference_trinity
+import reference_xing4
+
+T, D, H, ROWS = 96, 256, 128, 16
+DTYPES = {"float32": jnp.float32, "bfloat16": jnp.bfloat16}
+# the largest bias a router's scores (0..1) cannot outweigh
+FORCE = 100.0
+_KERNEL = grouped_matmul.grouped_gated_silu  # before any fixture replaces the module's name
+
+
+def gap(got, want) -> float:
+    got, want = jnp.asarray(got, jnp.float32), jnp.asarray(want, jnp.float32)
+    return float(jnp.linalg.norm(got - want) / jnp.maximum(jnp.linalg.norm(want), 1e-30))
+
+
+def _poisoned(x, source, w_gate, w_up, w_down, block_expert, used, block_rows):
+    """The kernel, interpreted, with every row of a block not in use set to NaN: on the chip
+    those rows are never written and hold whatever the buffer held."""
+    ys = _KERNEL(x, source, w_gate, w_up, w_down, block_expert, used, block_rows, interpret=True)
+    written = jnp.arange(source.shape[0], dtype=jnp.int32) < used[0] * block_rows
+    return jnp.where(written[:, None], ys, jnp.nan)
+
+
+@pytest.fixture
+def interpreted(monkeypatch):
+    """``MoE`` takes the kernel here, interpreted and poisoned; the counters are on."""
+    monkeypatch.setattr(grouped_matmul, "available", lambda interpret=False: True)
+    monkeypatch.setattr(grouped_matmul, "grouped_gated_silu", _poisoned)
+    was_on = ht.diagnostics.enabled()
+    ht.diagnostics.enable()
+    ht.diagnostics.reset()
+    yield
+    ht.diagnostics.reset()
+    if not was_on:
+        ht.diagnostics.disable()
+
+
+def _counter(name):
+    return ht.diagnostics.report()["counters"].get(name, 0)
+
+
+def _layer(experts, top_k, held, dtype, load="even", seed=0):
+    """A layer, its parameters with the router's bias bent to ``load``, and tokens."""
+    m = ht.nn.MoE(D, H, experts, top_k, 1, 2.0, held, ROWS, dtype=dtype)
+    p = m.init(jax.random.key(seed))
+    first, count = held or (0, experts)
+    bias = p["router_bias"]
+    if load == "skewed":  # two experts take most tokens' first choices
+        bias = bias.at[first].add(0.6).at[first + count - 1].add(0.3)
+    elif load == "one_expert":  # every token chooses the first held expert; the rest of its k
+        others = [e for e in range(experts) if not first <= e < first + count][:top_k - 1]
+        if len(others) < top_k - 1:  # the whole layer is held: its last experts take the rest
+            others = list(range(experts - top_k + 1, experts))
+        bias = bias.at[jnp.asarray([first] + others)].add(FORCE)
+    elif load == "empty_experts":  # no token chooses every other held expert
+        bias = bias.at[first:first + count:2].add(-FORCE)
+    elif load == "share_not_chosen":  # every token's k choices lie outside the held share
+        outside = [e for e in range(experts) if not first <= e < first + count][:top_k]
+        bias = bias.at[jnp.asarray(outside)].add(FORCE)
+    p = dict(p, router_bias=bias)
+    u = jax.random.normal(jax.random.key(seed + 1), (T, D), jnp.float32).astype(dtype)
+    return m, p, u
+
+
+def _reference(which, p, u, experts, top_k, held):
+    if which == "xing4":
+        cfg = {"n_routed_experts": experts, "num_experts_per_tok": top_k, "routed_scaling_factor": 2.0}
+        return reference_xing4.moe(p, u, cfg, held)
+    cfg = {"num_experts": experts, "num_experts_per_tok": top_k, "route_scale": 2.0}
+    return reference_trinity.moe(p, u, cfg, held)
+
+
+SHAPES = [(8, 2, None), (8, 2, (2, 4)), (16, 4, None), (16, 4, (8, 8)), (4, 1, (3, 1))]
+
+
+@pytest.mark.parametrize("dtype", list(DTYPES))
+@pytest.mark.parametrize("experts,top_k,held", SHAPES, ids=lambda v: str(v).replace(" ", ""))
+def test_the_kernel_equals_the_loop_and_meets_both_references(interpreted, experts, top_k, held, dtype):
+    """The same layer through the kernel and through the ``jnp`` loop: equal to the last bit
+    here (one block's three products in the same order); both references within float32's
+    rounding, or bfloat16's where the streams are bfloat16."""
+    m, p, u = _layer(experts, top_k, held, DTYPES[dtype])
+    y, aux = m.apply(p, u)
+    assert _counter("fallback.nn.moe") == 0
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setattr(grouped_matmul, "available", lambda interpret=False: False)
+        y_loop, aux_loop = m.apply(p, u)
+    assert _counter("fallback.nn.moe") == 1
+    assert np.array_equal(np.asarray(y, np.float32), np.asarray(y_loop, np.float32))
+    assert np.array_equal(np.asarray(aux["load"]), np.asarray(aux_loop["load"]))
+    p32 = jax.tree_util.tree_map(lambda a: a.astype(jnp.float32), p)
+    for which in ("xing4", "trinity"):
+        want, chosen = _reference(which, p32, u.astype(jnp.float32), experts, top_k, held)
+        assert np.array_equal(np.asarray(aux["chosen"]), np.asarray(chosen))
+        assert gap(y, want) < (1e-5 if dtype == "float32" else 1.5e-2)
+
+
+@pytest.mark.parametrize("load,held", [
+    (load, held) for load in ("skewed", "one_expert", "empty_experts", "share_not_chosen")
+    for held in (None, (4, 4)) if (load, held) != ("share_not_chosen", None)  # the whole is always chosen
+], ids=lambda v: "whole" if v is None else "share" if isinstance(v, tuple) else v)
+def test_no_load_drops_a_token_or_reads_an_unwritten_row(interpreted, load, held):
+    """Whatever the imbalance, every token's experts are multiplied (the reference has no
+    capacity), and the rows the kernel never wrote, NaN here, reach nothing. With a held
+    share that no token chose the layer's output is exactly the shared expert's."""
+    m, p, u = _layer(8, 2, held, jnp.float32, load)
+    y, aux = m.apply(p, u)
+    load_held = np.asarray(aux["load"])
+    first, count = held or (0, 8)
+    chosen = np.asarray(aux["chosen"])
+    assert np.array_equal(load_held, np.bincount(chosen.reshape(-1), minlength=8)[first:first + count])
+    if load == "one_expert":
+        assert load_held[0] == T and load_held[1:].sum() == (T if held is None else 0)
+    if load == "empty_experts":
+        assert not load_held[::2].any() and load_held[1::2].all()
+    assert bool(jnp.isfinite(y).all())
+    if load == "share_not_chosen":
+        assert not load_held.any()
+        assert np.array_equal(np.asarray(y), np.asarray(m.shared.apply(p["shared"], u)))
+    want, _ = _reference("trinity", p, u, 8, 2, held)
+    assert gap(y, want) < 1e-5
+
+
+def test_a_block_past_the_used_count_is_left_alone():
+    """The map repeats the last used block's expert past the used count, and the kernel's
+    output there is not a product: whatever the unused blocks' sources name, nothing of
+    them comes out."""
+    blocks = jnp.asarray([2, 0, 1, 0], jnp.int32)
+    expert, used = grouped_matmul.block_map(blocks, 6)
+    assert np.array_equal(np.asarray(expert), [0, 0, 2, 2, 2, 2]) and int(used[0]) == 3
+    expert0, used0 = grouped_matmul.block_map(jnp.zeros((4,), jnp.int32), 6)
+    assert int(used0[0]) == 0 and len(set(np.asarray(expert0).tolist())) == 1  # one expert, fetched once
+    k1, k2, k3, k4, k5 = jax.random.split(jax.random.key(0), 5)
+    x = jax.random.normal(k1, (40, D), jnp.float32)
+    source = jax.random.randint(k5, (6 * ROWS,), 0, 40, jnp.int32)
+    w = [jax.random.normal(k, s, jnp.float32) * 0.1
+         for k, s in ((k2, (4, D, H)), (k3, (4, D, H)), (k4, (4, H, D)))]
+    ys = _KERNEL(x, source, *w, expert, used, ROWS, interpret=True)
+    for j, e in enumerate([0, 0, 2]):
+        rows = slice(j * ROWS, (j + 1) * ROWS)
+        want = ht.nn.modules.gated_silu(x[source[rows]], w[0][e], w[1][e], w[2][e])
+        assert gap(ys[rows], want) < 1e-6
+    # what the unused blocks' sources name does not matter (they name a token, as padding does)
+    moved = _KERNEL(x, source.at[3 * ROWS:].set(7), *w, expert, used, ROWS, interpret=True)
+    assert np.array_equal(np.asarray(ys[:3 * ROWS]), np.asarray(moved[:3 * ROWS]))
+    # a step whose rows are walked in a rolled loop of chunks (what the rule does at 512 rows)
+    assert grouped_matmul._row_chunk(ROWS, D, H, 4) == ROWS
+    assert grouped_matmul._row_chunk(512, 2048, 1024, 2) == 128 == grouped_matmul._row_chunk(512, 3584, 1024, 2)
+    assert grouped_matmul._row_chunk(512, 8192, 2048, 2) == 64  # a body past the size measured good
+    halves = grouped_matmul._grouped_pallas(x, source, *w, expert, used, block_rows=ROWS,
+                                            sub=ROWS // 2, interpret=True)
+    assert np.array_equal(np.asarray(ys[:3 * ROWS]), np.asarray(halves[:3 * ROWS]))
+
+
+@pytest.mark.parametrize("dtype", list(DTYPES))
+def test_tokens_survive_the_packing(dtype):
+    """``_words`` lays a token out as whole (8, 128) tiles of 32-bit words, bfloat16 columns ``c``
+    and ``c + d / 2`` in one word: unpacked as the kernel does, every element comes back."""
+    d = 3 * 256  # 3 lane tiles of bfloat16 pairs, 6 of float32: padded to 8
+    x = jax.random.normal(jax.random.key(3), (5, d), jnp.float32).astype(DTYPES[dtype])
+    tiles, padded = grouped_matmul._token_tiles(d, x.dtype.itemsize)
+    assert (tiles, padded) == ((3, 8) if dtype == "bfloat16" else (6, 8))
+    words = grouped_matmul._words(x).reshape(5, padded * 128)[:, :tiles * 128]
+    if dtype == "float32":
+        back = jax.lax.bitcast_convert_type(words, jnp.float32)
+    else:
+        low = jax.lax.bitcast_convert_type(words << 16, jnp.float32)
+        high = jax.lax.bitcast_convert_type(words & jnp.uint32(0xFFFF0000), jnp.float32)
+        back = jnp.concatenate([low, high], axis=1).astype(jnp.bfloat16)
+    assert np.array_equal(np.asarray(back, np.float32), np.asarray(x, np.float32))
+
+
+REASONS = {
+    "float16": (dict(dtype=jnp.float16), "streams float16"),
+    "mixed": (dict(w_dtype=jnp.float32, down_dtype=jnp.bfloat16), "streams"),
+    "width": (dict(d=128), "tiles: d=128"),
+    "hidden": (dict(h=192), "h=192"),
+    "block_rows": (dict(block_rows=8), "block_rows=8"),
+    "ragged": (dict(rows=ROWS * 3, block_rows=32), "divide rows"),
+    "source_tile": (dict(rows=96 * 4, block_rows=96), "1024"),
+    "vmem": (dict(d=8192, h=4096, block_rows=512, rows=1024), "VMEM"),
+}
+
+
+@pytest.mark.parametrize("case", list(REASONS))
+def test_the_gate_says_why(case):
+    kw, said = REASONS[case]
+    dtype = kw.get("dtype", jnp.bfloat16)
+    d, h, rows, block_rows = kw.get("d", D), kw.get("h", H), kw.get("rows", 4 * ROWS), kw.get("block_rows", ROWS)
+    x = jax.ShapeDtypeStruct((T, d), dtype)
+    w_gate = jax.ShapeDtypeStruct((4, d, h), kw.get("w_dtype", dtype))
+    w_down = jax.ShapeDtypeStruct((4, h, d), kw.get("down_dtype", kw.get("w_dtype", dtype)))
+    assert said in grouped_matmul.decline_reason(x, rows, w_gate, w_down, block_rows)
+
+
+@pytest.mark.parametrize("d,h,e", [(2048, 1024, 128), (3584, 1024, 64)], ids=["trinity", "xing4"])
+def test_the_gate_admits_both_cells(d, h, e):
+    """Whole experts resident in both pipeline buffers, under the cap, at the two widths the
+    benchmark runs; float32 streams of Xing4's width are past it and say so."""
+    x = jax.ShapeDtypeStruct((32768, d), jnp.bfloat16)
+    w_gate, w_down = (jax.ShapeDtypeStruct(s, jnp.bfloat16) for s in ((e, d, h), (e, h, d)))
+    assert grouped_matmul.decline_reason(x, 4096, w_gate, w_down, 512) is None
+    need = grouped_matmul._footprint(d, h, 512, 2, 2)
+    assert 2 * 3 * d * h * 2 < need < grouped_matmul._VMEM_CAP - grouped_matmul._VMEM_MARGIN
+    x32, g32, d32 = (jax.ShapeDtypeStruct(a.shape, jnp.float32) for a in (x, w_gate, w_down))
+    assert ("VMEM" in (grouped_matmul.decline_reason(x32, 4096, g32, d32, 512) or "")) == (d == 3584)
+
+
+def test_the_counters_count_traces_not_calls(interpreted):
+    """``kernels.gmm.fwd`` counts a trace of the path that took the kernel, ``fallback.nn.moe`` a
+    trace that did not, with its reason; a warmed call counts neither."""
+    _, p, u = _layer(8, 2, None, jnp.bfloat16)
+    m = ht.nn.MoE(D, H, 8, 2, 1, 2.0, None, 2 * ROWS, dtype=jnp.bfloat16)  # this test's own shape
+    f = jax.jit(lambda p, u: m.apply(p, u)[0])
+    f(p, u)
+    assert _counter("kernels.gmm.fwd") >= 1 and _counter("fallback.nn.moe") == 0
+    before = _counter("kernels.gmm.fwd")
+    f(p, u)
+    assert _counter("kernels.gmm.fwd") == before
+    odd = ht.nn.MoE(D, H, 8, 2, 1, 2.0, None, 8, dtype=jnp.bfloat16)  # half a sublane tile
+    jax.jit(lambda p, u: odd.apply(p, u)[0])(p, u)
+    assert _counter("fallback.nn.moe") == 1
+    event = ht.diagnostics.report()["fallback_events"][-1]
+    assert event["site"] == "nn.moe" and "block_rows=8" in event["reason"]
+
+
+def test_without_a_tpu_the_loop_runs_and_says_so():
+    was_on = ht.diagnostics.enabled()
+    ht.diagnostics.enable()
+    ht.diagnostics.reset()
+    try:
+        m, p, u = _layer(8, 2, None, jnp.float32)
+        y, _ = m.apply(p, u)
+        assert _counter("kernels.gmm.fwd") == 0 and _counter("fallback.nn.moe") == 1
+        assert "backend cpu" in ht.diagnostics.report()["fallback_events"][-1]["reason"]
+        assert gap(y, _reference("xing4", p, u, 8, 2, None)[0]) < 1e-5
+    finally:
+        ht.diagnostics.reset()
+        if not was_on:
+            ht.diagnostics.disable()

@@ -2,7 +2,9 @@
 (validated in interpreter mode so the same test runs on the CPU mesh), the Lloyd
 program must carry the kernel in the form each place needs, and the kernel must compile
 for a described v5e at the block its gate picks. The flash forward compiles there too,
-at the latent-attention cell's shape and the blocks its gate picks."""
+at the latent-attention cell's shape and the blocks its gate picks, and so does the expert
+layer with its grouped kernel at both model cells' shapes (this file is the one that loads
+the TPU compiler; the kernel's other cases are in ``test_grouped_matmul.py``)."""
 
 import functools
 import os
@@ -17,6 +19,7 @@ import heat_tpu as ht
 from heat_tpu.cluster import _kcluster
 from heat_tpu.core.kernels import fused_assign_update, fused_assign_update_reference
 from heat_tpu.core.kernels import flash_attention as flash_kernel
+from heat_tpu.core.kernels import grouped_matmul
 from heat_tpu.core.kernels import kmeans as kmeans_kernel
 from heat_tpu.core.kernels.kmeans import _block_n, _fused_pallas
 from heat_tpu.testing import TestCase
@@ -366,3 +369,57 @@ if __name__ == "__main__":
     import unittest
 
     unittest.main()
+
+
+@pytest.mark.parametrize("cell,d,experts,top_k,chunk", [("trinity-score-32k", 2048, 128, 8, 128),
+                                                       ("xing4-score-32k", 3584, 64, 4, 128)])
+def test_mosaic_compiles_the_expert_layer_at_the_cell_shape(one_chip, monkeypatch, cell, d, experts,
+                                                           top_k, chunk):
+    """One expert layer of each model cell, 32,768 tokens in bfloat16 through ``MoE.apply``, as
+    a TPU would run it: the grouped kernel is in the program under its name, with whole experts
+    resident (the raised VMEM limit is one Mosaic accepts), the rows fetched through the
+    sorted index, and the row chunk the rule reads off the widths; the loop over the experts is gone (no ``while`` but the layout's binary search),
+    and with it every ``dynamic-update-slice`` into the sorted buffer."""
+    monkeypatch.setattr(grouped_matmul, "available", lambda interpret=False: True)
+    m = ht.nn.MoE(d, 1024, experts, top_k, 1, 2.5, None, 512, dtype=jnp.bfloat16)
+    assert grouped_matmul._row_chunk(512, d, 1024, 2) == chunk
+
+    def placed(a):
+        return jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip)
+
+    params = jax.tree_util.tree_map(placed, jax.eval_shape(m.init, jax.random.key(0)))
+    x = jax.ShapeDtypeStruct((32768, d), jnp.bfloat16, sharding=one_chip)
+    compiled = jax.jit(lambda p, x: m.apply(p, x)).lower(params, x).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text and "moe_grouped_fwd" in text
+    rows = 32768 * top_k + experts * 512
+    buffer = f"bf16[{rows},{d}]"
+    # the sorted buffer exists once, as the kernel's output: its input side is never made
+    # (the rows come through the sorted index), so no gather writes a buffer of its size
+    written = [line for line in text.splitlines()
+               if f" = {buffer}" in line and " parameter(" not in line]
+    assert len(written) == 1 and "moe_grouped_fwd" in written[0]
+    assert not [line for line in text.splitlines()
+                if "dynamic-update-slice" in line and buffer in line]
+    # the one loop left is the layout's binary search for the experts' edges
+    loops = [line for line in text.splitlines() if " while(" in line]
+    assert len(loops) == 1 and "searchsorted" in loops[0]
+
+
+@pytest.mark.parametrize("dtype,precision", [(jnp.bfloat16, "highest"), (jnp.float32, "default")],
+                         ids=["bfloat16", "float32"])
+def test_the_grouped_kernel_states_its_precision(one_chip, dtype, precision):
+    """Under a process-wide "highest" (the serving configuration sets it) the kernel's bfloat16
+    contractions still compile: they state one MXU pass, which Mosaic would otherwise refuse.
+    Float32 streams compile too (their rows are words as they are, their products ``HIGHEST``)."""
+    def shaped(shape, dtype=dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    args = (shaped((1024, 2048)), shaped((4096,), jnp.int32), shaped((4, 2048, 512)),
+            shaped((4, 2048, 512)), shaped((4, 512, 2048)), shaped((8,), jnp.int32),
+            shaped((1,), jnp.int32))
+    assert grouped_matmul.decline_reason(args[0], 4096, args[2], args[4], 512) is None
+    with jax.default_matmul_precision(precision):
+        compiled = jax.jit(functools.partial(grouped_matmul.grouped_gated_silu, block_rows=512)
+                           ).lower(*args).compile()
+    assert "moe_grouped_fwd" in compiled.as_text()

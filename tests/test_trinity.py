@@ -331,17 +331,19 @@ def test_xing4_is_unchanged_by_what_trinity_shares_with_it(what):
     """``nn/scoring.py`` took ``Xing4``'s scoring tail (the head over the continuation's
     positions, ``readback``, the trace counter) so that ``Trinity`` shares it, and the flash
     forward's one body gained the window and the grouped heads. ``program``: the lowered
-    text of the ``Xing4`` program at ``test_xing4.py``'s size is, byte for byte, what the
-    commit before those changes (PR 30) lowers. ``kernel``: so is the jaxpr of the causal
-    forward at ``xing4-score-32k``'s shape, the Pallas body included. A PR that changes
-    either on purpose replaces the digest here."""
+    text of the ``Xing4`` program at ``test_xing4.py``'s size was, byte for byte, what the
+    commit before those changes (PR 30) lowered; PR 32 replaced the digest on purpose
+    (``nn/moe.py``: padding rows read token 0 and no longer a zero fill, a pair held elsewhere
+    is selected to 0, the weighted sum takes its pairs rank-major). ``kernel``: the jaxpr of the causal
+    forward at ``xing4-score-32k``'s shape, the Pallas body included, is still PR 30's. A PR
+    that changes either on purpose replaces the digest here."""
     if what == "program":
         from test_xing4 import CFG as XING4, CONT as X_CONT, T as X_T
 
         model = ht.nn.Xing4(XING4, continuation=X_CONT, dtype=jnp.bfloat16, block_rows=16)
         params = jax.eval_shape(model.init, jax.random.key(0))
         text = model._program.lower(params, jax.ShapeDtypeStruct((X_T,), jnp.int32)).as_text()
-        want = "beb2418c2e90a9fc95b46c2b3709082c4031a8dfb191ac14f5573aef29df1275"
+        want = "bef63103e2b58b18a1ca62984a21a4b7fa01252ef046c53bc01c6a698f2dd232"
         assert ht.nn.Xing4.traces == "nn.xing4.traces"
         assert ht.nn.Xing4.logliks == ("loglik", "mtp_loglik")
     else:
