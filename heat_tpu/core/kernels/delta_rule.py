@@ -1,0 +1,375 @@
+"""Kimi Delta Attention between its projections: the gated delta-rule recurrence, chunked,
+with the convolution, norms and gates round it (Pallas, TPU).
+
+For one head, with a state ``S`` (d_k, d_v) in float32 that starts at 0::
+
+    S_t = (I - beta_t k_t k_t^T) Diag(exp(g_t)) S_{t-1} + beta_t k_t v_t^T
+    o_t = S_t^T q_t
+
+``g_t`` (d_k,) is the log-decay of every channel, ``-5 <= g_t <= 0`` (the bound is
+:data:`LOG_DECAY_BOUND`, ``kda_lower_bound`` of the published configurations), ``beta_t`` a
+scalar in (0, 1), ``|k_t| = 1``. :func:`chunk_step` takes ``k`` and the two products
+``beta k`` and ``beta v``, rounded to the operands' type.
+
+**The chunked (WY) form.** Over a chunk of ``C`` = :data:`CHUNK` positions that starts at
+state ``S_0``, with ``G_r = g_1 + .. + g_r`` (float32, inside the chunk)::
+
+    A[r, i]   = beta_r sum_c k_r[c] k_i[c] exp(G_r[c] - G_i[c])        i <  r, else 0
+    Aqk[r, i] =        sum_c q_r[c] k_i[c] exp(G_r[c] - G_i[c])        i <= r, else 0
+    T         = (I + A)^-1
+    U         = T (beta V)  -  T (beta K * exp(G)) S_0                 the chunk's updates
+    O         = (Q * exp(G)) S_0  +  Aqk U
+    S_C       = Diag(exp(G_C)) S_0  +  (K * exp(G_C - G))^T U
+
+``exp(-G)`` **is never taken over more than** :data:`SUB` **positions**: a chunk is cut
+into sub-chunks of 16 rows, a row block ``J`` takes its reference ``b_J`` = ``G`` just
+before the block, its rows carry ``exp(G_r - b_J + 40)`` and the columns
+``exp(min(b_J - G_i - 40, 40))``: a sub-chunk's range of ``exp(16 * 5) = exp(80) < 3.4e38``
+is shared between the two factors, so neither leaves float32 (a row factor lies in
+``exp(+-40)``; a column factor underflows only where the pair's weight is under
+``exp(-47)``); right of the block the clamp holds and the triangle's select drops the
+entry. ``T`` is exact arithmetic on nilpotent float32 matrices: the 16 x 16 diagonal blocks
+by ``(I + X)^-1 = (I - X)(I + X^2)(I + X^4)(I + X^8)`` (powers of a 16-row block stay small:
+no cancellation), then the 4 x 4 block structure the same way, ten products of 64 x 64 in
+all. ``T`` is then rounded to the operands' type, so its products follow that type
+(:func:`_product`): for float32 operands ``Precision.HIGHEST``; for bfloat16 operands each
+factor as two bfloat16 pieces and three products, 2^-17 of the result, at a third of the
+MXU passes and none of the splits and sums that six passes bring. Every other product takes
+its operands in the type they are stored in (gated operands are formed in float32 and
+rounded to it; the state is rounded to it for its two products, as published kernels do)
+and accumulates in float32; 16-bit operands are one MXU pass, float32 operands multiply at
+``Precision.HIGHEST``. The cumulative log-decay is exact in float32: the 0/1 triangle is
+exact in bfloat16 and ``g`` goes in as three bfloat16 pieces (24 bits) side by side.
+
+**Round the recurrence** (:func:`head_chunk`) a chunk step also does what Kimi Delta
+Attention puts before and after it, so that q, k, v, g and o never go through HBM on their
+own: from the three projections as stored, the causal depthwise convolution (its taps on
+the :data:`BEFORE` rows before the chunk too), SiLU, the L2 norm of q and k a head and
+``beta``, and the log-decay from its float32 pre-activation; after it the head's RMS norm
+and its sigmoid gate.
+
+**The call.** ``xq, xk, xv`` and the decay's pre-activation (T, H d) lie as the projections
+leave them, heads side by side on the lanes, so nothing is transposed on the way in or out.
+The grid is (head groups, chunks): a step takes one chunk of :data:`HEADS` heads, which
+ride a leading axis through :func:`chunk_step` so that every product is issued for all of
+them at once: a head's inverse alone is a chain of eight dependent 64 x 64 products, each a
+full MXU latency, and heads looped one after another did not overlap (36.8 ms a layer of 32
+heads over 32,768 positions on a v5e for four heads a step, 41.9 for one; on a leading axis
+18.7 for four, 15.2 for eight, 14.4 for sixteen: my chip runs, PR 33). Each head's state
+(kept as ``S^T``, so a channel's decay is a lane's) stays in VMEM over the chunk axis, which
+is sequential. The rows before a chunk are a second, 16-row view of the same three arrays.
+The call is named ``kda_chunk_fwd`` in a device trace.
+
+:func:`kda_mix_reference` is the same chunk step on all heads at once under ``lax.scan``
+over the chunks, in plain ``jnp``: the fallback of ``nn/kda.py`` on other backends and
+shapes (it pads a sequence that does not tile), and what the tests hold the interpreted
+kernel to, beside the token-by-token recurrence of ``tests/reference_ling.py``.
+
+No reference counterpart.
+"""
+
+from __future__ import annotations
+
+import functools
+from typing import Optional
+
+import jax
+import jax.numpy as jnp
+from jax import lax
+
+from .. import diagnostics
+
+__all__ = ["CHUNK", "SUB", "BEFORE", "LOG_DECAY_BOUND", "chunk_step", "short_conv", "head_chunk",
+           "kda_mix", "kda_mix_reference", "available", "decline_reason"]
+
+CHUNK = 64
+SUB = 16
+BEFORE = 16  # rows before a chunk that a step sees (a bfloat16 tile): the convolution's reach
+LOG_DECAY_BOUND = -5.0  # SUB * 5 = 80 < 88: exp stays inside float32 over a sub-chunk
+HEADS = 8  # heads a grid step takes together
+_LANES = 128
+_HALF = -LOG_DECAY_BOUND * SUB / 2  # 40: half of a sub-chunk's range of log-decay
+_L2_EPS = 1e-6
+_F32 = jnp.float32
+
+
+def _dot(a, b, dims):
+    """``dot_general`` of (n, ., .) operands over ``dims``, one product a head, accumulated
+    in float32: 16-bit operands one MXU pass (whatever the process-wide default says),
+    float32 operands exact."""
+    exact = a.dtype.itemsize >= 4 or b.dtype.itemsize >= 4
+    return lax.dot_general(a, b, (dims, ((0,), (0,))), preferred_element_type=_F32,
+                           precision=lax.Precision.HIGHEST if exact else lax.Precision.DEFAULT)
+
+
+_NN = ((2,), (1,))  # a @ b
+_NT = ((2,), (2,))  # a @ b^T
+_TN = ((1,), (1,))  # a^T @ b
+
+
+def _pieces(x, n: int):
+    """``x`` (float32) as ``n`` bfloat16 pieces that add up to it: 8, 16, 24 bits."""
+    parts, rest = [], x
+    for _ in range(n):
+        parts.append(rest.astype(jnp.bfloat16))
+        rest = rest - parts[-1].astype(_F32)
+    return parts
+
+
+def _product(a, b, exact: bool):
+    """``a @ b`` of float32 squares, a head each. ``exact``: at ``Precision.HIGHEST`` (six
+    MXU passes with their splits and sums). Otherwise each operand as two bfloat16 pieces and
+    the three products that matter, ``(a_hi + a_lo) b_hi + a_hi b_lo``: 2^-17 of the result,
+    for a result that is rounded to bfloat16's 2^-9 when it is used."""
+    if exact:
+        return _dot(a, b, _NN)
+    n = a.shape[1]
+    (a_hi, a_lo), (b_hi, b_lo) = _pieces(a, 2), _pieces(b, 2)
+    both = _dot(jnp.concatenate([a_hi, a_lo], axis=1), b_hi, _NN)
+    return both[:, :n] + both[:, n:] + _dot(a_hi, b_lo, _NN)
+
+
+def _inverse_of_one_plus(x, nilpotency: int, exact: bool):
+    """``(I + x)^-1`` of float32 squares ``x`` (n, c, c) with ``x^nilpotency = 0``:
+    ``(I - x)(I + x^2)(I + x^4)..``, each factor one :func:`_product`."""
+    c = x.shape[1]
+    eye = (lax.broadcasted_iota(jnp.int32, (c, c), 0)
+           == lax.broadcasted_iota(jnp.int32, (c, c), 1)).astype(_F32)
+    inv, power, reach = eye - x, x, 2
+    while reach < nilpotency:
+        power = _product(power, power, exact)
+        inv = inv + _product(inv, power, exact)
+        reach *= 2
+    return inv
+
+
+def chunk_step(q, k, kb, vb, g, st, sub: int = SUB):
+    """One chunk of ``n`` heads, every line a head's own: the heads ride a leading axis so
+    that each product is issued for all of them before the next one that waits for it
+    (their chains are independent, a chain's products are not). ``q, k, kb`` (n, C, d_k) and
+    ``vb`` (n, C, d_v) in the operands' type, ``g`` (n, C, d_k) float32, ``st`` the states
+    transposed, (n, d_v, d_k) float32. Returns ``(o (n, C, d_v) float32, st)``. ``C`` is a
+    whole number of sub-chunks of ``sub`` rows."""
+    n, c, d_k = k.shape
+    op = q.dtype
+    row = lax.broadcasted_iota(jnp.int32, (c, c), 0)
+    col = lax.broadcasted_iota(jnp.int32, (c, c), 1)
+    same = (row // sub) == (col // sub)
+    # the log-decay summed inside a row's sub-chunk (inclusive) and before the sub-chunk:
+    # a 0/1 triangle, exact in bfloat16, times g as three bfloat16 pieces side by side
+    triangle = jnp.concatenate([same & (col <= row), (col // sub) < (row // sub)])
+    sums = _dot(jnp.broadcast_to(triangle.astype(jnp.bfloat16), (n, 2 * c, c)),
+                jnp.concatenate(_pieces(g, 3), axis=2), _NN)
+    sums = sums[:, :, :d_k] + sums[:, :, d_k:2 * d_k] + sums[:, :, 2 * d_k:]
+    local, before = sums[:, :c], sums[:, c:]
+    total = before + local
+    kf = k.astype(_F32)
+    # a row's and a column's factor share the sub-chunk's range of exp(+-80) between them
+    lift = jnp.exp(local + _HALF)
+    rows_k, rows_q = kb.astype(_F32) * lift, q.astype(_F32) * lift
+    a_parts, qk_parts = [], []
+    for j in range(c // sub):
+        lo = j * sub
+        columns = (kf * jnp.exp(jnp.minimum(before[:, lo:lo + 1] - total - _HALF, _HALF))
+                   ).astype(op)
+        rows = jnp.concatenate([rows_k[:, lo:lo + sub], rows_q[:, lo:lo + sub]], axis=1)
+        s = _dot(rows.astype(op), columns, _NT)
+        a_parts.append(s[:, :sub])
+        qk_parts.append(s[:, sub:])
+    a = jnp.where(col < row, jnp.concatenate(a_parts, axis=1), 0.0)
+    a_qk = jnp.where(col <= row, jnp.concatenate(qk_parts, axis=1), 0.0)
+    # T = (I + A)^-1: the diagonal blocks, then the blocks below them
+    exact = op.itemsize >= 4  # T is rounded to the operands' type: float32 wants it exact
+    diagonal = _inverse_of_one_plus(jnp.where(same, a, 0.0), sub, exact)
+    below = _product(diagonal, jnp.where(same, 0.0, a), exact)
+    t = _product(_inverse_of_one_plus(below, c // sub, exact), diagonal, exact).astype(op)
+
+    decay = jnp.exp(total)
+    wu = _dot(t, jnp.concatenate([(kb.astype(_F32) * decay).astype(op), vb], axis=2), _NN)
+    st_op = st.astype(op)
+    u = (wu[:, :, d_k:] - _dot(wu[:, :, :d_k].astype(op), st_op, _NT)).astype(op)
+    o = _dot((q.astype(_F32) * decay).astype(op), st_op, _NT) + _dot(a_qk.astype(op), u, _NN)
+    whole = jnp.sum(g, axis=1, keepdims=True)  # the chunk's log-decay, (n, 1, d_k)
+    st = st * jnp.exp(whole) + _dot(u, (kf * jnp.exp(whole - total)).astype(op), _TN)
+    return o, st
+
+
+def short_conv(x, rows, w):
+    """``SiLU(sum_j w[j] * x_{t-(width-1)+j})`` over a chunk ``x`` (n, C, d) whose ``rows``
+    (n, B, d) come before it, with ``w`` (n, width, d) float32: a causal depthwise
+    convolution, float32."""
+    c, width = x.shape[1], w.shape[1]
+    ext = jnp.concatenate([rows.astype(_F32), x.astype(_F32)], axis=1)
+    first = rows.shape[1] - (width - 1)
+    y = w[:, 0:1] * ext[:, first:first + c]
+    for j in range(1, width):
+        y = y + w[:, j:j + 1] * ext[:, first + j:first + j + c]
+    return y * jax.nn.sigmoid(y)
+
+
+def head_chunk(xq, xk, xv, before, taps, pre, rate, side, norm_w, st, bound: float, eps: float):
+    """One chunk of ``n`` heads from the projections to the gated, normed output. ``xq, xk,
+    xv`` (n, C, d) as stored; ``before``: the three arrays' :data:`BEFORE` rows before the
+    chunk (zeros at the document's start); ``taps``: three (n, width, d) float32; ``pre`` (n,
+    C, d) and ``rate`` (n, 1, d) float32: the log-decay is ``bound * sigmoid(rate * pre)``;
+    ``side`` (n, C, 2) float32: beta and the gate's sigmoid; ``norm_w`` (1, d) float32;
+    ``st`` (n, d, d) float32. Returns ``(y (n, C, d) float32, st)``."""
+    d, op = xq.shape[2], xq.dtype
+    g = bound * jax.nn.sigmoid(rate * pre)
+
+    def unit(y):
+        return y * lax.rsqrt(jnp.sum(y * y, axis=2, keepdims=True) + _L2_EPS)
+
+    q, k, v = (short_conv(x, rows, w) for x, rows, w in zip((xq, xk, xv), before, taps))
+    q, k = unit(q) * d ** -0.5, unit(k)
+    beta, gate = side[:, :, 0:1], side[:, :, 1:2]
+    o, st = chunk_step(q.astype(op), k.astype(op), (beta * k).astype(op), (beta * v).astype(op),
+                       g, st)
+    o = o * lax.rsqrt(jnp.mean(o * o, axis=2, keepdims=True) + eps) * norm_w
+    return o * gate, st
+
+
+def _taps32(taps):
+    return tuple(w.astype(_F32) for w in taps)
+
+
+def kda_mix_reference(xq, xk, xv, taps, pre, rate, beta, gate, norm_w, heads: int,
+                      bound: float, eps: float):
+    """The chunked form in plain ``jnp``: operands as :func:`kda_mix` takes them. A sequence
+    that is no whole number of chunks is padded with positions after its end, which nothing
+    before them reads, and cut again."""
+    t, d = xq.shape[0], xq.shape[1] // heads
+    n = -(-t // CHUNK)
+
+    def by_chunk(x, width):
+        x = jnp.pad(x, ((0, n * CHUNK - t), (0, 0)))
+        return jnp.moveaxis(x.reshape(n, CHUNK, heads, width), 2, 1)  # (n, heads, C, width)
+
+    xs = [by_chunk(x, d) for x in (xq, xk, xv)]
+    # the rows before chunk j are the last of chunk j - 1; zeros before the first
+    rows = [jnp.concatenate([jnp.zeros_like(x[:1, :, :BEFORE]), x[:-1, :, CHUNK - BEFORE:]])
+            for x in xs]
+    side = by_chunk(jnp.stack([beta, gate], axis=-1).astype(_F32).reshape(t, 2 * heads), 2)
+    taps = tuple(jnp.moveaxis(w.reshape(-1, heads, d), 1, 0) for w in _taps32(taps))
+    rate, norm_w = rate.astype(_F32).reshape(heads, 1, d), norm_w.astype(_F32).reshape(1, d)
+
+    def one(st, chunk):
+        y, st = head_chunk(*chunk[:3], chunk[3:6], taps, chunk[6], rate, chunk[7], norm_w, st,
+                           bound, eps)
+        return st, y
+
+    st0 = jnp.zeros((heads, d, d), _F32)
+    _, y = lax.scan(one, st0, (*xs, *rows, by_chunk(pre.astype(_F32), d), side))
+    return jnp.moveaxis(y, 1, 2).reshape(n * CHUNK, heads * d)[:t].astype(xq.dtype)
+
+
+def available(interpret: bool = False) -> bool:
+    """Whether the kernel can run here: on a TPU backend, or interpreted anywhere."""
+    return interpret or jax.default_backend() == "tpu"
+
+
+def decline_reason(x, taps, heads: int) -> Optional[str]:
+    """Why the kernel is not compiled for projections ``x`` (T, heads * d) and convolutions
+    of ``taps`` (width, heads * d), or ``None`` where it is."""
+    if x.dtype not in (jnp.dtype(jnp.bfloat16), jnp.dtype(jnp.float32)):
+        return f"operands {x.dtype}: the kernel takes bfloat16 or float32"
+    t, d = x.shape[0], x.shape[1] // heads
+    if d % _LANES:
+        return f"tiles: a head's width d={d} must be whole lane tiles of {_LANES}"
+    if t % CHUNK:
+        return f"tiles: T={t} is no whole number of chunks of {CHUNK}"
+    if taps.shape[0] - 1 > BEFORE:
+        return f"a convolution of width {taps.shape[0]} reaches past the {BEFORE} rows a step sees"
+    return None
+
+
+def _heads_a_step(heads: int) -> int:
+    hb = min(HEADS, heads)
+    while heads % hb:
+        hb -= 1
+    return hb
+
+
+def _kernel(xq_ref, xk_ref, xv_ref, rq_ref, rk_ref, rv_ref, wq_ref, wk_ref, wv_ref, pre_ref,
+            rate_ref, side_ref, norm_ref, y_ref, st_ref, *, hb: int, d: int, bound: float,
+            eps: float):
+    import jax.experimental.pallas as pl
+
+    j = pl.program_id(1)
+
+    @pl.when(j == 0)
+    def _start():  # a head group's first chunk: S_0 = 0
+        st_ref[...] = jnp.zeros_like(st_ref)
+
+    inside = (j > 0).astype(_F32)  # the rows before the first chunk are zeros, not rows 0..15
+
+    def heads_of(ref, width=d):  # (rows, hb * width) on the lanes -> (hb, rows, width)
+        return jnp.stack([ref[:, h * width:(h + 1) * width] for h in range(hb)])
+
+    before = tuple(heads_of(r).astype(_F32) * inside for r in (rq_ref, rk_ref, rv_ref))
+    taps = tuple(heads_of(w) for w in (wq_ref, wk_ref, wv_ref))
+    side = jnp.stack([jnp.concatenate([side_ref[:, h:h + 1], side_ref[:, hb + h:hb + h + 1]],
+                                      axis=1) for h in range(hb)])
+    y, st = head_chunk(heads_of(xq_ref), heads_of(xk_ref), heads_of(xv_ref), before, taps,
+                       heads_of(pre_ref), heads_of(rate_ref), side, norm_ref[...], st_ref[...],
+                       bound, eps)
+    for h in range(hb):
+        y_ref[:, h * d:(h + 1) * d] = y[h].astype(y_ref.dtype)
+    st_ref[...] = st
+
+
+@functools.partial(jax.jit, static_argnames=("heads", "bound", "eps", "interpret"))
+def _kda_pallas(xq, xk, xv, taps, pre, rate, beta, gate, norm_w, heads: int, bound: float,
+                eps: float, interpret: bool = False):
+    import jax.experimental.pallas as pl  # deferred so CPU-only processes never pay it
+    from jax.experimental.pallas import tpu as pltpu
+
+    t, d = xq.shape[0], xq.shape[1] // heads
+    if t % CHUNK:
+        raise ValueError(f"the chunked delta rule takes whole chunks of {CHUNK} positions; "
+                         f"got T={t}")
+    hb = _heads_a_step(heads)
+    # the framework enables x64 globally; Mosaic only legalizes i32 scalars
+    with jax.enable_x64(False):
+        if diagnostics._enabled:  # trace time only: a trace of the path that took the kernel
+            diagnostics.counter("kernels.kda.fwd")
+        # beta and the gate of a step's heads side by side: (head groups, T, 2 hb)
+        side = jnp.stack([beta, gate], axis=1).astype(_F32).reshape(t, 2, heads // hb, hb)
+        side = jnp.moveaxis(side, 2, 0).reshape(heads // hb, t, 2 * hb)
+        rows = CHUNK // BEFORE
+        chunk = pl.BlockSpec((CHUNK, hb * d), lambda i, j: (j, i))
+        before = pl.BlockSpec((BEFORE, hb * d), lambda i, j: (jnp.maximum(j * rows - 1, 0), i))
+        width = taps[0].shape[0]
+        tap = pl.BlockSpec((width, hb * d), lambda i, j: (0, i))
+        return pl.pallas_call(
+            functools.partial(_kernel, hb=hb, d=d, bound=bound, eps=eps),
+            grid=(heads // hb, t // CHUNK),
+            in_specs=[chunk, chunk, chunk, before, before, before, tap, tap, tap, chunk,
+                      pl.BlockSpec((1, hb * d), lambda i, j: (0, i)),
+                      pl.BlockSpec((None, CHUNK, 2 * hb), lambda i, j: (i, j, 0)),
+                      pl.BlockSpec((1, d), lambda i, j: (0, 0))],
+            out_specs=chunk,
+            out_shape=jax.ShapeDtypeStruct((t, heads * d), xq.dtype),
+            scratch_shapes=[pltpu.VMEM((hb, d, d), _F32)],
+            interpret=interpret,
+            # a head group's chunks follow one another: the state is carried in VMEM
+            compiler_params=None if interpret else pltpu.CompilerParams(
+                dimension_semantics=("parallel", "arbitrary")),
+            name="kda_chunk_fwd",
+        )(xq, xk, xv, xq, xk, xv, *_taps32(taps), pre.astype(_F32),
+          rate.astype(_F32).reshape(1, heads * d), side, norm_w.astype(_F32).reshape(1, d))
+
+
+def kda_mix(xq, xk, xv, taps, pre, rate, beta, gate, norm_w, heads: int, bound: float,
+            eps: float, interpret: bool = False):
+    """Kimi Delta Attention between its projections, over the whole sequence from a zero
+    state: ``xq, xk, xv`` (T, heads * d) the three projections as stored, ``taps`` their
+    three convolutions (width, heads * d), ``pre`` (T, heads * d) float32 and ``rate``
+    (heads * d,) the decay's pre-activation and its rate (the log-decay is ``bound *
+    sigmoid(rate * pre)``, ``LOG_DECAY_BOUND <= bound < 0``), ``beta`` and ``gate`` (T,
+    heads) float32 (both after their sigmoid), ``norm_w`` (d,) the head norm's weight.
+    Returns the gated, normed heads (T, heads * d) in ``xq``'s type, ready for the output
+    projection. ``T`` is a whole number of chunks (``ValueError`` otherwise). Callers ask
+    :func:`decline_reason` first. No gradient is defined on this entry."""
+    return _kda_pallas(xq, xk, xv, tuple(taps), pre, rate, beta, gate, norm_w, heads=heads,
+                       bound=float(bound), eps=eps, interpret=interpret)

@@ -11,4 +11,7 @@ from .moe import *
 from .scoring import *
 from .xing4 import *
 from .trinity import *
-from . import attention, data_parallel, functional, hyper_connections, modules, moe, recurrent, scoring, trinity, xing4
+from .kda import *
+from .ling import *
+from . import (attention, data_parallel, functional, hyper_connections, kda, ling, modules, moe,
+               recurrent, scoring, trinity, xing4)

@@ -759,6 +759,11 @@ class MultiheadLatentAttention(Module):
     the heads' outputs (``v_head_dim`` wide, not the query's width) are concatenated into
     ``W_o``. No bias anywhere. Input ``(..., T, dim)``, positions ``0..T-1`` on axis -2.
 
+    Two variants that published models of this kind take. ``q_lora_rank=None``: no query
+    latent, ``[q_nope; q_rope] = x W_q`` per head directly (no ``W_qa``, no query norm).
+    ``head_gate=True``: every head's output is multiplied by ``sigmoid(x W_g)``, one
+    scalar a head (float32), before ``W_o``.
+
     This is the whole-sequence forward (scoring, prefill): no key/value cache and no
     absorbed products. On TPU the core runs in the flash Pallas kernel at
     ``d_qk != d_v``, named ``mla_flash_fwd`` in device traces; where it does not apply
@@ -767,10 +772,11 @@ class MultiheadLatentAttention(Module):
     (norm weights float32); contractions accumulate in float32.
     """
 
-    def __init__(self, dim: int, num_heads: int, q_lora_rank: int, kv_lora_rank: int,
+    def __init__(self, dim: int, num_heads: int, q_lora_rank: Optional[int], kv_lora_rank: int,
                  qk_nope_head_dim: int, qk_rope_head_dim: int, v_head_dim: int,
                  rope_theta: float = 10000.0, rope_scaling: Optional[dict] = None,
-                 eps: float = 1e-6, dtype=jnp.float32, norm_init_std: float = 0.0):
+                 eps: float = 1e-6, dtype=jnp.float32, norm_init_std: float = 0.0,
+                 head_gate: bool = False):
         self.dim = dim
         self.num_heads = num_heads
         self.q_lora_rank = q_lora_rank
@@ -783,17 +789,25 @@ class MultiheadLatentAttention(Module):
         self.scale = (qk_nope_head_dim + qk_rope_head_dim) ** -0.5 \
             * _yarn_mscale(rope_scaling, "mscale_all_dim") ** 2
         self.dtype = jnp.dtype(dtype)
-        self.q_norm = RMSNorm(q_lora_rank, eps, norm_init_std)
+        self.head_gate = head_gate
+        self.q_norm = None if q_lora_rank is None else RMSNorm(q_lora_rank, eps, norm_init_std)
         self.kv_norm = RMSNorm(kv_lora_rank, eps, norm_init_std)
 
     def init(self, key):
         kqa, kqb, kva, kvb, ko, kqn, kkn = jax.random.split(key, 7)
         h, dt = self.num_heads, self.dtype
-        return {
-            "wq_a": normal_weight(kqa, (self.dim, self.q_lora_rank), dt, self.dim ** -0.5),
-            "q_norm": self.q_norm.init(kqn),
-            "wq_b": normal_weight(kqb, (self.q_lora_rank, h * (self.nope + self.rope)), dt,
-                                  self.q_lora_rank ** -0.5),
+        if self.q_norm is None:
+            query = {"wq": normal_weight(kqb, (self.dim, h * (self.nope + self.rope)), dt,
+                                         self.dim ** -0.5)}
+        else:
+            query = {
+                "wq_a": normal_weight(kqa, (self.dim, self.q_lora_rank), dt, self.dim ** -0.5),
+                "q_norm": self.q_norm.init(kqn),
+                "wq_b": normal_weight(kqb, (self.q_lora_rank, h * (self.nope + self.rope)), dt,
+                                      self.q_lora_rank ** -0.5),
+            }
+        params = {
+            **query,
             "wkv_a": normal_weight(kva, (self.dim, self.kv_lora_rank + self.rope), dt,
                                    self.dim ** -0.5),
             "kv_norm": self.kv_norm.init(kkn),
@@ -801,6 +815,10 @@ class MultiheadLatentAttention(Module):
                                    self.kv_lora_rank ** -0.5),
             "wo": normal_weight(ko, (h * self.v_dim, self.dim), dt, (h * self.v_dim) ** -0.5),
         }
+        if self.head_gate:
+            params["wg"] = normal_weight(jax.random.fold_in(key, 7), (self.dim, h), dt,
+                                         self.dim ** -0.5)
+        return params
 
     def _core(self, q, k, v):
         """Causal softmax(q k^T scale) v on (..., H, T, .) operands."""
@@ -822,11 +840,15 @@ class MultiheadLatentAttention(Module):
         h, dn, dr, dv = self.num_heads, self.nope, self.rope, self.v_dim
         dt = x.dtype
         with jax.named_scope("ht.nn.mla"):
-            c_q = self.q_norm.apply(
-                params["q_norm"], contract("...td,dr->...tr", x, params["wq_a"]).astype(dt))
+            if self.q_norm is None:
+                c_q, wq = x, params["wq"]
+            else:
+                c_q = self.q_norm.apply(
+                    params["q_norm"], contract("...td,dr->...tr", x, params["wq_a"]).astype(dt))
+                wq = params["wq_b"]
             # the rope columns of both projections, published as interleaved pairs, are
             # taken even ones first: rotate_halves then turns the published pairs
-            wq_b = even_then_odd(params["wq_b"].reshape(self.q_lora_rank, h, dn + dr), dn)
+            wq_b = even_then_odd(wq.reshape(wq.shape[0], h, dn + dr), dn)
             q = contract("...tr,rhe->...hte", c_q, wq_b).astype(dt)
             q = jnp.concatenate(
                 [q[..., :dn], rotate_halves(q[..., dn:], self.inv_freq, self.rope_magnitude)],
@@ -841,6 +863,9 @@ class MultiheadLatentAttention(Module):
             k_rope = jnp.broadcast_to(k_rope[..., None, :, :], kv_h.shape[:-1] + (dr,))
             k = jnp.concatenate([kv_h[..., :dn], k_rope], axis=-1)
             o = self._core(q, k, kv_h[..., dn:])
+            if self.head_gate:
+                gate = jax.nn.sigmoid(contract("...td,dh->...ht", x, params["wg"]))
+                o = (o.astype(jnp.float32) * gate[..., None]).astype(dt)
             return contract("...htv,hvd->...td", o,
                             params["wo"].reshape(h, dv, self.dim)).astype(dt)
 

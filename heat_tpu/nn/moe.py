@@ -6,8 +6,16 @@ For every token ``u`` (d)::
     s      = sigmoid(u W_g)                 float32, over all E experts
     chosen = top_k(s + b)                   b: selection bias (auxiliary-loss-free
                                             balancing: it moves the choice, not the weight)
+                                            with ``n_group`` > 1: among the experts of the
+                                            ``topk_group`` best groups only (see below)
     w      = s[chosen] / sum(s[chosen]) * scaling
     y      = sum_e w_e W_down,e (silu(W_gate,e u) * W_up,e u)  +  shared(u)
+
+**The group limit.** With ``n_group`` > 1 the experts are ``n_group`` groups of
+neighbours, a group's score is the sum of its two largest ``s + b``, the ``topk_group``
+best groups stay and the top k are taken among their experts alone: a token's experts then
+lie on at most ``topk_group`` of the ``n_group`` holders. ``n_group = 1`` is no limit and
+traces none of this.
 
 **Which experts live here.** ``experts_held = (first, count)`` states what the one-integer
 ``split`` of a DNDarray cannot: this instance holds the weights of experts
@@ -56,7 +64,8 @@ class MoE(Module):
     """Routed gated-SiLU experts plus ``n_shared`` shared experts (one gated MLP of
     ``n_shared * hidden``), on tokens ``(T, dim)``. ``apply`` returns
     ``(y, {"chosen": (T, k) int32, "load": (count,) int32})``: the router's choices over
-    all experts and the rows each held expert multiplied.
+    all experts and the rows each held expert multiplied. ``n_group`` and ``topk_group``
+    limit the groups a token may choose from (none by default).
 
     Expert weights are stored stacked, ``(count, dim, hidden)`` and ``(count, hidden,
     dim)``, in ``dtype``; the router's weight and its selection bias are float32.
@@ -64,10 +73,17 @@ class MoE(Module):
 
     def __init__(self, dim: int, hidden: int, n_experts: int, top_k: int, n_shared: int = 1,
                  scaling: float = 1.0, experts_held: Optional[Tuple[int, int]] = None,
-                 block_rows: int = 512, dtype=jnp.float32):
+                 block_rows: int = 512, dtype=jnp.float32, n_group: int = 1,
+                 topk_group: int = 1):
         first, count = experts_held if experts_held is not None else (0, n_experts)
         if first < 0 or count < 1 or first + count > n_experts:
             raise ValueError(f"experts_held {experts_held} lies outside 0..{n_experts}")
+        if n_group > 1 and (n_experts % n_group or not 1 <= topk_group <= n_group
+                            or topk_group * (n_experts // n_group) < top_k
+                            or n_experts // n_group < 2):
+            raise ValueError(f"{n_experts} experts in {n_group} groups of which {topk_group} "
+                             f"stay do not hold a token's {top_k} (a group has at least 2)")
+        self.n_group, self.topk_group = n_group, topk_group
         self.dim, self.hidden = dim, hidden
         self.n_experts, self.top_k = n_experts, top_k
         self.scaling = scaling
@@ -97,7 +113,15 @@ class MoE(Module):
     def route(self, params, u):
         """``(chosen (T, k) int32, weights (T, k) float32)`` over all experts."""
         scores = jax.nn.sigmoid(contract("td,de->te", u, params["router"]))
-        _, chosen = lax.top_k(scores + params["router_bias"], self.top_k)
+        choice = scores + params["router_bias"]
+        if self.n_group > 1:
+            by_group = choice.reshape(choice.shape[0], self.n_group, -1)
+            group_score = jnp.sum(lax.top_k(by_group, 2)[0], axis=-1)
+            _, kept = lax.top_k(group_score, self.topk_group)
+            stays = jnp.any(kept[:, :, None] == jnp.arange(self.n_group, dtype=kept.dtype),
+                            axis=1)
+            choice = jnp.where(stays[:, :, None], by_group, -jnp.inf).reshape(choice.shape)
+        _, chosen = lax.top_k(choice, self.top_k)
         w = jnp.take_along_axis(scores, chosen, axis=1)
         w = w / jnp.sum(w, axis=1, keepdims=True) * jnp.float32(self.scaling)
         return chosen.astype(jnp.int32), w

@@ -423,3 +423,47 @@ def test_the_grouped_kernel_states_its_precision(one_chip, dtype, precision):
         compiled = jax.jit(functools.partial(grouped_matmul.grouped_gated_silu, block_rows=512)
                            ).lower(*args).compile()
     assert "moe_grouped_fwd" in compiled.as_text()
+
+
+def test_mosaic_compiles_the_delta_rule_at_the_ling_cell_shape(one_chip):
+    """The chunked KDA forward at ``ling-score-32k``'s shape, 32 heads of 128 over 32,768
+    positions in bfloat16 with the log-decay in float32: the gate takes it and Mosaic compiles
+    it under its name (the convolution's shifted rows, transposed products and the float32
+    inverse as bfloat16 pieces included); beside operands and output it holds only beta and
+    the gate laid out by head group."""
+    from heat_tpu.core.kernels import delta_rule
+
+    def shaped(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    x, g = shaped((32768, 4096), jnp.bfloat16), shaped((32768, 4096), jnp.float32)
+    w, side = shaped((4, 4096), jnp.bfloat16), shaped((32768, 32), jnp.float32)
+    assert delta_rule.decline_reason(x, w, 32) is None
+    compiled = jax.jit(functools.partial(delta_rule.kda_mix, heads=32, bound=-5.0, eps=1e-6)).lower(
+        x, x, x, (w, w, w), g, shaped((4096,), jnp.float32), side, side,
+        shaped((128,), jnp.float32)).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text and "kda_chunk_fwd" in text
+    assert compiled.memory_analysis().temp_size_in_bytes < 2**28
+
+
+def test_mosaic_compiles_the_expert_layer_at_the_ling_cell_shape(one_chip, monkeypatch):
+    """One expert layer of ``ling-score-32k``: 128 of 512 experts of 2560 x 768 held, top-8 in
+    8 groups of which 4 stay, blocks of 128 rows: the grouped kernel is in the program, and
+    the sorted buffer is sized for the worst case of all pairs held here."""
+    from heat_tpu.nn.ling import BLOCK_ROWS
+
+    monkeypatch.setattr(grouped_matmul, "available", lambda interpret=False: True)
+    m = ht.nn.MoE(2560, 768, 512, 8, 1, 2.5, (0, 128), BLOCK_ROWS, jnp.bfloat16, 8, 4)
+
+    def placed(a):
+        return jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip)
+
+    params = jax.tree_util.tree_map(placed, jax.eval_shape(m.init, jax.random.key(0)))
+    assert params["experts"]["w_gate"].shape == (128, 2560, 768)
+    assert params["router"].shape == (2560, 512)
+    x = jax.ShapeDtypeStruct((32768, 2560), jnp.bfloat16, sharding=one_chip)
+    text = jax.jit(lambda p, x: m.apply(p, x)).lower(params, x).compile().as_text()
+    assert "tpu_custom_call" in text and "moe_grouped_fwd" in text
+    rows = 32768 * 8 + 128 * BLOCK_ROWS
+    assert f"bf16[{rows},2560]" in text

@@ -321,7 +321,10 @@ class TestSwapHammer(TestCase):
                 s = i % len(batches)
                 i += 1
                 try:
-                    y = batches[s] * pool.state["w"] + pool.state["w"]
+                    # a request binds the state ONCE: the swap is one atomic reference
+                    # swap, so two reads of ``pool.state`` may straddle it (2 * 1 + 3)
+                    w = pool.state["w"]
+                    y = batches[s] * w + w
                     v = float(np.asarray(y.parray)[0])
                 except (resilience.Shed, resilience.DeadlineExceeded,
                         resilience.RequestCancelled,
