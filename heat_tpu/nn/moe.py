@@ -26,10 +26,16 @@ out: summing the parts over all holders, with the shared expert counted once, gi
 uncut layer. On one chip the layer runs without its exchange; nothing stands in for the
 absent chips.
 
-**No token is dropped.** The (token, expert) pairs are sorted by expert and every
-expert's group is padded to whole blocks of ``block_rows`` rows, so the buffer holds any
-imbalance (``T k + count * block_rows`` rows) and a block belongs to exactly one expert.
-Only the blocks in use are multiplied: the cost follows the tokens, not a capacity.
+**No token is dropped.** The (token, expert) pairs are laid out by expert, then by token,
+and every expert's group is padded to whole blocks of ``block_rows`` rows, so the buffer
+holds any imbalance (``T k + count * block_rows`` rows) and a block belongs to exactly one
+expert. Only the blocks in use are multiplied: the cost follows the tokens, not a capacity.
+The order is found by counting, not by sorting: a token's experts are distinct, so a pair's
+place in its expert's group is the number of earlier tokens that chose the expert, a
+running count along a 0/1 matrix (held experts, tokens); the groups' first rows follow from
+its row sums, and a pair reads its row, as the router reads a chosen score, by comparison
+against the experts' indices. XLA's sort, and its gathers and scatters of single elements,
+move one element at a time on a TPU; the one scatter left writes ``source``.
 
 **The products.** On a TPU the held experts' gated products are one grouped Pallas call
 over the sorted buffer (``core/kernels/grouped_matmul.py``, ``moe_grouped_fwd`` in a device
@@ -58,6 +64,37 @@ from ..core.kernels import grouped_matmul
 from .modules import GatedMLP, Module, contract, gated_silu, normal_weight
 
 __all__ = ["MoE"]
+
+
+def _read(table, index, axis):
+    """``table`` read along ``axis``, its experts' axis, at ``index``: ``table[t, index[t, j]]``
+    of a ``table`` (T, n) at ``index`` (T, k) for ``axis`` 1, ``table[index[j, t], t]`` of (n, T)
+    at (k, T) for ``axis`` 0. A compare-select-reduce against the experts' indices and not a
+    gather, which on a TPU moves one element at a time; with the tokens last (``axis`` 0) the
+    reduction is element-wise adds and costs a tenth of one across the lanes. ``table`` has 32
+    bits an element and the sum runs over them as integers, so it is the selected element
+    exactly in whatever order it is taken (a float sum could be merged with a caller's own and
+    reordered); an index outside ``0 .. n - 1`` reads 0."""
+    shape = index.shape[:axis + 1] + table.shape[axis:axis + 1] + index.shape[axis + 1:]
+    hit = jnp.expand_dims(index, axis + 1) == lax.broadcasted_iota(jnp.int32, shape, axis + 1)
+    bits = jnp.expand_dims(lax.bitcast_convert_type(table, jnp.int32), axis)
+    picked = jnp.sum(jnp.where(hit, bits, 0), axis=axis + 1, dtype=jnp.int32)
+    return lax.bitcast_convert_type(picked, table.dtype)
+
+
+def _count_before(held):
+    """The exclusive running count along the rows of a 0/1 matrix (n, T) int32, on the MXU:
+    inside chunks of 512 columns a product with a 0/1 triangle (bfloat16 operands, float32
+    sums: exact), plus the running count of the chunks' totals."""
+    n, t = held.shape
+    chunk = 512
+    h = jnp.pad(held, ((0, 0), (0, -t % chunk))).reshape(n, -1, chunk)
+    earlier = jnp.arange(chunk)[:, None] < jnp.arange(chunk)[None, :]
+    within = jnp.einsum("nci,ij->ncj", h.astype(jnp.bfloat16), earlier.astype(jnp.bfloat16),
+                        preferred_element_type=jnp.float32, precision=lax.Precision.DEFAULT)
+    total = jnp.sum(h, axis=2, dtype=jnp.int32)
+    ahead = jnp.cumsum(total, axis=1, dtype=jnp.int32) - total
+    return (within.astype(jnp.int32) + ahead[:, :, None]).reshape(n, -1)[:, :t]
 
 
 class MoE(Module):
@@ -121,13 +158,15 @@ class MoE(Module):
             stays = jnp.any(kept[:, :, None] == jnp.arange(self.n_group, dtype=kept.dtype),
                             axis=1)
             choice = jnp.where(stays[:, :, None], by_group, -jnp.inf).reshape(choice.shape)
-        _, chosen = lax.top_k(choice, self.top_k)
-        w = jnp.take_along_axis(scores, chosen, axis=1)
+        chosen = lax.top_k(choice, self.top_k)[1].astype(jnp.int32)
+        # read with k last, as a gather would leave the scores: a float sum takes its order
+        # from its operand's shape, and the sum below is held to the gathered form's bits
+        w = _read(scores, chosen, 1)
         w = w / jnp.sum(w, axis=1, keepdims=True) * jnp.float32(self.scaling)
-        return chosen.astype(jnp.int32), w
+        return chosen, w
 
     def _layout(self, chosen):
-        """Where each (token, expert) pair goes in the padded, expert-sorted buffer.
+        """Where each (token, expert) pair goes in the padded, expert-ordered buffer.
         Returns ``slot`` (T k,) int32 (pairs of experts held elsewhere: the buffer's
         length, which reads as nothing and writes nowhere), the token behind each buffer
         row ``source`` (rows,) (padding: token 0, whose product no slot reads), each held
@@ -135,22 +174,21 @@ class MoE(Module):
         t, k = chosen.shape
         b, e = self.block_rows, self.count
         rows = -(-t * k // b) * b + e * b
-        local = chosen.reshape(-1) - jnp.int32(self.first)
-        local = jnp.where((local >= 0) & (local < e), local, jnp.int32(e))
-        order = jnp.argsort(local, stable=True).astype(jnp.int32)
-        sorted_e = local[order]
-        edges = jnp.searchsorted(sorted_e, jnp.arange(e + 2, dtype=jnp.int32),
-                                 side="left").astype(jnp.int32)
-        load = edges[1:] - edges[:-1]  # the last entry counts the pairs held elsewhere
-        blocks = (load[:e] + (b - 1)) // b
-        first_row = jnp.concatenate([jnp.zeros((1,), jnp.int32),
-                                     jnp.cumsum(blocks * b, dtype=jnp.int32)])
-        first_row = first_row.at[e].set(rows)  # pairs held elsewhere land out of range
-        rank = jnp.arange(t * k, dtype=jnp.int32) - edges[sorted_e]
-        slot_sorted = jnp.where(sorted_e < e, first_row[sorted_e] + rank, jnp.int32(rows))
-        slot = jnp.zeros((t * k,), jnp.int32).at[order].set(slot_sorted)
-        source = jnp.zeros((rows,), jnp.int32).at[slot_sorted].set(order // k, mode="drop")
-        return slot, source, first_row[:e], blocks, load[:e]
+        local = chosen.T - jnp.int32(self.first)  # (k, T); outside 0..e-1: held elsewhere
+        held_by = jnp.sum(local[:, None, :] == jnp.arange(e, dtype=jnp.int32)[:, None], axis=0,
+                          dtype=jnp.int32)
+        load = jnp.sum(held_by, axis=1, dtype=jnp.int32)
+        blocks = (load + (b - 1)) // b
+        first_row = jnp.cumsum(blocks * b, dtype=jnp.int32) - blocks * b
+        # a token's experts are distinct, so a pair's rank in its expert's group is the
+        # number of earlier tokens that chose the expert
+        slot = _read(first_row[:, None] + _count_before(held_by), local, 0)
+        slot = jnp.where((local >= 0) & (local < e), slot, jnp.int32(rows))
+        token = jnp.broadcast_to(jnp.arange(t, dtype=jnp.int32), (k, t))
+        source = jnp.zeros((rows,), jnp.int32).at[slot.reshape(-1)].set(token.reshape(-1),
+                                                                       mode="drop")
+        slot = slot.T.reshape(-1)
+        return slot, source, first_row, blocks, load
 
     def _experts(self, experts, x, source, first_row, blocks):
         """The sorted buffer ``x[source]`` (rows, dim): every held expert's blocks through

@@ -378,8 +378,9 @@ def test_mosaic_compiles_the_expert_layer_at_the_cell_shape(one_chip, monkeypatc
     """One expert layer of each model cell, 32,768 tokens in bfloat16 through ``MoE.apply``, as
     a TPU would run it: the grouped kernel is in the program under its name, with whole experts
     resident (the raised VMEM limit is one Mosaic accepts), the rows fetched through the
-    sorted index, and the row chunk the rule reads off the widths; the loop over the experts is gone (no ``while`` but the layout's binary search),
-    and with it every ``dynamic-update-slice`` into the sorted buffer."""
+    sorted index, and the row chunk the rule reads off the widths; the loop over the experts is
+    gone (no ``while`` at all, since the layout counts and no longer sorts and searches), and
+    with it every ``dynamic-update-slice`` into the sorted buffer."""
     monkeypatch.setattr(grouped_matmul, "available", lambda interpret=False: True)
     m = ht.nn.MoE(d, 1024, experts, top_k, 1, 2.5, None, 512, dtype=jnp.bfloat16)
     assert grouped_matmul._row_chunk(512, d, 1024, 2) == chunk
@@ -401,9 +402,9 @@ def test_mosaic_compiles_the_expert_layer_at_the_cell_shape(one_chip, monkeypatc
     assert len(written) == 1 and "moe_grouped_fwd" in written[0]
     assert not [line for line in text.splitlines()
                 if "dynamic-update-slice" in line and buffer in line]
-    # the one loop left is the layout's binary search for the experts' edges
-    loops = [line for line in text.splitlines() if " while(" in line]
-    assert len(loops) == 1 and "searchsorted" in loops[0]
+    # no loop is left, and no sort but the router's ``top_k``: the layout counts (PR 34)
+    assert not [line for line in text.splitlines() if " while(" in line]
+    assert all("/top_k" in line for line in text.splitlines() if " sort(" in line)
 
 
 @pytest.mark.parametrize("dtype,precision", [(jnp.bfloat16, "highest"), (jnp.float32, "default")],

@@ -334,7 +334,10 @@ def test_xing4_is_unchanged_by_what_trinity_shares_with_it(what):
     text of the ``Xing4`` program at ``test_xing4.py``'s size was, byte for byte, what the
     commit before those changes (PR 30) lowered; PR 32 replaced the digest on purpose
     (``nn/moe.py``: padding rows read token 0 and no longer a zero fill, a pair held elsewhere
-    is selected to 0, the weighted sum takes its pairs rank-major). ``kernel``: the jaxpr of the causal
+    is selected to 0, the weighted sum takes its pairs rank-major), and PR 34 again (``nn/moe.py``'s
+    index work, ``route``'s weight read and ``_layout``, by counting and comparison in place of
+    a sort and element gathers and scatters: the same values, and nothing else changed).
+    ``kernel``: the jaxpr of the causal
     forward at ``xing4-score-32k``'s shape, the Pallas body included, is still PR 30's. A PR
     that changes either on purpose replaces the digest here."""
     if what == "program":
@@ -343,7 +346,7 @@ def test_xing4_is_unchanged_by_what_trinity_shares_with_it(what):
         model = ht.nn.Xing4(XING4, continuation=X_CONT, dtype=jnp.bfloat16, block_rows=16)
         params = jax.eval_shape(model.init, jax.random.key(0))
         text = model._program.lower(params, jax.ShapeDtypeStruct((X_T,), jnp.int32)).as_text()
-        want = "bef63103e2b58b18a1ca62984a21a4b7fa01252ef046c53bc01c6a698f2dd232"
+        want = "4ece729834c56bd4d5e658e702844eb50cc61911ce3adb1b798b9e7ff90a57c1"
         assert ht.nn.Xing4.traces == "nn.xing4.traces"
         assert ht.nn.Xing4.logliks == ("loglik", "mtp_loglik")
     else:
