@@ -10,6 +10,10 @@ hand-written collective choreography. Usage mirrors the reference::
     x.sum()
 """
 
+import time as _time
+
+_FIRST = _time.perf_counter()  # the start-up record's ``import_s`` counts from here
+
 # The reference computes every matmul in full fp32/fp64 (torch on CPU/GPU). TPU MXUs
 # default to bf16-input passes — fast, and the right default for the framework's bulk
 # compute path. fp32-sensitive algorithms (QR, hSVD, CG/Lanczos, cdist's quadratic
@@ -19,24 +23,28 @@ hand-written collective choreography. Usage mirrors the reference::
 from .core import *
 from .core import __version__
 from .core import diagnostics
-from .core import forensics
-from .core import ops
-from .core import profiler
-from .core import resilience
-from .core import supervision
-from . import telemetry
-from . import core
-from . import fft
-from . import utils
-from . import spatial
-from . import cluster
-from . import classification
-from . import naive_bayes
-from . import regression
-from . import preprocessing
-from . import graph
-from . import datasets
-from . import sparse
-from . import nn
-from . import optim
-from . import serving
+
+with diagnostics.startup("import.packages"):
+    from .core import forensics
+    from .core import ops
+    from .core import profiler
+    from .core import resilience
+    from .core import supervision
+    from . import telemetry
+    from . import core
+    from . import fft
+    from . import utils
+    from . import spatial
+    from . import cluster
+    from . import classification
+    from . import naive_bayes
+    from . import regression
+    from . import preprocessing
+    from . import graph
+    from . import datasets
+    from . import sparse
+    from . import nn
+    from . import optim
+    from . import serving
+
+diagnostics.startup_imported(_FIRST)

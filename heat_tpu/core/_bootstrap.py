@@ -4,8 +4,8 @@ it imports anything else.
 
 A process can join a ``jax.distributed`` job only while it has no XLA backend: one
 that creates it (``jax.devices()``, ``jax.default_backend()``, ...) before step (c)
-comes up alone, whatever the launcher said. Hence only ``jax``, ``os`` and the
-stdlib-only ``supervision`` are imported here at load; analysis rule
+comes up alone, whatever the launcher said. Hence only ``jax``, ``os``, ``sys`` and the
+stdlib-only ``diagnostics`` and ``supervision`` are imported here at load; analysis rule
 ``import-backend-touch`` and its runtime twin in ``tests/test_analysis.py`` hold
 the line. The launch contract is the environment, as ``mpirun``'s is::
 
@@ -14,16 +14,22 @@ the line. The launch contract is the environment, as ``mpirun``'s is::
 
 or ``jax.distributed.initialize`` called by the program before its first
 ``import heat_tpu``: a client that exists is respected.
+
+Each step is a phase of the start-up record (``diagnostics.startup``,
+``ht.diagnostics.report()["startup"]``): what a process pays before its first line
+of work, by name.
 """
 
 from __future__ import annotations
 
 import os
+import sys
 
-import jax
-from jax._src import xla_bridge as _xla_bridge
+from . import diagnostics, supervision
 
-from . import supervision
+with diagnostics.startup("import.jax", jax_preimported="jax" in sys.modules):
+    import jax
+    from jax._src import xla_bridge as _xla_bridge
 
 #: ``<checkout>/.jax_cache`` (gitignored). The directory is part of JAX's cache key,
 #: so it is never a temp name, a pid or a time.
@@ -37,11 +43,28 @@ _CONTRACT = ("HEAT_TPU_COORDINATOR_ADDRESS", "HEAT_TPU_NUM_PROCESSES", "HEAT_TPU
 
 def run() -> None:
     """The bring-up, steps (a) to (e)."""
-    # (a) float64/complex128/int64 availability (the reference supports f64 via
-    # torch); the *default* float stays float32: factories pass explicit dtypes.
-    jax.config.update("jax_enable_x64", True)
-    place_jax_cache()  # (b)
-    # (c) the environment contract: all three or none
+    with diagnostics.startup("bootstrap"):
+        with diagnostics.startup("bootstrap.config"):
+            # (a) float64/complex128/int64 availability (the reference supports f64 via
+            # torch); the *default* float stays float32: factories pass explicit dtypes.
+            jax.config.update("jax_enable_x64", True)
+            place_jax_cache()  # (b)
+        with diagnostics.startup("bootstrap.join"):
+            _join_from_environment()  # (c)
+        # (d) + (e) only now may the backend exist (importing ``devices`` creates it):
+        # the world singletons, the telemetry stamp / clock handshake, ``auto_arm()``
+        with diagnostics.startup("bootstrap.world"):
+            # ``backend_created``: the program had made it before this import (~0 then)
+            with diagnostics.startup("bootstrap.world.backend",
+                                     backend_created=_xla_bridge.backends_are_initialized()):
+                jax.devices()
+            from . import communication
+
+            communication.build_world()
+
+
+def _join_from_environment() -> None:
+    """Step (c), the environment contract: all three or none."""
     env = [os.environ.get(name) for name in _CONTRACT]
     if env[0]:
         missing = [name for name, value in zip(_CONTRACT, env) if not value]
@@ -54,11 +77,6 @@ def run() -> None:
         if supervision._distributed_client() is None:
             join(coordinator_address=env[0], num_processes=int(env[1]),
                  process_id=int(env[2]))
-    # (d) + (e) only now may the backend exist (importing ``devices`` creates it):
-    # the world singletons, the telemetry stamp / clock handshake, ``auto_arm()``
-    from . import communication
-
-    communication.build_world()
 
 
 def place_jax_cache() -> None:

@@ -772,7 +772,8 @@ def build_world() -> None:
     COMM_SELF = MeshCommunication(jax.devices()[:1])
     __default_comm = COMM_WORLD
     _pad_cache.clear()
-    _telemetry_bootstrap()
+    with diagnostics.startup("bootstrap.world.telemetry"):
+        _telemetry_bootstrap()
 
 
 def get_comm() -> MeshCommunication:

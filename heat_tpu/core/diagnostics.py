@@ -16,6 +16,12 @@ report into:
   also flat in ``report()["counters"]`` as ``span_n.`` / ``span_s.`` /
   ``span_self_s.<name>``), and backend compiles attributed to the innermost
   open span (``compile_n.`` / ``compile_s.<span>``).
+- **Compile accounting** — what ``jax.monitoring`` tells of tracing, lowering,
+  backend compiles and the persistent cache, kept while enabled: the flat
+  ``jit.*`` counters and ``report()["programs"]``, one entry a ``fun_name``
+  (:func:`_on_duration`, :func:`_on_event`, :func:`_on_stage_begins`).
+- **The start-up record** — ``report()["startup"]``: the phases of ``import
+  heat_tpu`` (:func:`startup`), written once a process and always on.
 - **Collective telemetry** — every ``MeshCommunication`` collective (``psum`` …
   ``scatter``, plus ``shard`` and ``_pad_reshard``) records (op name, mesh axis,
   participant count, logical bytes moved). Collectives called inside a traced
@@ -51,7 +57,9 @@ branch not taken, and nothing is ever injected into traced program bodies —
 compiled HLO is byte-identical to an uninstrumented build
 (``tests/test_diagnostics.py::TestZeroOverheadContract``). Backend-health
 events are the one always-on stream: they are only produced by explicit probe
-calls, never on a compute path.
+calls, never on a compute path. The start-up record is always on for the same
+reason: a dozen ``time.perf_counter()`` reads while the package is imported,
+none afterwards, and ``enable()`` can only be called after the import it measures.
 
 Env knobs (read once at import)
 -------------------------------
@@ -128,6 +136,7 @@ __all__ = [
     "dump",
     "span",
     "NO_SPAN",
+    "startup",
     "counter",
     "record_collective",
     "record_compile",
@@ -195,11 +204,32 @@ _annotation: Any = None
 _tracer: Any = ()  # jax.core.Tracer, bound with it
 _open = threading.local()
 
+# What ``jax.monitoring`` emits where JAX traces, lowers, compiles or reads its
+# persistent cache (jax 0.9: ``dispatch.py``, ``compiler.py``), and nowhere else: a
+# cached dispatch fires none of them. The first three carry ``fun_name=``.
 _COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
+_STAGES = {
+    "/jax/core/compile/jaxpr_trace_duration": "trace",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": "lower",
+    _COMPILE_EVENT: "backend",
+}
+_CACHE_SECONDS = {
+    "/jax/compilation_cache/cache_retrieval_time_sec": "jit.cache_read_s",
+    "/jax/compilation_cache/compile_time_saved_sec": "jit.cache_saved_s",
+}
+_CACHE_REQUEST = "/jax/compilation_cache/compile_requests_use_cache"
+_CACHE_HIT = "/jax/compilation_cache/cache_hits"
+_JIT_COUNTERS = ("jit.trace_s", "jit.lower_s", "jit.backend_s", "jit.backend_n",
+                 "jit.cache_hit_n", "jit.cache_miss_n", "jit.cache_read_s",
+                 "jit.cache_saved_s")
+# ``report()["programs"]``: one entry a ``fun_name``, the names past the bound
+# summed under ``other``.
+_MAX_PROGRAMS = 256
+_programs: Dict[str, Dict[str, float]] = {}
 
 
-def _utcnow() -> str:
-    return time.strftime("%Y-%m-%dT%H:%M:%SZ", time.gmtime())
+def _utcnow(at: Optional[float] = None) -> str:
+    return time.strftime("%Y-%m-%dT%H:%M:%SZ", time.gmtime(at))
 
 
 # ------------------------------------------------------------------ switches
@@ -238,11 +268,12 @@ def tracing() -> bool:
 
 
 def reset() -> None:
-    """Drop every collected datum (counters, spans, collectives, pad gauges,
-    compile/dispatch/backend events). The enabled/tracing switches and the
-    last-known backend state are kept."""
+    """Drop every collected datum (counters, spans, programs, collectives, pad
+    gauges, compile/dispatch/backend events). The enabled/tracing switches, the
+    last-known backend state and the start-up record are kept."""
     with _lock:
         _counters.clear()
+        _programs.clear()
         _spans.clear()
         _collectives.clear()
         _pad_gauges.clear()
@@ -261,6 +292,79 @@ def register_provider(name: str, fn: Callable[[], Any]) -> None:
         _providers[name] = fn
 
 
+# ------------------------------------------------------------------ start-up record
+def _process_start() -> Optional[float]:
+    """The ``time.perf_counter()`` reading that this process's start corresponds
+    to: its ``starttime`` (``/proc/self/stat``, ticks since boot) against
+    ``CLOCK_BOOTTIME``. ``None`` where there is no ``/proc``."""
+    try:
+        with open("/proc/self/stat", "rb") as f:
+            ticks = int(f.read().rsplit(b")", 1)[1].split()[19])
+        age = time.clock_gettime(time.CLOCK_BOOTTIME) - ticks / os.sysconf("SC_CLK_TCK")
+    except (OSError, ValueError, IndexError, AttributeError):
+        return None
+    return time.perf_counter() - age
+
+
+# ``start_s`` counts from process start, or from this module's load where that
+# is not known. Written while the package is imported, by the importing thread
+# alone; :func:`startup_imported` closes it.
+_T_START = _process_start()
+_T_ZERO = time.perf_counter() if _T_START is None else _T_START
+_startup: Dict[str, Any] = {
+    "perf_counter_at_start": _T_START,
+    "wall_start": _utcnow(time.time() - (time.perf_counter() - _T_ZERO)),
+}
+_startup_open = True
+
+
+class _Phase:
+    """One phase of the start-up record (see :func:`startup`)."""
+
+    __slots__ = ("name", "fields", "t0")
+
+    def __init__(self, name: str, fields: Dict[str, Any]):
+        self.name = name
+        self.fields = fields
+
+    def __enter__(self):
+        self.t0 = time.perf_counter()
+        return self
+
+    def __exit__(self, *exc):
+        seconds = time.perf_counter() - self.t0
+        _startup[self.name] = {"start_s": self.t0 - _T_ZERO, "seconds": seconds,
+                               **self.fields}
+        return False
+
+
+def startup(name: str, **fields: Any):
+    """One phase of ``import heat_tpu``: ``with diagnostics.startup("bootstrap.world"):``
+    writes ``report()["startup"][name]`` = ``start_s`` (seconds since process
+    start), ``seconds`` and ``fields``. Always on, whatever ``HEAT_TPU_METRICS``
+    says: two clock reads a phase. Once the package is imported the record is
+    closed and this is the shared no-op, so that a later ``build_world()`` (an
+    elastic restart) leaves the record of the process's start alone."""
+    return _Phase(name, fields) if _startup_open else NO_SPAN
+
+
+def startup_imported(first: float) -> None:
+    """The last statement of ``heat_tpu/__init__.py``, given the
+    ``time.perf_counter()`` reading of its first: writes ``before_import``
+    (process start to that first statement: the interpreter and whatever the
+    program imported and started before ``heat_tpu``; ``None`` where process
+    start is not known) and the totals ``import_s`` (first to last statement)
+    and ``bootstrap_s`` (``_bootstrap.run()`` whole), and closes the record."""
+    global _startup_open
+    if not _startup_open:
+        return
+    _startup["before_import"] = None if _T_START is None else {
+        "start_s": 0.0, "seconds": first - _T_START}
+    _startup["import_s"] = time.perf_counter() - first
+    _startup["bootstrap_s"] = _startup.pop("bootstrap", {}).get("seconds")
+    _startup_open = False
+
+
 # ------------------------------------------------------------------ primitives
 def counter(name: str, value: float = 1) -> None:
     """Add ``value`` to the named counter (no-op while disabled)."""
@@ -275,8 +379,9 @@ NO_SPAN = contextlib.nullcontext()  # what :func:`span` is while disabled, share
 
 def _bind_jax() -> None:
     """First enabled span: bind ``jax.profiler.TraceAnnotation`` and register
-    the one compile-duration listener (JAX has no public way to take a
-    listener off again, so it stays and reads ``_enabled`` itself)."""
+    the listeners, one of a kind: durations, events, and the scalar JAX records
+    where a compile stage begins (JAX has no public way to take a listener off
+    again, so they stay and read ``_enabled`` themselves)."""
     global _annotation, _tracer
     with _lock:
         if _annotation is None:
@@ -287,20 +392,94 @@ def _bind_jax() -> None:
                 _annotation = False
             else:
                 jax.monitoring.register_event_duration_secs_listener(_on_duration)
+                jax.monitoring.register_event_listener(_on_event)
+                jax.monitoring.register_scalar_listener(_on_stage_begins)
                 _tracer = jax.core.Tracer
                 _annotation = jax.profiler.TraceAnnotation
 
 
-def _on_duration(event: str, seconds: float, **_kw) -> None:
-    """Which span compiled: each backend compile of this thread lands on the
-    innermost span open on it (``none`` outside every span)."""
-    if not _enabled or event != _COMPILE_EVENT:
+def _program_locked(fun_name: str) -> Dict[str, float]:
+    # callers hold _lock; ``jit(f)`` / ``pmap(f)`` (lowering, backend) is ``f`` (trace)
+    if fun_name.endswith(")") and "(" in fun_name:
+        fun_name = fun_name[fun_name.index("(") + 1:-1]
+    entry = _programs.get(fun_name)
+    if entry is None:
+        if len(_programs) >= _MAX_PROGRAMS:
+            fun_name = "other"
+        entry = _programs.setdefault(fun_name, {
+            "trace_n": 0, "trace_s": 0.0, "lower_s": 0.0, "backend_n": 0,
+            "backend_s": 0.0, "cache_hit_n": 0, "cache_miss_n": 0})
+    return entry
+
+
+def _on_stage_begins(event: str, _value: float, **_kw) -> None:
+    """JAX records a stage's start time as a scalar under the stage's own event
+    name: from here to its duration event, what ends on this thread lies inside it.
+    Kept whatever the switch says, so that the stack of open stages stays true."""
+    if event in _STAGES:
+        inside = getattr(_open, "stages", None)
+        if inside is None:
+            inside = _open.stages = []
+        inside.append(0.0)
+
+
+def _on_duration(event: str, seconds: float, fun_name: str = "?", **_kw) -> None:
+    """What JAX spent where it traced, lowered, compiled or read its cache.
+
+    A program's entry keeps each stage as JAX reports it, what the stage
+    encloses included; the flat ``jit.trace_s`` / ``jit.lower_s`` /
+    ``jit.backend_s`` count every second once: a stage that lay inside another on
+    its thread (a jitted ``jnp`` function traced while its caller is) is taken off
+    the one that encloses it, so the three add up to the time the thread spent.
+    ``jit.backend_s`` includes ``jit.cache_read_s``, as JAX's event does. Each
+    backend compile also lands on the innermost span open on its thread (``none``
+    outside every span), and settles the cache requests that its thread made
+    inside it (:func:`_on_event`)."""
+    stage = _STAGES.get(event)
+    if stage is not None:
+        open_stages = getattr(_open, "stages", None)
+        inside = open_stages.pop() if open_stages else 0.0
+        if open_stages:
+            open_stages[-1] += seconds
+    if not _enabled:
         return
-    stack = getattr(_open, "stack", None)
-    name = stack[-1].name if stack else "none"
+    if stage is None:
+        flat = _CACHE_SECONDS.get(event)
+        if flat is not None:
+            with _lock:
+                _counters[flat] = _counters.get(flat, 0) + seconds
+        return
+    add = {f"jit.{stage}_s": max(0.0, seconds - inside)}
     with _lock:
-        for key, value in ((f"compile_n.{name}", 1), (f"compile_s.{name}", seconds)):
+        entry = _program_locked(fun_name)
+        entry[f"{stage}_s"] += seconds
+        if stage == "trace":
+            entry["trace_n"] += 1
+        elif stage == "backend":
+            requests, hits = getattr(_open, "cache", (0, 0))
+            _open.cache = (0, 0)
+            entry["backend_n"] += 1
+            entry["cache_hit_n"] += hits
+            entry["cache_miss_n"] += requests - hits
+            spans = getattr(_open, "stack", None)
+            name = spans[-1].name if spans else "none"
+            add.update({"jit.backend_n": 1, "jit.cache_hit_n": hits,
+                        "jit.cache_miss_n": requests - hits,
+                        f"compile_n.{name}": 1, f"compile_s.{name}": seconds})
+        for key, value in add.items():
             _counters[key] = _counters.get(key, 0) + value
+
+
+def _on_event(event: str, **_kw) -> None:
+    """A compile that asked the persistent cache, and one that was answered from
+    it, noted on their thread until the backend-compile event that encloses them
+    (:func:`_on_duration`). A miss is a request that was not a hit: JAX's own
+    ``/cache_misses`` fires only where it writes the entry, which its size and
+    time thresholds decide."""
+    if not _enabled or event not in (_CACHE_REQUEST, _CACHE_HIT):
+        return
+    requests, hits = getattr(_open, "cache", (0, 0))
+    _open.cache = (requests + 1, hits) if event == _CACHE_REQUEST else (requests, hits + 1)
 
 
 class _Span:
@@ -499,6 +678,9 @@ def record_backend_event(up: bool, detail: str = "") -> dict:
 def _flat_counters_locked() -> Dict[str, float]:
     # callers hold _lock; the field leads the name so that a prefix selects one field
     flat = dict(_counters)
+    if _enabled:  # a reader tells "none" from "not counted"
+        for name in _JIT_COUNTERS:
+            flat.setdefault(name, 0)
     for name, agg in _spans.items():
         flat[f"span_n.{name}"] = agg["count"]
         flat[f"span_s.{name}"] = agg["total_s"]
@@ -515,8 +697,11 @@ def report() -> dict:
             "generated_at": _utcnow(),
             "enabled": _enabled,
             "tracing": _tracing,
+            "startup": {k: dict(v) if isinstance(v, dict) else v
+                        for k, v in _startup.items()},
             "counters": _flat_counters_locked(),
             "spans": {k: dict(v) for k, v in _spans.items()},
+            "programs": {k: dict(v) for k, v in _programs.items()},
             "collectives": [
                 {
                     "op": op,

@@ -810,3 +810,233 @@ class TestHostSpans(_DiagTestCase):
         self.assertIsNotNone(rid)
         for name in nest[1:] + ["ht.spatial.cdist", "ht.statistics.argreduce"]:
             self.assertEqual(found[name][2].get("req"), rid, name)  # one request, one id
+
+
+def _run_python(code, **env):
+    """``code`` in a fresh interpreter on the CPU, metrics as the caller's ``env`` says."""
+    base = {k: v for k, v in os.environ.items()
+            if k not in ("HEAT_TPU_METRICS", "HEAT_TPU_DIAG_DUMP", "JAX_COMPILATION_CACHE_DIR")}
+    base.update(env)
+    return subprocess.run([sys.executable, "-c", code], env=base, capture_output=True,
+                          text=True, timeout=240)
+
+
+TOP_PHASES = ["import.jax", "bootstrap.config", "bootstrap.join", "bootstrap.world",
+              "import.core", "import.packages"]
+WORLD_PHASES = ["bootstrap.world.backend", "bootstrap.world.telemetry"]
+
+
+class TestStartupRecord(_DiagTestCase):
+    """``report()["startup"]``: the phases of ``import heat_tpu``, always on, written once."""
+
+    def test_the_record_is_there_with_metrics_off(self):
+        diagnostics.disable()
+        record = diagnostics.report()["startup"]
+        for name in TOP_PHASES + WORLD_PHASES + ["before_import"]:
+            self.assertGreaterEqual(record[name]["start_s"], 0.0, name)
+            self.assertGreaterEqual(record[name]["seconds"], 0.0, name)
+        self.assertIn("jax_preimported", record["import.jax"])
+        self.assertIn("backend_created", record["bootstrap.world.backend"])
+        self.assertGreater(record["import_s"], 0.0)
+        self.assertRegex(record["wall_start"], r"^\d{4}-\d\d-\d\dT\d\d:\d\d:\d\dZ$")
+        json.dumps(record)  # plain numbers, strings and booleans all through
+
+    def test_phases_nest_and_sum_to_no_more_than_the_import(self):
+        record = diagnostics.report()["startup"]
+
+        def ends(name):
+            return record[name]["start_s"] + record[name]["seconds"]
+
+        for earlier, later in zip(TOP_PHASES, TOP_PHASES[1:]):  # one after the other
+            self.assertLessEqual(ends(earlier), record[later]["start_s"] + 1e-9, later)
+        world = record["bootstrap.world"]
+        for name in WORLD_PHASES:
+            self.assertGreaterEqual(record[name]["start_s"], world["start_s"], name)
+            self.assertLessEqual(ends(name), ends("bootstrap.world") + 1e-9, name)
+        steps = sum(record[n]["seconds"] for n in TOP_PHASES[1:4])
+        self.assertLessEqual(steps, record["bootstrap_s"] + 1e-9)
+        parts = (record["import.jax"]["seconds"] + record["bootstrap_s"]
+                 + record["import.core"]["seconds"] + record["import.packages"]["seconds"])
+        self.assertLessEqual(parts, record["import_s"] + 1e-9)
+        self.assertGreater(parts, 0.5 * record["import_s"])  # the phases cover the import
+        # the record's clock: process start, the import and now lie in that order
+        import time
+        since_start = time.perf_counter() - record["perf_counter_at_start"]
+        self.assertLessEqual(record["before_import"]["seconds"] + record["import_s"], since_start)
+        self.assertLessEqual(ends("import.packages"),
+                             record["before_import"]["seconds"] + record["import_s"] + 1e-6)
+
+    def test_reset_keeps_it_and_nothing_writes_it_after_the_import(self):
+        before = diagnostics.report()["startup"]
+        diagnostics.reset()
+        self.assertIs(diagnostics.startup("late.phase", field=1), diagnostics.NO_SPAN)
+        with diagnostics.startup("late.phase"):
+            pass
+        ht.core.communication.build_world()  # an elastic restart's tail runs the phase again
+        diagnostics.startup_imported(0.0)
+        self.assertEqual(diagnostics.report()["startup"], before)
+
+    def test_a_program_that_imported_jax_and_made_the_backend_first(self):
+        code = (
+            "import jax, json\n"
+            "jax.devices()\n"
+            "import heat_tpu as ht\n"
+            "print(json.dumps(ht.diagnostics.report()['startup']))\n"
+        )
+        proc = _run_python(code)
+        self.assertEqual(proc.returncode, 0, proc.stderr[-2000:])
+        record = json.loads(proc.stdout.strip().splitlines()[-1])
+        self.assertIs(record["import.jax"]["jax_preimported"], True)
+        self.assertIs(record["bootstrap.world.backend"]["backend_created"], True)
+        self.assertLess(record["import.jax"]["seconds"], 0.25)
+        # `import jax` and the backend lie before the import, where the record says
+        self.assertGreater(record["before_import"]["seconds"], record["import.jax"]["seconds"])
+        own = diagnostics.report()["startup"]  # this process left both to the import
+        if not own["import.jax"]["jax_preimported"]:
+            self.assertGreater(own["import.jax"]["seconds"],
+                               record["import.jax"]["seconds"])
+
+    def test_metrics_unset_fills_the_record_and_registers_no_listener(self):
+        code = (
+            "import heat_tpu as ht, jax, jax.numpy as jnp\n"
+            "from jax._src import monitoring\n"
+            "d = ht.diagnostics\n"
+            "def ours():\n"
+            "    return [f for f in monitoring.get_event_listeners()\n"
+            "            + monitoring.get_event_duration_listeners()\n"
+            "            + monitoring.get_scalar_listeners()\n"
+            "            if getattr(f, '__module__', '') == d.__name__]\n"
+            "jax.jit(lambda t: t + 1)(jnp.ones(3)).block_until_ready()\n"
+            "rep = d.report()\n"
+            "assert not rep['enabled'] and ours() == [], ours()\n"
+            "assert rep['programs'] == {} and rep['counters'] == {}, rep['counters']\n"
+            "assert rep['startup']['import_s'] > 0 and rep['startup']['bootstrap_s'] > 0\n"
+            "d.enable()\n"
+            "assert sorted(f.__name__ for f in ours()) == ['_on_duration', '_on_event', '_on_stage_begins']\n"
+            "d.disable(); d.enable()\n"
+            "assert len(ours()) == 3  # registered once\n"
+            "print('unset-ok')\n"
+        )
+        proc = _run_python(code)
+        self.assertEqual(proc.returncode, 0, proc.stderr[-2000:])
+        self.assertIn("unset-ok", proc.stdout)
+
+
+class TestCompileAccounting(_DiagTestCase):
+    """What ``jax.monitoring`` tells of tracing, lowering, compiling and the persistent
+    cache, kept by program (``report()["programs"]``) and flat (``jit.*``)."""
+
+    def test_a_jitted_function_is_one_program_and_a_cached_call_adds_nothing(self):
+        def accounted_program(t):
+            return jnp.tanh(t) * 3.0 - 41.0
+
+        fn = jax.jit(accounted_program)
+        v = jnp.arange(11, dtype=jnp.float32)
+        with metrics():
+            diagnostics.reset()
+            fn(v).block_until_ready()
+            first = diagnostics.report()
+            fn(v).block_until_ready()
+            second = diagnostics.report()
+        entry = first["programs"]["accounted_program"]
+        self.assertEqual((entry["trace_n"], entry["backend_n"]), (1, 1))
+        self.assertGreater(entry["trace_s"], 0.0)
+        self.assertGreater(entry["lower_s"], 0.0)  # `jit(accounted_program)` is the same entry
+        self.assertGreater(entry["backend_s"], 0.0)
+        counters = first["counters"]
+        for name in diagnostics._JIT_COUNTERS:
+            self.assertIn(name, counters)
+        self.assertGreaterEqual(counters["jit.backend_n"], 1)
+        self.assertGreaterEqual(counters["jit.backend_s"], entry["backend_s"] - 1e-9)
+        self.assertEqual(second["programs"], first["programs"])
+        for name in diagnostics._JIT_COUNTERS:
+            self.assertEqual(second["counters"][name], counters[name], name)
+
+    def test_a_stage_inside_another_is_counted_once_in_the_flat_seconds(self):
+        def inner_program(t):
+            return jnp.sinh(t) + 43.0
+
+        inner = jax.jit(inner_program)
+
+        def outer_program(t):
+            return inner(t) * 2.0
+
+        v = jnp.arange(13, dtype=jnp.float32)
+        with metrics():
+            diagnostics.reset()
+            jax.jit(outer_program)(v).block_until_ready()
+            rep = diagnostics.report()
+        programs, counters = rep["programs"], rep["counters"]
+        self.assertEqual(programs["inner_program"]["trace_n"], 1)
+        self.assertEqual(programs["inner_program"]["backend_n"], 0)  # no program of its own
+        self.assertGreater(programs["outer_program"]["trace_s"],
+                           programs["inner_program"]["trace_s"])
+        by_program = sum(p["trace_s"] for p in programs.values())
+        self.assertLess(counters["jit.trace_s"], by_program)
+        # nothing else was traced at the top: the flat seconds are the outer trace's own
+        top = sum(p["trace_s"] for name, p in programs.items() if p["backend_n"])
+        self.assertAlmostEqual(counters["jit.trace_s"], top, delta=1e-3)
+        self.assertEqual(getattr(diagnostics._open, "stages", []), [])  # every stage closed
+
+    def test_the_flat_seconds_by_hand(self):
+        trace, lower = list(diagnostics._STAGES)[:2]
+        with metrics():
+            diagnostics.reset()
+            diagnostics._on_stage_begins(trace, 0.0, fun_name="outer_fn")
+            for seconds in (0.25, 0.125):
+                diagnostics._on_stage_begins(trace, 0.0, fun_name="inner_fn")
+                diagnostics._on_duration(trace, seconds, fun_name="inner_fn")
+            diagnostics._on_duration(trace, 10.0, fun_name="outer_fn")
+            diagnostics._on_stage_begins(lower, 0.0, fun_name="jit(outer_fn)")
+            diagnostics._on_duration(lower, 2.0, fun_name="jit(outer_fn)")
+            diagnostics._on_duration(lower, 1.0, fun_name="jit(late_fn)")  # began while off
+            rep = diagnostics.report()
+        self.assertEqual(rep["programs"]["inner_fn"]["trace_n"], 2)
+        self.assertAlmostEqual(rep["programs"]["inner_fn"]["trace_s"], 0.375)
+        self.assertAlmostEqual(rep["programs"]["outer_fn"]["trace_s"], 10.0)
+        self.assertAlmostEqual(rep["programs"]["outer_fn"]["lower_s"], 2.0)
+        self.assertAlmostEqual(rep["counters"]["jit.trace_s"], 10.0)  # not 10.375
+        self.assertAlmostEqual(rep["counters"]["jit.lower_s"], 3.0)
+
+    def test_the_table_is_bounded(self):
+        with metrics():
+            diagnostics.reset()
+            for i in range(diagnostics._MAX_PROGRAMS + 44):
+                diagnostics._on_duration(diagnostics._COMPILE_EVENT, 1e-9, fun_name=f"jit(p{i})")
+            rep = diagnostics.report()
+        self.assertEqual(len(rep["programs"]), diagnostics._MAX_PROGRAMS + 1)
+        self.assertEqual(rep["programs"]["other"]["backend_n"], 44)
+        self.assertEqual(rep["counters"]["jit.backend_n"], diagnostics._MAX_PROGRAMS + 44)
+        self.assertEqual(rep["counters"]["compile_n.none"], diagnostics._MAX_PROGRAMS + 44)
+
+    def test_a_second_compile_is_read_from_the_persistent_cache(self):
+        code = (
+            "import sys, jax, jax.numpy as jnp\n"
+            "jax.config.update('jax_persistent_cache_min_compile_time_secs', 0)\n"
+            "jax.config.update('jax_persistent_cache_min_entry_size_bytes', -1)\n"
+            "import heat_tpu as ht\n"
+            "d = ht.diagnostics\n"
+            "def cached_program(t):\n"
+            "    return jnp.cos(t) * 5.0 + 17.0\n"
+            "fn = jax.jit(cached_program)\n"
+            "v = jnp.arange(9, dtype=jnp.float32)\n"
+            "d.enable()\n"
+            "fn(v).block_until_ready()\n"
+            "c = d.report()['counters']\n"
+            "assert c['jit.cache_hit_n'] == 0 and c['jit.cache_miss_n'] >= 1, c\n"
+            "assert c['jit.cache_read_s'] == 0, c\n"
+            "jax.clear_caches()\n"
+            "fn(v).block_until_ready()\n"
+            "rep = d.report()\n"
+            "c, p = rep['counters'], rep['programs']['cached_program']\n"
+            "assert c['jit.cache_hit_n'] >= 1 and c['jit.cache_read_s'] > 0, c\n"
+            "assert c['jit.cache_read_s'] <= c['jit.backend_s'], c\n"
+            "assert (p['trace_n'], p['backend_n']) == (2, 2), p\n"
+            "assert (p['cache_miss_n'], p['cache_hit_n']) == (1, 1), p\n"
+            "assert 'jit.cache_saved_s' in c, c\n"
+            "print('cache-ok')\n"
+        )
+        with tempfile.TemporaryDirectory() as cache:
+            proc = _run_python(code, JAX_COMPILATION_CACHE_DIR=cache)
+        self.assertEqual(proc.returncode, 0, proc.stderr[-2000:])
+        self.assertIn("cache-ok", proc.stdout)
