@@ -43,10 +43,17 @@ trace): its grid is the buffer's blocks, a prefetched map names each block's exp
 expert's weights stay on the chip over its blocks, and the rows come through ``source`` by
 DMA, so the buffer's input side is never written to HBM. It writes the blocks in use and
 nothing else; a padding row reads some token's row, since no pair's slot points at it.
-Where the kernel's gate declines (another backend, another type, widths or ``block_rows``
-off the tiles, an expert too large for VMEM) the same blocks go through a ``jnp`` loop
-over the held experts, and ``record_fallback("nn.moe", why)`` says why. Either way a pair
-held elsewhere contributes exactly 0, by a select and never by a product with 0.
+
+**The combine.** A token's ``y`` is the weighted sum of its pairs' rows of that buffer. On a
+TPU it is the second Pallas call of the same module (``moe_combine_fwd``): the buffer stays
+in the 32-bit words the grouped kernel reads its input in, so a row is one aligned DMA; a
+step fetches the rows of the next 1,024 pairs, **those held here only**, and sums the current
+ones rank by rank in float32 from VMEM, so neither a gathered row a pair nor a ``(k, T, d)``
+intermediate goes through HBM. Where the kernels' one gate declines (another backend, another
+type, widths or ``block_rows`` off the tiles, an expert too large for VMEM) the same blocks
+go through a ``jnp`` loop over the held experts and the combine is a gather and a sum, and
+``record_fallback("nn.moe", why)`` says why. Either way a pair held elsewhere contributes
+exactly 0, by a select and never by a product with 0.
 
 No reference counterpart (the reference has no expert layers).
 """
@@ -190,18 +197,10 @@ class MoE(Module):
         slot = slot.T.reshape(-1)
         return slot, source, first_row, blocks, load
 
-    def _experts(self, experts, x, source, first_row, blocks):
+    def _loop(self, experts, x, source, first_row, blocks):
         """The sorted buffer ``x[source]`` (rows, dim): every held expert's blocks through
-        its gated MLP. Rows of blocks not in use come back as anything (the kernel) or as
-        0 (the loop)."""
-        b, rows = self.block_rows, source.shape[0]
-        why = (grouped_matmul.decline_reason(x, rows, experts["w_gate"], experts["w_down"], b)
-               if grouped_matmul.available() else f"backend {jax.default_backend()}")
-        if why is None:
-            return grouped_matmul.grouped_gated_silu(
-                x, source, experts["w_gate"], experts["w_up"], experts["w_down"],
-                *grouped_matmul.block_map(blocks, rows // b), block_rows=b)
-        diagnostics.record_fallback("nn.moe", why)
+        its gated MLP, one expert and one block at a time. Rows of blocks not in use are 0."""
+        b = self.block_rows
         xs = jnp.take(x, source, axis=0, mode="clip")
 
         def one_expert(e, ys):
@@ -217,22 +216,38 @@ class MoE(Module):
 
         return lax.fori_loop(0, self.count, one_expert, jnp.zeros_like(xs))
 
+    def _routed(self, experts, x, w, slot, source, first_row, blocks):
+        """The held experts' part of ``y`` (T, dim) float32: the sorted buffer through the
+        experts, and each token's weighted sum over its pairs held here. Both halves are the
+        kernels' or both are ``jnp``: the buffer's layout ties them."""
+        t, k = w.shape
+        b, rows = self.block_rows, source.shape[0]
+        why = (grouped_matmul.decline_reason(x, rows, experts["w_gate"], experts["w_down"], b, k)
+               if grouped_matmul.available() else f"backend {jax.default_backend()}")
+        if why is None:
+            ys = grouped_matmul.grouped_gated_silu(
+                x, source, experts["w_gate"], experts["w_up"], experts["w_down"],
+                *grouped_matmul.block_map(blocks, rows // b), block_rows=b)
+            return grouped_matmul.combine(ys, slot.reshape(t, k), w, self.dim, x.dtype,
+                                          all_held=self.count == self.n_experts)
+        diagnostics.record_fallback("nn.moe", why)
+        ys = self._loop(experts, x, source, first_row, blocks)
+        # a pair held elsewhere adds exactly 0: selected, since the row it would read may
+        # never have been written. Pairs are taken rank-major, (k, T), so that the
+        # weighted sum runs over the leading axis and not over a sublane-padded k
+        slot = slot.reshape(t, k).T.reshape(-1)
+        held = slot < rows
+        picked = jnp.where(held[:, None], ys[jnp.where(held, slot, 0)], 0)
+        picked = picked.reshape(k, t, self.dim)
+        return jnp.sum(picked.astype(jnp.float32) * w.T[:, :, None], axis=0)
+
     def apply(self, params, x, *, key=None, train=False):
         if x.ndim != 2:
             raise ValueError(f"MoE routes tokens of shape (T, dim); got {x.shape}")
-        t, k = x.shape[0], self.top_k
         with jax.named_scope("ht.nn.moe"):
             chosen, w = self.route(params, x)
             slot, source, first_row, blocks, load = self._layout(chosen)
-            ys = self._experts(params["experts"], x, source, first_row, blocks)
-            # a pair held elsewhere adds exactly 0: selected, since the row it would read may
-            # never have been written. Pairs are taken rank-major, (k, T), so that the
-            # weighted sum runs over the leading axis and not over a sublane-padded k
-            slot = slot.reshape(t, k).T.reshape(-1)
-            held = slot < ys.shape[0]
-            picked = jnp.where(held[:, None], ys[jnp.where(held, slot, 0)], 0)
-            picked = picked.reshape(k, t, self.dim)
-            y = jnp.sum(picked.astype(jnp.float32) * w.T[:, :, None], axis=0)
+            y = self._routed(params["experts"], x, w, slot, source, first_row, blocks)
             if self.shared is not None:
                 y = y + self.shared.apply(params["shared"], x).astype(jnp.float32)
             return y.astype(x.dtype), {"chosen": chosen, "load": load}

@@ -3,8 +3,9 @@
 program must carry the kernel in the form each place needs, and the kernel must compile
 for a described v5e at the block its gate picks. The flash forward compiles there too,
 at the latent-attention cell's shape and the blocks its gate picks, and so does the expert
-layer with its grouped kernel at both model cells' shapes (this file is the one that loads
-the TPU compiler; the kernel's other cases are in ``test_grouped_matmul.py``)."""
+layer with its grouped kernel and its combine kernel at the three model cells' shapes (this
+file is the one that loads the TPU compiler; the kernels' other cases are in
+``test_grouped_matmul.py``)."""
 
 import functools
 import os
@@ -380,7 +381,8 @@ def test_mosaic_compiles_the_expert_layer_at_the_cell_shape(one_chip, monkeypatc
     resident (the raised VMEM limit is one Mosaic accepts), the rows fetched through the
     sorted index, and the row chunk the rule reads off the widths; the loop over the experts is
     gone (no ``while`` at all, since the layout counts and no longer sorts and searches), and
-    with it every ``dynamic-update-slice`` into the sorted buffer."""
+    with it every ``dynamic-update-slice`` into the sorted buffer; the buffer is 32-bit words
+    and the combine is the second kernel (PR 36)."""
     monkeypatch.setattr(grouped_matmul, "available", lambda interpret=False: True)
     m = ht.nn.MoE(d, 1024, experts, top_k, 1, 2.5, None, 512, dtype=jnp.bfloat16)
     assert grouped_matmul._row_chunk(512, d, 1024, 2) == chunk
@@ -394,17 +396,54 @@ def test_mosaic_compiles_the_expert_layer_at_the_cell_shape(one_chip, monkeypatc
     text = compiled.as_text()
     assert "tpu_custom_call" in text and "moe_grouped_fwd" in text
     rows = 32768 * top_k + experts * 512
-    buffer = f"bf16[{rows},{d}]"
-    # the sorted buffer exists once, as the kernel's output: its input side is never made
-    # (the rows come through the sorted index), so no gather writes a buffer of its size
-    written = [line for line in text.splitlines()
-               if f" = {buffer}" in line and " parameter(" not in line]
-    assert len(written) == 1 and "moe_grouped_fwd" in written[0]
-    assert not [line for line in text.splitlines()
-                if "dynamic-update-slice" in line and buffer in line]
+    _the_buffer_is_words_and_the_combine_a_kernel(text, rows, d, top_k)
     # no loop is left, and no sort but the router's ``top_k``: the layout counts (PR 34)
     assert not [line for line in text.splitlines() if " while(" in line]
     assert all("/top_k" in line for line in text.splitlines() if " sort(" in line)
+
+
+def _the_buffer_is_words_and_the_combine_a_kernel(text, rows, d, top_k, tokens=32768):
+    """In a compiled expert layer the sorted buffer exists once, as the grouped kernel's
+    output in 32-bit words, a row padded to whole (8, 128) tiles: its input side is never made
+    (the rows come through the sorted index) and neither is ``bf16[rows, d]``; the combine is
+    ``moe_combine_fwd``, so no gather writes a row a pair (``bf16[pairs, d]``), no ``(k, T, d)``
+    temporary is left for a weighted sum to read back, and nothing updates the buffer in place."""
+    padded = grouped_matmul._token_tiles(d, 2)[1]
+    buffer = f"u32[{rows * padded},128]"
+    lines = text.splitlines()
+    written = [line for line in lines if f" = {buffer}" in line and " parameter(" not in line]
+    assert len(written) == 1 and "moe_grouped_fwd" in written[0]
+    assert "moe_combine_fwd" in text
+    assert [line for line in lines if "moe_combine_fwd" in line and f" = f32[{tokens},{d}]" in line]
+    pairs = tokens * top_k
+    for gone in (f"bf16[{rows},{d}]", f"bf16[{pairs},{d}]", f"[{top_k},{tokens},{d}]"):
+        assert gone not in text, gone
+    assert not [line for line in lines if "dynamic-update-slice" in line and buffer in line]
+
+
+@pytest.mark.parametrize("cell,d,top_k,experts,held,block_rows", [
+    ("trinity-score-32k", 2048, 8, 128, None, 512), ("xing4-score-32k", 3584, 4, 64, None, 512),
+    ("ling-score-32k", 2560, 8, 512, (0, 128), 128)])
+def test_mosaic_compiles_the_combine_at_the_cell_shape(one_chip, cell, d, top_k, experts, held,
+                                                      block_rows):
+    """``moe_combine_fwd`` alone at each model cell's shape: 32,768 tokens, the buffer of the
+    worst case of all pairs held here in 32-bit words, 1,024 pairs a step (128 tokens at top-8,
+    256 at top-4). Where every pair is held the row DMAs start without a branch; Ling's
+    quarter branches on each slot. The float32 sum is the program's one output: no temporary."""
+    rows = 32768 * top_k + (held[1] if held else experts) * block_rows
+    padded = grouped_matmul._token_tiles(d, 2)[1]
+    assert grouped_matmul._combine_blocks(top_k) == (1024 // top_k, 128 // top_k)
+
+    def shaped(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    compiled = jax.jit(functools.partial(grouped_matmul.combine, d=d, dtype=jnp.bfloat16,
+                                         all_held=held is None)).lower(
+        shaped((rows * padded, 128), jnp.uint32), shaped((32768, top_k), jnp.int32),
+        shaped((32768, top_k), jnp.float32)).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text and "moe_combine_fwd" in text
+    assert compiled.memory_analysis().temp_size_in_bytes < 2**24
 
 
 @pytest.mark.parametrize("dtype,precision", [(jnp.bfloat16, "highest"), (jnp.float32, "default")],
@@ -419,11 +458,20 @@ def test_the_grouped_kernel_states_its_precision(one_chip, dtype, precision):
     args = (shaped((1024, 2048)), shaped((4096,), jnp.int32), shaped((4, 2048, 512)),
             shaped((4, 2048, 512)), shaped((4, 512, 2048)), shaped((8,), jnp.int32),
             shaped((1,), jnp.int32))
-    assert grouped_matmul.decline_reason(args[0], 4096, args[2], args[4], 512) is None
+    assert grouped_matmul.decline_reason(args[0], 4096, args[2], args[4], 512, 8) is None
     with jax.default_matmul_precision(precision):
         compiled = jax.jit(functools.partial(grouped_matmul.grouped_gated_silu, block_rows=512)
                            ).lower(*args).compile()
-    assert "moe_grouped_fwd" in compiled.as_text()
+    text = compiled.as_text()
+    assert "moe_grouped_fwd" in text
+    # the buffer comes out as 32-bit words whatever the streams: a bfloat16 row of 2048 is 8
+    # sublane rows of 128 words, a float32 row 16
+    padded = grouped_matmul._token_tiles(2048, jnp.dtype(dtype).itemsize)[1]
+    assert padded == (8 if dtype == jnp.bfloat16 else 16) and f"u32[{4096 * padded},128]" in text
+    combined = jax.jit(functools.partial(grouped_matmul.combine, d=2048, dtype=dtype, all_held=True)
+                       ).lower(shaped((4096 * padded, 128), jnp.uint32), shaped((1024, 4), jnp.int32),
+                               shaped((1024, 4), jnp.float32)).compile()
+    assert "moe_combine_fwd" in combined.as_text()
 
 
 def test_mosaic_compiles_the_delta_rule_at_the_ling_cell_shape(one_chip):
@@ -450,8 +498,9 @@ def test_mosaic_compiles_the_delta_rule_at_the_ling_cell_shape(one_chip):
 
 def test_mosaic_compiles_the_expert_layer_at_the_ling_cell_shape(one_chip, monkeypatch):
     """One expert layer of ``ling-score-32k``: 128 of 512 experts of 2560 x 768 held, top-8 in
-    8 groups of which 4 stay, blocks of 128 rows: the grouped kernel is in the program, and
-    the sorted buffer is sized for the worst case of all pairs held here."""
+    8 groups of which 4 stay, blocks of 128 rows: the grouped kernel is in the program, the
+    sorted buffer is sized for the worst case of all pairs held here and is 32-bit words, a row
+    of 2560 padded to 16 sublane rows (8 KB), and the combine is the second kernel (PR 36)."""
     from heat_tpu.nn.ling import BLOCK_ROWS
 
     monkeypatch.setattr(grouped_matmul, "available", lambda interpret=False: True)
@@ -467,4 +516,4 @@ def test_mosaic_compiles_the_expert_layer_at_the_ling_cell_shape(one_chip, monke
     text = jax.jit(lambda p, x: m.apply(p, x)).lower(params, x).compile().as_text()
     assert "tpu_custom_call" in text and "moe_grouped_fwd" in text
     rows = 32768 * 8 + 128 * BLOCK_ROWS
-    assert f"bf16[{rows},2560]" in text
+    _the_buffer_is_words_and_the_combine_a_kernel(text, rows, 2560, 8)
