@@ -61,6 +61,7 @@ import numpy as np
 from jax import lax
 
 from .. import diagnostics
+from .sparse_index import WORD_KEYS, mask_words
 
 __all__ = ["flash_attention", "flash_attention_reference", "flash_forward", "forward_blocks"]
 
@@ -159,13 +160,14 @@ def _sub_tiles(bq: int, bk: int) -> tuple:
 
 
 def _fwd_footprint(bq: int, bk: int, d: int, dv: int, itemsize: int,
-                   with_bias: bool = False) -> int:
+                   with_bias: bool = False, with_mask: bool = False) -> int:
     """Bytes of VMEM a forward grid step holds at blocks ``(bq, bk)``: the one
     footprint model, which :func:`_fits` and :func:`forward_blocks` both gate on.
     Counted: the q / k / v / out blocks double-buffered (last dimension padded to 128
     lanes), the double-buffered LSE block and the running max / sum (one value a row,
     128 lanes each), the f32 accumulator of v's width, a streamed f32 bias block
-    double-buffered, and the live tiles of the step's sub-tiles: f32 scores, f32
+    double-buffered, a streamed tile of mask words (``(bq, 128)`` int32) likewise, and the
+    live tiles of the step's sub-tiles: f32 scores, f32
     probabilities and the probabilities in v's type, twice where the step has more
     than one sub-tile (one under the VPU while the next is under the MXU). Against the
     least ``vmem_limit_bytes`` Mosaic accepts (AOT, v5e, PR 30) the model reads high at
@@ -180,7 +182,8 @@ def _fwd_footprint(bq: int, bk: int, d: int, dv: int, itemsize: int,
     state = 4 * bq * (pad(dv) + 4 * _LANES)
     tiles = (8 + itemsize) * br * bs * (1 if (br, bs) == (bq, bk) else 2)
     bias = 8 * bq * bk if with_bias else 0
-    return blocks + state + tiles + bias
+    words = 8 * bq * _LANES if with_mask else 0
+    return blocks + state + tiles + bias + words
 
 
 def _fwd_blocks(dtype, tq: int, tk: int, with_bias: bool = False) -> tuple:
@@ -228,7 +231,7 @@ def _lanes(x, n: int):
 
 def _kernel(im_ref, jm_ref, flags_ref, q_ref, k_ref, v_ref, *refs,
             scale: float, bq: int, bk: int, br: int, bs: int, has_bias: bool = False,
-            window=None):
+            window=None, has_mask: bool = False):
     """One (q-block, k-block) pair of the online-softmax recurrence, walked as
     ``(bq / br) x (bk / bs)`` sub-tiles in one straight-line region.
 
@@ -246,6 +249,13 @@ def _kernel(im_ref, jm_ref, flags_ref, q_ref, k_ref, v_ref, *refs,
     iota/where mask, each behind a scalar test that skips it when it lies wholly above
     the diagonal or wholly below the band.
 
+    ``has_mask``: what a row may see differs row by row. A streamed tile of packed mask
+    words (``sparse_index.pack_mask``'s layout: bit ``b`` of lane ``l`` is key ``128 b + l``
+    of the tile's 4,096) decides every element of every step in place of the iota mask, a
+    lane tile of keys a shift and an ``and``; the schedule is the causal one (the mask lies
+    under the diagonal), so a diagonal step still skips its sub-tiles above it, and no
+    other pair is dropped: none is known to be empty when the call is traced.
+
     Pallas double-buffers the k/v block DMA against compute because the kv pair
     index advances with the grid. MXU inputs stay in the input dtype (bf16 runs
     at full MXU rate — forcing f32 here quarters throughput); softmax state and
@@ -253,10 +263,10 @@ def _kernel(im_ref, jm_ref, flags_ref, q_ref, k_ref, v_ref, *refs,
     """
     import jax.experimental.pallas as pl
 
-    if has_bias:
-        bias_ref, o_ref, lse_ref, acc_ref, m_ref, l_ref = refs
-    else:
-        o_ref, lse_ref, acc_ref, m_ref, l_ref = refs
+    refs = list(refs)
+    bias_ref = refs.pop(0) if has_bias else None
+    mask_ref = refs.pop(0) if has_mask else None
+    o_ref, lse_ref, acc_ref, m_ref, l_ref = refs
 
     p = pl.program_id(1)
     dv = v_ref.shape[2]
@@ -283,7 +293,14 @@ def _kernel(im_ref, jm_ref, flags_ref, q_ref, k_ref, v_ref, *refs,
         ) * scale  # (br, bs) f32
         if has_bias:
             s = s + bias_ref[rows, cols]
-        if masked:
+        if has_mask:
+            # the key block's place in its tile of words, then the sub-tile's, in lane tiles
+            first = jm_ref[p] % (WORD_KEYS // bk) * (bk // _LANES) + c * (bs // _LANES)
+            words = mask_ref[rows, :]
+            stays = jnp.concatenate([(words >> (first + g)) & 1 for g in range(bs // _LANES)],
+                                    axis=1)
+            s = jnp.where(stays != 0, s, _NEG_INF)
+        elif masked:
             ri = row0 + r * br + lax.broadcasted_iota(jnp.int32, (br, bs), 0)
             ci = col0 + c * bs + lax.broadcasted_iota(jnp.int32, (br, bs), 1)
             keep = ri >= ci
@@ -292,9 +309,9 @@ def _kernel(im_ref, jm_ref, flags_ref, q_ref, k_ref, v_ref, *refs,
             s = jnp.where(keep, s, _NEG_INF)
         m = m_ref[rows, :]
         m_new = jnp.maximum(m, jnp.max(s, axis=1, keepdims=True))
-        # a bias can mask a whole row of the block (all -inf): keep the exps finite —
-        # the row's l stays 0 and its output finalizes to 0 like the dense path
-        m_safe = jnp.maximum(m_new, _NEG_INF / 2) if has_bias else m_new
+        # a bias or a mask can hide a whole row of the block (all -inf): keep the exps
+        # finite — the row's l stays 0 and its output finalizes to 0 like the dense path
+        m_safe = jnp.maximum(m_new, _NEG_INF / 2) if has_bias or has_mask else m_new
         p_tile = jnp.exp(s - _lanes(m_safe, bs))
         corr = jnp.exp(m - m_safe)
         l_ref[rows, :] = l_ref[rows, :] * corr + jnp.sum(p_tile, axis=1, keepdims=True)
@@ -382,12 +399,15 @@ def _pair_schedule(nq: int, nk: int, bq: int, bk: int, causal: bool, window=None
     static_argnames=("causal", "scale", "bq", "bk", "interpret", "sub", "name", "window"),
 )
 def _flash_pallas(q, k, v, causal: bool, scale: float, bq: int, bk: int,
-                  interpret: bool = False, bias=None, sub=None, name=None, window=None):
+                  interpret: bool = False, bias=None, sub=None, name=None, window=None,
+                  mask=None):
     """q, k: (..., T, d); v: (..., Tk, dv) with its own width (dv != d is the latent-
     attention case: 192 against 128). k and v may have fewer heads (axis -3) than q,
     ``Hq = rep * Hkv``: query head ``h`` reads key/value head ``h // rep`` through the
     block index map, and nothing is repeated in HBM. ``window`` (causal only): row ``i``
     sees keys ``i - window < j <= i``, and the blocks outside that band are not visited.
+    ``mask`` (causal only): packed words ``(T, mask_words(Tk))`` int32, shared by all heads,
+    of the keys each row sees, all of them at or under the row's own position.
     ``sub`` is the step's sub-tile ``(br, bs)``, by default what :func:`_sub_tiles`
     reads off the blocks. ``name`` names the Pallas call in a device trace."""
     import jax.experimental.pallas as pl  # ht: ignore[trace-lazy-import] -- pallas imports deferred so CPU-only processes never pay them; runs once per compile, imports nothing of heat_tpu
@@ -401,11 +421,18 @@ def _flash_pallas(q, k, v, causal: bool, scale: float, bq: int, bk: int,
         qr = q.reshape(bh, tq, d)
         kr = k.reshape(bh // rep, tk, d)
         vr = v.reshape(bh // rep, tk, dv)
-        has_bias = bias is not None
+        has_bias, has_mask = bias is not None, mask is not None
         br, bs = _sub_tiles(bq, bk) if sub is None else sub
+        if has_mask and (not causal or window is not None or WORD_KEYS % bk or bs % _LANES
+                         or mask.shape != (tq, mask_words(tk))):
+            raise ValueError(f"a packed mask goes with the causal schedule, key blocks that "
+                             f"divide {WORD_KEYS} and words ({tq}, mask_words({tk})); got "
+                             f"causal={causal}, window={window}, bk={bk}, {mask.shape}")
 
         im, jm, flags = _pair_schedule(tq // bq, tk // bk, bq, bk, causal, window)
         if diagnostics._enabled:  # trace time only: which schedule this trace's steps take
+            if has_mask:
+                diagnostics.counter("kernels.dsa.flash")
             diagnostics.counter(
                 "kernels.flash.fwd." + ("serial" if (br, bs) == (bq, bk) else "overlapped"))
             # block pairs the schedule lists against all of them: what causal and band skip
@@ -428,6 +455,11 @@ def _flash_pallas(q, k, v, causal: bool, scale: float, bq: int, bk: int,
                 pl.BlockSpec((bq, bk), lambda b, p, im, jm, fl: (im[p], jm[p]))
             )
             inputs.append(bias.astype(jnp.float32))
+        if has_mask:
+            # one tile of words covers 4,096 keys: it is fetched anew every 4096 / bk steps
+            in_specs.append(pl.BlockSpec(
+                (bq, _LANES), lambda b, p, im, jm, fl: (im[p], jm[p] // (WORD_KEYS // bk))))
+            inputs.append(mask)
         grid_spec = pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=3,
             grid=(bh, len(im)),
@@ -444,7 +476,7 @@ def _flash_pallas(q, k, v, causal: bool, scale: float, bq: int, bk: int,
         )
         out, lse = pl.pallas_call(
             functools.partial(_kernel, scale=scale, bq=bq, bk=bk, br=br, bs=bs,
-                              has_bias=has_bias, window=window),
+                              has_bias=has_bias, window=window, has_mask=has_mask),
             grid_spec=grid_spec,
             out_shape=[
                 jax.ShapeDtypeStruct((bh, tq, dv), q.dtype),
@@ -736,7 +768,7 @@ def _fits(q, k, bq: int, bk: int, with_bias: bool = False) -> bool:
     return max(_fwd_footprint(bq, bk, d, d, itemsize, with_bias), bwd) <= _vmem_budget()
 
 
-def forward_blocks(q, k, v):
+def forward_blocks(q, k, v, with_mask: bool = False):
     """The largest preferred ``(bq, bk)`` with which the forward kernel alone runs
     ``q, k: (..., T, d)``, ``v: (..., Tk, dv)`` (k and v with q's heads or a divisor of
     them), or None: the sequence does not tile, the pair list outgrows SMEM, a type
@@ -746,7 +778,9 @@ def forward_blocks(q, k, v):
     sub-tiles: 528 steps a head, 6.0 MiB by the model. The same preference serves a
     window: at d = d_v = 128 under a band of 2,048 keys (1024, 1024) was the fastest of ten
     block pairs on the chip (11.2 ms a call; key blocks of 512 12.5, of 256 18.9: PERF.md,
-    PR 31), because a row sweep's masked steps, not its skipped keys, set the time."""
+    PR 31), because a row sweep's masked steps, not its skipped keys, set the time.
+    ``with_mask``: the call streams packed mask words; every preferred key block divides
+    their tile of 4,096 keys."""
     tq, d = q.shape[-2], q.shape[-1]
     tk, dv = k.shape[-2], v.shape[-1]
     if any(t.dtype not in (jnp.float32, jnp.bfloat16, jnp.float16) for t in (q, k, v)):
@@ -755,20 +789,22 @@ def forward_blocks(q, k, v):
     for bq, bk in _FWD_BLOCK_PREFS.get(itemsize, ((512, 512),)):
         if tq % bq or tk % bk or (tq // bq) * (tk // bk) > _MAX_PAIRS:
             continue
-        if _fwd_footprint(bq, bk, d, dv, itemsize) <= _vmem_budget():
+        if _fwd_footprint(bq, bk, d, dv, itemsize, with_mask=with_mask) <= _vmem_budget():
             return bq, bk
     return None
 
 
 def flash_forward(q, k, v, causal: bool, scale: float, blocks, name=None,
-                  interpret: bool = False, window=None):
+                  interpret: bool = False, window=None, mask=None):
     """The forward kernel alone, for inference paths: v may be narrower or wider than
     q and k, k and v may have fewer heads than q (grouped heads, taken where they lie),
     ``window`` keeps row ``i`` to keys ``i - window < j <= i`` and skips the blocks
-    outside that band, ``blocks`` is what :func:`forward_blocks` chose, ``name`` names
-    the Pallas call in device traces. No gradient is defined on this entry."""
+    outside that band, ``mask`` (packed words, ``sparse_index.pack_mask``) keeps row ``i``
+    to the keys whose bits are set, all heads alike, ``blocks`` is what
+    :func:`forward_blocks` chose, ``name`` names the Pallas call in device traces. No
+    gradient is defined on this entry."""
     out, _ = _flash_pallas(q, k, v, causal, float(scale), *blocks, interpret=interpret,
-                           name=name, window=window)
+                           name=name, window=window, mask=mask)
     return out
 
 

@@ -13,5 +13,6 @@ from .xing4 import *
 from .trinity import *
 from .kda import *
 from .ling import *
-from . import (attention, data_parallel, functional, hyper_connections, kda, ling, modules, moe,
-               recurrent, scoring, trinity, xing4)
+from .deepseek_v32 import *
+from . import (attention, data_parallel, deepseek_v32, functional, hyper_connections, kda, ling,
+               modules, moe, recurrent, scoring, trinity, xing4)

@@ -30,7 +30,7 @@ from __future__ import annotations
 
 import math
 from functools import partial
-from typing import Optional
+from typing import Optional, Tuple
 
 import numpy as np
 
@@ -40,6 +40,7 @@ from jax import lax
 from jax.sharding import PartitionSpec as P
 
 from ..core import diagnostics
+from ..core.kernels import sparse_index
 from ..core.kernels.flash_attention import (
     flash_attention,
     flash_forward,
@@ -58,6 +59,7 @@ __all__ = [
     "ulysses_attention",
     "MultiheadAttention",
     "MultiheadLatentAttention",
+    "LightningIndexer",
     "GroupedQueryAttention",
     "yarn_inv_freq",
     "rotate_halves",
@@ -748,6 +750,79 @@ def even_then_odd(w, start: int):
     return jnp.take(w, jnp.asarray(order, jnp.int32), axis=-1)
 
 
+class LightningIndexer(Module):
+    """The learned index of a sparse attention: which ``topk`` earlier tokens each query
+    attends to (DeepSeek sparse attention's lightning indexer).
+
+    A small attention of ``n_heads`` heads that share one key a token, read from the layer's
+    input ``u`` (T, dim) and its query latent ``c_q`` (T, q_lora_rank)::
+
+        q_j[t] = (c_q[t] W_qb)_j                      n_heads vectors of head_dim
+        k[s]   = LayerNorm(u[s] W_k)                  one vector of head_dim, weight and bias
+        w_j[t] = (u[t] W_w)_j n_heads^-1/2 head_dim^-1/2          float32
+        I[t,s] = sum_j w_j[t] relu(q_j[t] . k[s])     float32, s <= t
+
+    with rotary positions on the first ``rope_dim`` dimensions of ``q_j`` and ``k`` (the
+    two-halves layout, ``inv_freq`` as the layer's own). Query ``t`` keeps the ``min(topk,
+    t + 1)`` positions of largest ``I[t, .]``, ties to the lower position. ``apply`` takes
+    ``(u, c_q)`` and returns the selection as packed words ``(T, mask_words(T))`` int32
+    (``core/kernels/sparse_index.py``: bit ``s`` of row ``t``).
+
+    On a TPU scores and selection are one Pallas call (``dsa_index_fwd`` in a device trace)
+    that holds neither a (T, T) score nor a sort; where it does not apply the ``jnp`` form
+    runs (dense scores, ``lax.top_k``) and ``record_fallback("nn.dsa", why)`` says why.
+    Operands are in the input's type with float32 accumulation; the head weights, the ReLU
+    and the sum over the heads are float32.
+    """
+
+    def __init__(self, dim: int, q_lora_rank: int, n_heads: int, head_dim: int, rope_dim: int,
+                 topk: int, inv_freq, rope_magnitude: float = 1.0, eps: float = 1e-6,
+                 dtype=jnp.float32, norm_init_std: float = 0.0):
+        self.dim, self.q_lora_rank = dim, q_lora_rank
+        self.n_heads, self.head_dim, self.rope_dim, self.topk = n_heads, head_dim, rope_dim, topk
+        self.inv_freq, self.rope_magnitude = inv_freq, rope_magnitude
+        self.eps = eps
+        self.dtype = jnp.dtype(dtype)
+        self.norm_init_std = norm_init_std
+
+    def init(self, key):
+        kq, kk, kw, kn, kb = jax.random.split(key, 5)
+        r, h, d, dt = self.q_lora_rank, self.n_heads, self.head_dim, self.dtype
+        std = jnp.float32(self.norm_init_std)
+        return {
+            "wq_b": normal_weight(kq, (r, h * d), dt, r ** -0.5),
+            "wk": normal_weight(kk, (self.dim, d), dt, self.dim ** -0.5),
+            "k_norm": {"weight": 1.0 + std * jax.random.normal(kn, (d,), jnp.float32),
+                       "bias": std * jax.random.normal(kb, (d,), jnp.float32)},
+            "weights_proj": normal_weight(kw, (self.dim, h), dt, self.dim ** -0.5),
+        }
+
+    def _rotate(self, x):
+        r = self.rope_dim
+        return jnp.concatenate(
+            [rotate_halves(x[..., :r], self.inv_freq, self.rope_magnitude), x[..., r:]], axis=-1)
+
+    def apply(self, params, x, *, key=None, train=False):
+        u, c_q = x
+        if u.ndim != 2:
+            raise ValueError(f"LightningIndexer selects over one document (T, dim); got {u.shape}")
+        h, d, dt = self.n_heads, self.head_dim, u.dtype
+        with jax.named_scope("ht.nn.dsa"):
+            q = contract("tr,rhd->htd", c_q, params["wq_b"].reshape(-1, h, d)).astype(dt)
+            k = contract("td,de->te", u, params["wk"]).astype(dt).astype(jnp.float32)
+            k = k - jnp.mean(k, axis=-1, keepdims=True)
+            k = k * lax.rsqrt(jnp.mean(k * k, axis=-1, keepdims=True) + jnp.float32(self.eps))
+            k = (k * params["k_norm"]["weight"] + params["k_norm"]["bias"]).astype(dt)
+            q, k = self._rotate(q), self._rotate(k)
+            w = contract("td,dh->ht", u, params["weights_proj"]) * jnp.float32((h * d) ** -0.5)
+            why = (sparse_index.decline_reason(q, k, w) if sparse_index.available()
+                   else f"backend {jax.default_backend()}")
+            if why is None:
+                return sparse_index.dsa_index(q, k, w, self.topk)
+            diagnostics.record_fallback("nn.dsa", f"{why}: T={u.shape[0]} {dt}")
+            return sparse_index.dsa_index_plain(q, k, w, self.topk)
+
+
 class MultiheadLatentAttention(Module):
     """Causal self-attention through low-rank latents (multi-head latent attention).
 
@@ -759,14 +834,23 @@ class MultiheadLatentAttention(Module):
     the heads' outputs (``v_head_dim`` wide, not the query's width) are concatenated into
     ``W_o``. No bias anywhere. Input ``(..., T, dim)``, positions ``0..T-1`` on axis -2.
 
-    Two variants that published models of this kind take. ``q_lora_rank=None``: no query
+    Three variants that published models of this kind take. ``q_lora_rank=None``: no query
     latent, ``[q_nope; q_rope] = x W_q`` per head directly (no ``W_qa``, no query norm).
     ``head_gate=True``: every head's output is multiplied by ``sigmoid(x W_g)``, one
-    scalar a head (float32), before ``W_o``.
+    scalar a head (float32), before ``W_o``. ``index=(heads, head_dim, topk)``: a
+    :class:`LightningIndexer` reads ``x`` and ``c_q`` and every head of query ``t`` attends
+    to the ``topk`` earlier tokens it selects, one set for all heads (one document
+    ``(T, dim)`` then, and a query latent); ``apply`` returns ``(y, {"selection": packed
+    words (T, mask_words(T))})``.
+
+    ``head_groups`` > 1 runs the heads a group at a time (a ``lax.scan``): q, k, v and o exist
+    for one group only and ``W_o``'s products are summed in float32. At 128 heads of 192 and
+    32,768 tokens q alone is 1.6 GB; in four groups the core is the shape of 32 heads.
 
     This is the whole-sequence forward (scoring, prefill): no key/value cache and no
     absorbed products. On TPU the core runs in the flash Pallas kernel at
-    ``d_qk != d_v``, named ``mla_flash_fwd`` in device traces; where it does not apply
+    ``d_qk != d_v``, named ``mla_flash_fwd`` in device traces, or ``dsa_flash_fwd`` under a
+    selection, whose packed words it reads; where it does not apply
     (another backend, a sequence that does not tile) the XLA path runs and
     ``record_fallback("nn.mla", ...)`` says why. Parameters are stored in ``dtype``
     (norm weights float32); contractions accumulate in float32.
@@ -776,9 +860,14 @@ class MultiheadLatentAttention(Module):
                  qk_nope_head_dim: int, qk_rope_head_dim: int, v_head_dim: int,
                  rope_theta: float = 10000.0, rope_scaling: Optional[dict] = None,
                  eps: float = 1e-6, dtype=jnp.float32, norm_init_std: float = 0.0,
-                 head_gate: bool = False):
+                 head_gate: bool = False, index: Optional[Tuple[int, int, int]] = None,
+                 head_groups: int = 1):
+        if num_heads % head_groups:
+            raise ValueError(f"{head_groups} groups do not divide {num_heads} heads")
+        if index is not None and q_lora_rank is None:
+            raise ValueError("the indexer reads the query latent: q_lora_rank is None")
         self.dim = dim
-        self.num_heads = num_heads
+        self.num_heads, self.head_groups = num_heads, head_groups
         self.q_lora_rank = q_lora_rank
         self.kv_lora_rank = kv_lora_rank
         self.nope, self.rope, self.v_dim = qk_nope_head_dim, qk_rope_head_dim, v_head_dim
@@ -792,6 +881,9 @@ class MultiheadLatentAttention(Module):
         self.head_gate = head_gate
         self.q_norm = None if q_lora_rank is None else RMSNorm(q_lora_rank, eps, norm_init_std)
         self.kv_norm = RMSNorm(kv_lora_rank, eps, norm_init_std)
+        self.indexer = None if index is None else LightningIndexer(
+            dim, q_lora_rank, index[0], index[1], qk_rope_head_dim, index[2], self.inv_freq,
+            self.rope_magnitude, eps, dtype, norm_init_std)
 
     def init(self, key):
         kqa, kqb, kva, kvb, ko, kqn, kkn = jax.random.split(key, 7)
@@ -818,26 +910,68 @@ class MultiheadLatentAttention(Module):
         if self.head_gate:
             params["wg"] = normal_weight(jax.random.fold_in(key, 7), (self.dim, h), dt,
                                          self.dim ** -0.5)
+        if self.indexer is not None:
+            params["indexer"] = self.indexer.init(jax.random.fold_in(key, 8))
         return params
 
-    def _core(self, q, k, v):
-        """Causal softmax(q k^T scale) v on (..., H, T, .) operands."""
+    def _core(self, q, k, v, selection=None):
+        """Causal softmax(q k^T scale) v on (..., H, T, .) operands; under a ``selection``
+        (packed words) a row sees the keys of its set bits only."""
         blocks, why = None, f"backend {jax.default_backend()}"
         if jax.default_backend() == "tpu":
-            blocks, why = forward_blocks(q, k, v), "no block pair tiles and fits"
+            blocks = forward_blocks(q, k, v, selection is not None)
+            why = "no block pair tiles and fits"
         if blocks is not None:
-            return flash_forward(q, k, v, True, self.scale, blocks, name="mla_flash_fwd")
-        diagnostics.record_fallback("nn.mla", f"{why}: T={q.shape[-2]} {q.dtype}")
+            name = "mla_flash_fwd" if selection is None else "dsa_flash_fwd"
+            return flash_forward(q, k, v, True, self.scale, blocks, name=name, mask=selection)
+        if diagnostics._enabled:  # trace time only
+            diagnostics.record_fallback("nn.mla", f"{why}: T={q.shape[-2]} {q.dtype}")
         t = q.shape[-2]
         s = contract("...qd,...kd->...qk", q, k) * jnp.float32(self.scale)
-        rows = jnp.arange(t, dtype=jnp.int32)
-        s = jnp.where(rows[:, None] >= rows[None, :], s, _NEG_INF)
+        if selection is None:
+            rows = jnp.arange(t, dtype=jnp.int32)
+            keep = rows[:, None] >= rows[None, :]
+        else:
+            keep = sparse_index.unpack_mask(selection, t)
+        s = jnp.where(keep, s, _NEG_INF)
         p = jax.nn.softmax(s, axis=-1).astype(v.dtype)
         return contract("...qk,...kd->...qd", p, v).astype(q.dtype)
 
+    def _latent(self, params, x):
+        """``(c_kv, k_rope)``: the normed key/value latent and the one rotated rope key."""
+        kv = contract("...td,dr->...tr", x,
+                      even_then_odd(params["wkv_a"], self.kv_lora_rank)).astype(x.dtype)
+        c_kv = self.kv_norm.apply(params["kv_norm"], kv[..., :self.kv_lora_rank])
+        return c_kv, rotate_halves(kv[..., self.kv_lora_rank:], self.inv_freq,
+                                   self.rope_magnitude)
+
+    def _heads(self, x, c_q, latent, selection, wq, wkv_b, wo, wg=None):
+        """The heads whose columns of ``wq`` and ``wkv_b``, rows of ``wo`` and columns of
+        ``wg`` these are, through the core and ``W_o``: (..., T, dim) float32. ``latent()``
+        gives :meth:`_latent`'s pair: called after the queries are made, so that the heads
+        in one group trace the operations in the order they always had."""
+        dn, dr, dv, dt = self.nope, self.rope, self.v_dim, x.dtype
+        h = wo.shape[0] // dv
+        # the rope columns of both projections, published as interleaved pairs, are
+        # taken even ones first: rotate_halves then turns the published pairs
+        wq_b = even_then_odd(wq.reshape(wq.shape[0], h, dn + dr), dn)
+        q = contract("...tr,rhe->...hte", c_q, wq_b).astype(dt)
+        q = jnp.concatenate(
+            [q[..., :dn], rotate_halves(q[..., dn:], self.inv_freq, self.rope_magnitude)],
+            axis=-1)
+        c_kv, k_rope = latent()
+        kv_h = contract("...tr,rhe->...hte", c_kv,
+                        wkv_b.reshape(self.kv_lora_rank, h, dn + dv)).astype(dt)
+        k_rope = jnp.broadcast_to(k_rope[..., None, :, :], kv_h.shape[:-1] + (dr,))
+        k = jnp.concatenate([kv_h[..., :dn], k_rope], axis=-1)
+        o = self._core(q, k, kv_h[..., dn:], selection)
+        if wg is not None:
+            gate = jax.nn.sigmoid(contract("...td,dh->...ht", x, wg))
+            o = (o.astype(jnp.float32) * gate[..., None]).astype(dt)
+        return contract("...htv,hvd->...td", o, wo.reshape(h, dv, self.dim))
+
     def apply(self, params, x, *, key=None, train=False):
         x = x.larray if isinstance(x, DNDarray) else x
-        h, dn, dr, dv = self.num_heads, self.nope, self.rope, self.v_dim
         dt = x.dtype
         with jax.named_scope("ht.nn.mla"):
             if self.q_norm is None:
@@ -846,28 +980,25 @@ class MultiheadLatentAttention(Module):
                 c_q = self.q_norm.apply(
                     params["q_norm"], contract("...td,dr->...tr", x, params["wq_a"]).astype(dt))
                 wq = params["wq_b"]
-            # the rope columns of both projections, published as interleaved pairs, are
-            # taken even ones first: rotate_halves then turns the published pairs
-            wq_b = even_then_odd(wq.reshape(wq.shape[0], h, dn + dr), dn)
-            q = contract("...tr,rhe->...hte", c_q, wq_b).astype(dt)
-            q = jnp.concatenate(
-                [q[..., :dn], rotate_halves(q[..., dn:], self.inv_freq, self.rope_magnitude)],
-                axis=-1)
-            kv = contract("...td,dr->...tr", x,
-                          even_then_odd(params["wkv_a"], self.kv_lora_rank)).astype(dt)
-            c_kv = self.kv_norm.apply(params["kv_norm"], kv[..., :self.kv_lora_rank])
-            k_rope = rotate_halves(kv[..., self.kv_lora_rank:], self.inv_freq,
-                                   self.rope_magnitude)
-            kv_h = contract("...tr,rhe->...hte", c_kv,
-                            params["wkv_b"].reshape(self.kv_lora_rank, h, dn + dv)).astype(dt)
-            k_rope = jnp.broadcast_to(k_rope[..., None, :, :], kv_h.shape[:-1] + (dr,))
-            k = jnp.concatenate([kv_h[..., :dn], k_rope], axis=-1)
-            o = self._core(q, k, kv_h[..., dn:])
+            selection = None
+            if self.indexer is not None:
+                selection = self.indexer.apply(params["indexer"], (x, c_q))
+            weights = [wq, params["wkv_b"], params["wo"]]
             if self.head_gate:
-                gate = jax.nn.sigmoid(contract("...td,dh->...ht", x, params["wg"]))
-                o = (o.astype(jnp.float32) * gate[..., None]).astype(dt)
-            return contract("...htv,hvd->...td", o,
-                            params["wo"].reshape(h, dv, self.dim)).astype(dt)
+                weights.append(params["wg"])
+            if self.head_groups == 1:
+                y = self._heads(x, c_q, lambda: self._latent(params, x), selection, *weights)
+            else:
+                # a group's heads are neighbours: columns of W_qb, W_kvb and W_g, rows of W_o
+                latent, g = self._latent(params, x), self.head_groups
+                groups = tuple(
+                    jnp.moveaxis(w.reshape(w.shape[:axis] + (g, -1) + w.shape[axis + 1:]), axis, 0)
+                    for w, axis in zip(weights, (1, 1, 0, 1)))
+                y = lax.scan(
+                    lambda acc, ws: (acc + self._heads(x, c_q, lambda: latent, selection, *ws), None),
+                    jnp.zeros(x.shape[:-1] + (self.dim,), jnp.float32), groups)[0]
+            y = y.astype(dt)
+            return y if selection is None else (y, {"selection": selection})
 
 
 class GroupedQueryAttention(Module):
@@ -935,7 +1066,8 @@ class GroupedQueryAttention(Module):
             name = "gqa_flash_fwd" if self.window is None else "swa_flash_fwd"
             return flash_forward(q, k, v, True, self.scale, blocks, name=name,
                                  window=self.window)
-        diagnostics.record_fallback("nn.gqa", f"{why}: T={q.shape[-2]} {q.dtype}")
+        if diagnostics._enabled:  # trace time only
+            diagnostics.record_fallback("nn.gqa", f"{why}: T={q.shape[-2]} {q.dtype}")
         t, g = q.shape[-2], self.num_kv_heads
         qg = q.reshape(q.shape[:-3] + (g, self.num_heads // g) + q.shape[-2:])
         s = contract("...grqd,...gkd->...grqk", qg, k) * jnp.float32(self.scale)

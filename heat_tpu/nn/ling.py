@@ -126,7 +126,8 @@ class LingScores(NamedTuple):
 class LingBlock(Module):
     """One layer on tokens ``(T, d)``: ``x <- x + mix(norm(x))``, then ``x <- x +
     feed-forward(norm(x))``; ``latent`` chooses the mixing. ``apply`` returns ``(x, aux)``,
-    ``aux`` the expert layer's ``{"chosen", "load"}`` or None for a dense layer."""
+    ``aux`` the expert layer's ``{"chosen", "load"}`` (and what a mixing that returns one adds
+    to it) or None for a dense layer that mixes without one."""
 
     def __init__(self, config: LingConfig, latent: bool, dense: bool,
                  experts_held: Optional[Tuple[int, int]] = None, dtype=jnp.bfloat16,
@@ -153,10 +154,15 @@ class LingBlock(Module):
                            c.n_group, c.topk_group)
 
     def apply(self, params, x, *, key=None, train=False):
-        x = x + self.attn.apply(params["attn"], self.attn_norm.apply(params["attn_norm"], x))
-        out = self.ffn.apply(params["ffn"], self.ffn_norm.apply(params["ffn_norm"], x))
-        f, aux = out if isinstance(out, tuple) else (out, None)  # experts give (y, aux)
-        return x + f, aux
+        def with_aux(out):  # experts, and attention over a selection, give (y, aux)
+            return out if isinstance(out, tuple) else (out, {})
+
+        a, seen = with_aux(self.attn.apply(params["attn"],
+                                           self.attn_norm.apply(params["attn_norm"], x)))
+        x = x + a
+        f, routed = with_aux(self.ffn.apply(params["ffn"],
+                                            self.ffn_norm.apply(params["ffn_norm"], x)))
+        return x + f, ({**seen, **routed} or None)
 
 
 class Ling(ScoringForward):

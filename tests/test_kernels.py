@@ -517,3 +517,140 @@ def test_mosaic_compiles_the_expert_layer_at_the_ling_cell_shape(one_chip, monke
     assert "tpu_custom_call" in text and "moe_grouped_fwd" in text
     rows = 32768 * 8 + 128 * BLOCK_ROWS
     _the_buffer_is_words_and_the_combine_a_kernel(text, rows, 2560, 8)
+
+
+# ------------------------- the index kernel and the flash forward under its packed mask (PR 37)
+def _index_inputs(h, t, d, case, dtype, seed=0):
+    """``q`` (H, T, D), ``k`` (T, D), ``w`` (H, T). ``ties``: small integers and signed powers
+    of two, so that every score is exact in any order of summation and many are equal."""
+    kq, kk, kw = jax.random.split(jax.random.key(seed), 3)
+    if case == "ties":
+        q = jax.random.randint(kq, (h, t, d), -2, 3).astype(dtype)
+        k = jax.random.randint(kk, (t, d), -2, 3).astype(dtype)
+        w = jnp.exp2(jax.random.randint(kw, (h, t), -2, 2).astype(jnp.float32))
+        return q, k, w * jnp.where(jax.random.bernoulli(kw, 0.3, (h, t)), -1.0, 1.0)
+    return (jax.random.normal(kq, (h, t, d)).astype(dtype), jax.random.normal(kk, (t, d)).astype(dtype),
+            jax.random.normal(kw, (h, t), jnp.float32))
+
+
+@pytest.mark.parametrize("h,t,topk,case,dtype", [
+    (8, 256, 64, "ties", jnp.bfloat16), (8, 384, 64, "ties", jnp.bfloat16),
+    (8, 384, 64, "normal", jnp.bfloat16), (16, 512, 100, "normal", jnp.float32),
+    (8, 256, 300, "normal", jnp.bfloat16)], ids=str)
+def test_interpreted_index_kernel_selects_what_top_k_selects(h, t, topk, case, dtype):
+    """``dsa_index_fwd`` against dense scores and ``lax.top_k``: the same sets, bit for bit of
+    the packed words, with ties (``ties``: most scores are equal to others) going to the lower
+    position; key blocks of 256 and of 128 (T = 384), a ``topk`` above T (everything causal),
+    rows with fewer earlier tokens than ``topk``."""
+    from heat_tpu.core.kernels import sparse_index
+
+    q, k, w = _index_inputs(h, t, 128, case, dtype)
+    assert sparse_index.decline_reason(q, k, w) is None
+    got = sparse_index.dsa_index(q, k, w, topk, interpret=True)
+    want = sparse_index.select_plain(sparse_index.index_scores(q, k, w), topk)
+    assert got.shape == (t, sparse_index.mask_words(t)) and got.dtype == jnp.int32
+    np.testing.assert_array_equal(np.asarray(sparse_index.unpack_mask(got, t)), np.asarray(want))
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(sparse_index.pack_mask(want)))
+    kept = np.asarray(want).sum(axis=1)
+    assert kept.tolist() == [min(topk, i + 1) for i in range(t)]
+    if case == "ties":  # the test means what it says: the rank's value is shared
+        scores = np.asarray(sparse_index.index_scores(q, k, w))
+        assert any(len(np.unique(scores[i, :i + 1])) < i + 1 for i in range(topk, t))
+
+
+def test_the_index_gate_declines_what_does_not_tile():
+    from heat_tpu.core.kernels import sparse_index
+
+    q, k, w = _index_inputs(8, 256, 128, "normal", jnp.bfloat16)
+    assert "tiles" in sparse_index.decline_reason(q[:, :200], k[:200], w[:, :200])
+    assert "tiles" in sparse_index.decline_reason(q[..., :64], k[..., :64], w)
+    assert "takes bfloat16 or float32" in sparse_index.decline_reason(q.astype(jnp.float16), k, w)
+    big = jax.ShapeDtypeStruct((64, 2 ** 18, 128), jnp.bfloat16)
+    assert "VMEM" in sparse_index.decline_reason(big, jax.ShapeDtypeStruct((2 ** 18, 128), jnp.bfloat16), w)
+
+
+@pytest.mark.parametrize("t,blocks,sub,dtype", [
+    (512, (128, 128), None, jnp.float32), (512, (256, 256), (128, 128), jnp.float32),
+    (8192, (1024, 1024), None, jnp.float32), (1024, (512, 512), None, jnp.bfloat16)], ids=str)
+def test_interpreted_flash_forward_under_a_packed_mask(t, blocks, sub, dtype):
+    """The masked schedule of the forward body: every head of a row attends to the keys whose
+    bits are set and to no other, whole blocks with no selected key included (their rows keep
+    a finite running maximum), key blocks that share a tile of words and ones that start the
+    next (T 8,192 = two tiles), sub-tiles of a step reading their own bits."""
+    from heat_tpu.core.kernels import sparse_index
+
+    kq, kk, kv, ks = jax.random.split(jax.random.key(1), 4)
+    h, d, dv = 2, 64, 32
+    q, k = (jax.random.normal(key, (h, t, d)).astype(dtype) for key in (kq, kk))
+    v = jax.random.normal(kv, (h, t, dv)).astype(dtype)
+    mask = sparse_index.select_plain(jax.random.normal(ks, (t, t)), max(t // 26, 20))
+    got, _ = flash_kernel._flash_pallas(q, k, v, True, d ** -0.5, *blocks, interpret=True, sub=sub,
+                                        mask=sparse_index.pack_mask(mask), name="dsa_flash_fwd")
+    f32 = functools.partial(jnp.asarray, dtype=jnp.float32)
+    s = jnp.einsum("hqd,hkd->hqk", f32(q), f32(k), precision="highest") * d ** -0.5
+    p = jax.nn.softmax(jnp.where(mask[None], s, -jnp.inf), axis=-1)
+    want = jnp.einsum("hqk,hkd->hqd", p, f32(v), precision="highest")
+    assert float(jnp.max(jnp.abs(f32(got) - want))) < (1e-5 if dtype == jnp.float32 else 2e-2)
+    with pytest.raises(ValueError, match="packed mask goes with the causal schedule"):
+        flash_kernel._flash_pallas(q, k, v, False, 1.0, *blocks, interpret=True,
+                                   mask=sparse_index.pack_mask(mask))
+
+
+@pytest.mark.parametrize("name,shape,digest", [
+    ("mla_flash_fwd", (32, 32, 192, 128, None), "21340080dd9d0121"),
+    ("gqa_flash_fwd", (32, 4, 128, 128, None), "84e04e2104125b18"),
+    ("swa_flash_fwd", (32, 4, 128, 128, 2048), "44a69c677a3cc085")])
+def test_the_three_schedules_trace_what_they_traced_before_the_mask(name, shape, digest):
+    """One forward body for four names: with no mask the jaxpr of the call at each accepted
+    cell's shape is, character for character, what the commit before PR 37 traced (the digests
+    were taken on that commit). A PR that changes the body on purpose replaces them."""
+    import hashlib
+
+    hq, hkv, d, dv, window = shape
+    q = jax.ShapeDtypeStruct((hq, 32768, d), jnp.bfloat16)
+    k = jax.ShapeDtypeStruct((hkv, 32768, d), jnp.bfloat16)
+    v = jax.ShapeDtypeStruct((hkv, 32768, dv), jnp.bfloat16)
+    blocks = flash_kernel.forward_blocks(q, k, v)
+    text = str(jax.make_jaxpr(lambda q, k, v: flash_kernel.flash_forward(
+        q, k, v, True, 0.07, blocks, name=name, window=window))(q, k, v))
+    assert hashlib.sha256(text.encode()).hexdigest()[:16] == digest
+
+
+def test_mosaic_compiles_the_index_kernel_at_the_dsv32_cell_shape(one_chip):
+    """``dsv32-score-32k``'s indexer: 64 heads of 128 over 32,768 tokens in bfloat16, the
+    document's keys and a 16 MB score block in VMEM under the limit the call raises; the
+    program's one output is the packed words, 134 MB, and it has no temporary."""
+    from heat_tpu.core.kernels import sparse_index
+
+    t = 32768
+    q = jax.ShapeDtypeStruct((64, t, 128), jnp.bfloat16, sharding=one_chip)
+    k = jax.ShapeDtypeStruct((t, 128), jnp.bfloat16, sharding=one_chip)
+    w = jax.ShapeDtypeStruct((64, t), jnp.float32, sharding=one_chip)
+    assert sparse_index.decline_reason(q, k, w) is None
+    assert sparse_index._footprint(64, t, 128, 2) + sparse_index._VMEM_MARGIN < 48 * 2 ** 20
+    with jax.default_matmul_precision("highest"):  # the kernel states its products' precision
+        compiled = jax.jit(lambda q, k, w: sparse_index.dsa_index(q, k, w, 2048)).lower(q, k, w).compile()
+    assert "dsa_index_fwd" in compiled.as_text()
+    memory = compiled.memory_analysis()
+    assert memory.output_size_in_bytes == t * 1024 * 4 and memory.temp_size_in_bytes == 0
+
+
+def test_mosaic_compiles_the_masked_flash_forward_at_the_dsv32_cell_shape(one_chip):
+    """One group of 16 heads of ``dsv32-score-32k``'s attention, bf16 [16, 32768, 192] against
+    [.., 128] under the packed words, at the blocks the gate picks with the mask's tile counted
+    and Mosaic's default VMEM scope; the words enter as they are (no copy of them)."""
+    from heat_tpu.core.kernels import sparse_index
+
+    t = 32768
+    q = jax.ShapeDtypeStruct((16, t, 192), jnp.bfloat16, sharding=one_chip)
+    v = jax.ShapeDtypeStruct((16, t, 128), jnp.bfloat16, sharding=one_chip)
+    words = jax.ShapeDtypeStruct((t, sparse_index.mask_words(t)), jnp.int32, sharding=one_chip)
+    blocks = flash_kernel.forward_blocks(q, q, v, True)
+    assert blocks == (1024, 1024) and words.shape == (t, 1024)
+    assert flash_kernel._fwd_footprint(*blocks, 192, 128, 2, with_mask=True) \
+        == flash_kernel._fwd_footprint(*blocks, 192, 128, 2) + 2 * 1024 * 128 * 4
+    compiled = jax.jit(lambda q, k, v, m: flash_kernel.flash_forward(
+        q, k, v, True, 0.07, blocks, name="dsa_flash_fwd", mask=m)).lower(q, q, v, words).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text and "dsa_flash_fwd" in text
+    assert not [line for line in text.splitlines() if " copy(" in line and "s32[32768,1024]" in line]
