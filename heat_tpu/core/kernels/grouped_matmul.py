@@ -34,7 +34,8 @@ stored, float32 accumulation of gate and up, ``silu(gate) * up`` in float32, rou
 the activation type, float32 accumulation of the down product, rounded once. 16-bit
 operands state ``Precision.DEFAULT`` (one MXU pass, whatever the process-wide default
 says: Mosaic refuses them at "highest"), float32 operands ``Precision.HIGHEST``. The
-hidden activation ``(block_rows, h)`` stays in VMEM and never goes through HBM.
+hidden activation ``(block_rows, h)`` (of a slab, where the expert is walked in slabs) stays in
+VMEM and never goes through HBM.
 
 **What bounds a step** (TPU v5e, bfloat16; my chip runs, PR 32): the MXU, then the DMA
 issue. On a buffer gathered beforehand, at ``d`` 2048, ``h`` 1024 a 512-row step as one
@@ -73,13 +74,23 @@ at the three cells' shapes where the kernel takes 3.2, 4.1 and 2.9. The sum walk
 tiles in a rolled loop: unrolled in Python it cost every process 0.4 s more of tracing for
 0.05 to 0.23 ms a layer.
 
-**VMEM.** Whole experts are resident, in both pipeline buffers: at ``d`` 2048, ``h`` 1024
-in bfloat16 (Trinity-Mini) 2 x 12.6 MB of weights, at ``d`` 3584 (Xing4.0) 2 x 22 MB.
+**VMEM.** Whole experts are resident where they fit, in both pipeline buffers: at ``d`` 2048,
+``h`` 1024 in bfloat16 (Trinity-Mini) 2 x 12.6 MB of weights, at ``d`` 3584 (Xing4.0) 2 x 22 MB.
 :func:`_footprint` counts a step's bytes from the shapes, the call raises Mosaic's
 ``vmem_limit_bytes`` to that count plus a margin, and :func:`decline_reason` declines what
-would pass :data:`_VMEM_CAP` of the v5e's 128 MiB before Mosaic does. The combine holds two
-blocks of 1,024 gathered rows (2 x 4 or 2 x 8 MB) and its float32 output block
-(:func:`_combine_footprint`). **One gate** serves both calls: the buffer's layout ties them.
+would pass :data:`_VMEM_CAP` of the v5e's 128 MiB before Mosaic does. **Where the whole expert
+does not fit** (DeepSeek-V3.2: ``d`` 7168, ``h`` 2048, 179 MiB) a step walks the hidden width
+in slabs (PR 38): the grid is (blocks, slabs), gate and up come as ``(1, d, hs)``, down as
+``(1, hs, d)``, and a float32 ``(block_rows, d)`` scratch sums the slabs' down products;
+:func:`_pack` runs on the last slab only, so the one change to the numbers is that float32 sum
+taken slab by slab. Each slab starts its share of the next block's rows. :func:`_slab` picks
+``hs`` from the shapes: all of ``h`` wherever the expert fits, so the other cells trace the
+body they traced before, else ``h`` halved until the step fits (512 at ``d`` 7168 and 256-row
+blocks: 2 x 22 MB of weights, 71 MiB in all). An expert's weights are then read again for
+every block: blocks past the used count repeat the last used block's last slab and fetch
+nothing. The combine holds two blocks of 1,024 gathered rows (2 x 4, 2 x 8 or, at ``d`` 7168,
+2 x 16 MB) and its float32 output block (:func:`_combine_footprint`). **One gate** serves both
+calls: the buffer's layout ties them.
 """
 
 from __future__ import annotations
@@ -129,16 +140,33 @@ def _token_tiles(d: int, itemsize: int) -> Tuple[int, int]:
     return tiles, -(-tiles // 8) * 8
 
 
-def _footprint(d: int, h: int, block_rows: int, x_size: int, w_size: int) -> int:
-    """Bytes of VMEM one grid step holds: the three weight matrices of one expert and the
-    output block of padded 32-bit rows, double-buffered; the two gathered blocks of the same
-    size; and the live tiles of a row chunk: its tokens unpacked, gate and up in
-    float32, the hidden activation, the down product."""
-    br = _row_chunk(block_rows, d, h, w_size)
-    weights = 2 * 3 * d * h * w_size
+def _footprint(d: int, h: int, block_rows: int, x_size: int, w_size: int,
+               hs: Optional[int] = None) -> int:
+    """Bytes of VMEM one grid step holds: a slab of ``hs`` of the hidden width (all ``h`` by
+    default) of one expert's three weight matrices and the output block of padded 32-bit rows,
+    double-buffered; the two gathered blocks of the same size; where ``hs`` is a part of ``h``,
+    the float32 sum of the down product over the block; and the live tiles of a row chunk: its
+    tokens unpacked, gate and up in float32, the hidden activation, the down product."""
+    hs = h if hs is None else hs
+    br = _row_chunk(block_rows, d, hs, w_size)
+    weights = 2 * 3 * d * hs * w_size
     blocks = 2 * 2 * block_rows * _token_tiles(d, x_size)[1] * _LANES * 4
-    tiles = br * (x_size * d + 2 * 4 * h + x_size * h + 4 * d)
-    return weights + blocks + tiles
+    total = 0 if hs == h else block_rows * d * 4
+    tiles = br * (x_size * d + 2 * 4 * hs + x_size * hs + 4 * d)
+    return weights + blocks + total + tiles
+
+
+def _slab(d: int, h: int, block_rows: int, x_size: int, w_size: int) -> int:
+    """The part of the hidden width a grid step multiplies: all of ``h`` wherever the whole
+    expert fits under :data:`_VMEM_CAP`, else ``h`` halved until its slab does, in whole lane
+    tiles; 0 where none does, or where the slabs outnumber the rows of a chunk (each slab
+    starts an equal share of them)."""
+    hs = h
+    while _footprint(d, h, block_rows, x_size, w_size, hs) + _VMEM_MARGIN > _VMEM_CAP:
+        if hs % (2 * _LANES):
+            return 0
+        hs //= 2
+    return 0 if _row_chunk(block_rows, d, hs, w_size) % (h // hs) else hs
 
 
 def available(interpret: bool = False) -> bool:
@@ -163,10 +191,11 @@ def decline_reason(x, rows: int, w_gate, w_down, block_rows: int, top_k: int) ->
             or (_SOURCE_TILE % block_rows and block_rows % _SOURCE_TILE)):
         return (f"tiles: block_rows={block_rows} must be whole sublane tiles of {sublanes}, "
                 f"divide rows={rows}, and divide or be divided by {_SOURCE_TILE}")
-    need = _footprint(d, h, block_rows, x.dtype.itemsize, w_gate.dtype.itemsize)
-    if need + _VMEM_MARGIN > _VMEM_CAP:
+    if not _slab(d, h, block_rows, x.dtype.itemsize, w_gate.dtype.itemsize):
+        need = _footprint(d, h, block_rows, x.dtype.itemsize, w_gate.dtype.itemsize)
         return (f"VMEM: an expert of d={d}, h={h} in {w_gate.dtype} with blocks of {block_rows} "
-                f"rows holds {need >> 20} MiB of {_VMEM_CAP >> 20}")
+                f"rows holds {need >> 20} MiB of {_VMEM_CAP >> 20}, and no slab of its hidden "
+                f"width fits")
     need = _combine_footprint(d, top_k, x.dtype.itemsize)
     if top_k * 8 > _SOURCE_TILE or need + _VMEM_MARGIN > _VMEM_CAP:
         return (f"combine: top_k={top_k} pairs a token of d={d}: a step takes 8 tokens or more, "
@@ -223,7 +252,7 @@ def _halves(words):
 
 
 def _kernel(expert_ref, used_ref, now_ref, next_ref, x_hbm, wg_ref, wu_ref, wd_ref, o_ref,
-            buf, sem, *, br: int, dtype):
+            buf, sem, *total, br: int, dtype, slabs: int = 1):
     import jax.experimental.pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -236,6 +265,8 @@ def _kernel(expert_ref, used_ref, now_ref, next_ref, x_hbm, wg_ref, wu_ref, wd_r
     # 16-bit operands are one MXU pass whatever the process-wide default says (Mosaic
     # refuses them at "highest"); float32 operands multiply exactly, as `contract` does
     precision = lax.Precision.DEFAULT if wg_ref.dtype.itemsize < 4 else lax.Precision.HIGHEST
+    # the hidden width in slabs: grid axis 1 walks them, ``total`` sums the down product
+    j, last = (pl.program_id(1), slabs - 1) if slabs > 1 else (0, 0)
 
     def fetch(src_ref, block, row, to):
         """Start the DMA of buffer row ``row`` of ``block`` into gathered block ``to``."""
@@ -261,25 +292,50 @@ def _kernel(expert_ref, used_ref, now_ref, next_ref, x_hbm, wg_ref, wu_ref, wd_r
         return lax.dot_general(a, b, (((1,), (0,)), ((), ())),
                                preferred_element_type=jnp.float32, precision=precision)
 
-    def chunk(r0):
-        # the next block's rows first, in program order: their DMA starts are scalar work
-        # that the scheduler places under this chunk's products
-        following = jnp.minimum(i + 1, used - 1)
-
-        def one(row, carry):
-            fetch(next_ref, following, r0 + row, 1 - slot)
-            return carry
-
-        lax.fori_loop(0, br, one, 0, unroll=True)  # traced once, unrolled when lowered
-        x = tokens(r0)
-        gate, up = dot(x, wg_ref[0]), dot(x, wu_ref[0])
-        hidden = (gate * jax.nn.sigmoid(gate) * up).astype(x.dtype)
-        # out as it came in: lane tile ``s`` of a row is the row's ``s``-th sublane row
-        words = _pack(dot(hidden, wd_ref[0]), dtype)
+    def out(r0, y):  # out as it came in: lane tile ``s`` of a row is the row's ``s``-th sublane row
+        words = _pack(y, dtype)
         for s in range(tiles):
             o_ref[pl.ds(r0 * padded + s, br, stride=padded), :] = words[:, s * _LANES:(s + 1) * _LANES]
 
-    @pl.when((i == 0) & (used > 0))
+    def chunk(r0):
+        # the next block's rows first, in program order: their DMA starts are scalar work
+        # that the scheduler places under this chunk's products; walked in slabs, each slab
+        # starts its share of the chunk's rows (faster than all of them in the first slab:
+        # PERF.md, PR 38)
+        following = jnp.minimum(i + 1, used - 1)
+        share = br // slabs
+        at = r0 if slabs == 1 else r0 + j * share
+
+        def one(row, carry):
+            fetch(next_ref, following, at + row, 1 - slot)
+            return carry
+
+        lax.fori_loop(0, share, one, 0, unroll=True)  # traced once, unrolled when lowered
+        x = tokens(r0)
+        gate, up = dot(x, wg_ref[0]), dot(x, wu_ref[0])
+        hidden = (gate * jax.nn.sigmoid(gate) * up).astype(x.dtype)
+        y = dot(hidden, wd_ref[0])
+        if slabs == 1:
+            return out(r0, y)
+        part = total[0].at[pl.ds(r0, br), :]
+
+        @pl.when(j == 0)
+        def _open():
+            part[...] = y
+
+        if slabs > 2:
+            @pl.when((j > 0) & (j < last))
+            def _add():
+                part[...] += y
+
+        @pl.when(j == last)
+        def _close():  # the one rounding, on the last slab only
+            out(r0, part[...] + y)
+
+    def on_slab(when, at):  # on one slab of the step only, where there are slabs
+        return when if slabs == 1 else when & (j == at)
+
+    @pl.when(on_slab((i == 0) & (used > 0), 0))
     def _first():  # nothing is in flight yet: the first block's rows, once a call
         def one(row, carry):
             fetch(now_ref, 0, row, 0)
@@ -289,7 +345,10 @@ def _kernel(expert_ref, used_ref, now_ref, next_ref, x_hbm, wg_ref, wu_ref, wd_r
 
     @pl.when(i < used)
     def _block():
-        wait(slot)
+        if slabs == 1:
+            wait(slot)
+        else:  # the block's rows landed before its first slab
+            pl.when(j == 0)(functools.partial(wait, slot))
         if br == rows:
             chunk(0)
         else:  # rolled: the body is compiled once (its size is what the chunk was cut for)
@@ -299,7 +358,7 @@ def _kernel(expert_ref, used_ref, now_ref, next_ref, x_hbm, wg_ref, wu_ref, wd_r
 
             lax.fori_loop(0, rows // br, body, 0)
 
-    @pl.when(i + 1 == used)
+    @pl.when(on_slab(i + 1 == used, last))
     def _drain():  # the last used step fetched its own block again: nothing stays in flight
         wait(1 - slot)
 
@@ -315,8 +374,12 @@ def _grouped_pallas(x, source, w_gate, w_up, w_down, block_expert, used, block_r
     # the framework enables x64 globally; Mosaic only legalizes i32 scalars
     with jax.enable_x64(False):
         (d, h), rows = w_gate.shape[1:], source.shape[0]
+        hs = _slab(d, h, block_rows, x.dtype.itemsize, w_gate.dtype.itemsize)
+        slabs = h // hs
         if diagnostics._enabled:  # trace time only: a trace of the path that took the kernel
             diagnostics.counter("kernels.gmm.fwd")
+            if slabs > 1:
+                diagnostics.counter("kernels.gmm.fwd.slabs")
         # a step sees its own and the next block's sources as one SMEM tile each
         seen = max(_SOURCE_TILE, block_rows)
         source = jnp.pad(source.astype(jnp.int32), (0, -rows % seen))
@@ -325,36 +388,41 @@ def _grouped_pallas(x, source, w_gate, w_up, w_down, block_expert, used, block_r
         def last(used):  # past the used count: the last used block again
             return jnp.maximum(used[0] - 1, 0)
 
-        def out_block(i, expert, used):
-            return jnp.minimum(i, last(used)), 0
+        def spec(shape, index, **kw):
+            """A block of ``shape`` at ``index(block, slab, expert, used)``; on a grid of blocks
+            alone (the whole expert fits) the slab is 0."""
+            if slabs == 1:
+                return pl.BlockSpec(shape, lambda i, expert, used: index(i, 0, expert, used), **kw)
+            return pl.BlockSpec(shape, index, **kw)
 
-        def of_expert(i, expert, used):
-            return expert[i], 0, 0
+        def slab(i, j, used):  # past the used count: the last used block's last slab
+            return j if slabs == 1 else jnp.where(i < used[0], j, slabs - 1)
 
-        need = _footprint(d, h, block_rows, x.dtype.itemsize, w_gate.dtype.itemsize)
+        need = _footprint(d, h, block_rows, x.dtype.itemsize, w_gate.dtype.itemsize, hs)
         padded = _token_tiles(d, x.dtype.itemsize)[1]
         grid_spec = pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,
-            grid=(rows // block_rows,),
+            grid=(rows // block_rows,) if slabs == 1 else (rows // block_rows, slabs),
             in_specs=[
-                pl.BlockSpec((seen,), lambda i, e, u: (jnp.minimum(i, last(u)) // per,),
-                             memory_space=pltpu.SMEM),
-                pl.BlockSpec((seen,), lambda i, e, u: (jnp.minimum(i + 1, last(u)) // per,),
-                             memory_space=pltpu.SMEM),
+                spec((seen,), lambda i, j, e, u: (jnp.minimum(i, last(u)) // per,),
+                     memory_space=pltpu.SMEM),
+                spec((seen,), lambda i, j, e, u: (jnp.minimum(i + 1, last(u)) // per,),
+                     memory_space=pltpu.SMEM),
                 pl.BlockSpec(memory_space=pl.ANY),  # the tokens stay in HBM: rows come by DMA
-                pl.BlockSpec((1, d, h), of_expert),
-                pl.BlockSpec((1, d, h), of_expert),
-                pl.BlockSpec((1, h, d), of_expert),
+                spec((1, d, hs), lambda i, j, e, u: (e[i], 0, slab(i, j, u))),
+                spec((1, d, hs), lambda i, j, e, u: (e[i], 0, slab(i, j, u))),
+                spec((1, hs, d), lambda i, j, e, u: (e[i], slab(i, j, u), 0)),
             ],
-            out_specs=pl.BlockSpec((block_rows * padded, _LANES), out_block),
+            out_specs=spec((block_rows * padded, _LANES),
+                           lambda i, j, e, u: (jnp.minimum(i, last(u)), 0)),
             scratch_shapes=[
                 pltpu.VMEM((2, block_rows * padded, _LANES), jnp.uint32),
                 pltpu.SemaphoreType.DMA((2,)),
-            ],
+            ] + ([pltpu.VMEM((block_rows, d), jnp.float32)] if slabs > 1 else []),
         )
         return pl.pallas_call(
-            functools.partial(_kernel, dtype=x.dtype,
-                              br=sub or _row_chunk(block_rows, d, h, w_gate.dtype.itemsize)),
+            functools.partial(_kernel, dtype=x.dtype, slabs=slabs,
+                              br=sub or _row_chunk(block_rows, d, hs, w_gate.dtype.itemsize)),
             grid_spec=grid_spec,
             out_shape=jax.ShapeDtypeStruct((rows * padded, _LANES), jnp.uint32),
             interpret=interpret,
@@ -362,7 +430,7 @@ def _grouped_pallas(x, source, w_gate, w_up, w_down, block_expert, used, block_r
             # its neighbour in the sweep is the same expert's, and its rows were fetched by
             # the step before
             compiler_params=None if interpret else pltpu.CompilerParams(
-                dimension_semantics=("arbitrary",),
+                dimension_semantics=("arbitrary",) * (1 if slabs == 1 else 2),
                 vmem_limit_bytes=need + _VMEM_MARGIN),  # under _VMEM_CAP by the gate
             name="moe_grouped_fwd",
         )(block_expert, used, source, source, _words(x), w_gate, w_up, w_down)

@@ -39,7 +39,7 @@ from jax import lax
 
 from ..core import diagnostics
 from .attention import MultiheadLatentAttention
-from .ling import BLOCK_ROWS, NORM_INIT_STD, LingBlock
+from .ling import NORM_INIT_STD, LingBlock
 from .modules import GatedMLP, Module, RMSNorm, normal_weight
 from .moe import MoE
 from .scoring import ScoringForward, score
@@ -49,6 +49,9 @@ __all__ = ["DeepseekV32", "DeepseekV32Block", "DeepseekV32Config", "DeepseekV32S
 # every layer's selection is returned for every 64th query and for the positions that score:
 # what a comparison with a reference reads, 2.6 MB a layer at 32,768 tokens and not 134
 SELECTION_STRIDE = 64
+# rows a block of an expert layer's sorted buffer holds: an expert of 7,168 x 2,048 is walked in
+# slabs of its hidden width, so its 88 MB of weights are read again for every block (PERF.md, PR 38)
+BLOCK_ROWS = 256
 
 
 @dataclasses.dataclass(frozen=True)
@@ -175,9 +178,9 @@ class DeepseekV32(ScoringForward):
     ``continuation`` is the number of trailing tokens that are scored; ``experts_held =
     (first, count)`` is the share of every expert layer that lives here (all by default, see
     :class:`~.moe.MoE`); ``block_rows`` is the block every held expert's group of rows is
-    padded to; ``head_groups`` and ``ffn_pieces`` cut the attention's heads and the
-    feed-forward's tokens into parts that run one after another (the results are the uncut
-    ones; see the module's text). Parameters are stored in ``dtype`` (norms and router
+    padded to (:data:`BLOCK_ROWS` by default); ``head_groups`` and ``ffn_pieces`` cut the
+    attention's heads and the feed-forward's tokens into parts that run one after another (the
+    results are the uncut ones; see the module's text). Parameters are stored in ``dtype`` (norms and router
     float32) and activations follow it.
     """
 

@@ -50,7 +50,7 @@ in the 32-bit words the grouped kernel reads its input in, so a row is one align
 step fetches the rows of the next 1,024 pairs, **those held here only**, and sums the current
 ones rank by rank in float32 from VMEM, so neither a gathered row a pair nor a ``(k, T, d)``
 intermediate goes through HBM. Where the kernels' one gate declines (another backend, another
-type, widths or ``block_rows`` off the tiles, an expert too large for VMEM) the same blocks
+type, widths or ``block_rows`` off the tiles, no slab of an expert fits VMEM) the same blocks
 go through a ``jnp`` loop over the held experts and the combine is a gather and a sum, and
 ``record_fallback("nn.moe", why)`` says why. Either way a pair held elsewhere contributes
 exactly 0, by a select and never by a product with 0.

@@ -277,6 +277,47 @@ def test_one_trace_for_repeated_calls_and_the_counters():
         model.layers[0].attn.apply(params["layers"][0]["attn"], jnp.zeros((2, T, D)))
 
 
+def test_expert_layers_through_the_interpreted_kernels_walked_in_slabs(monkeypatch):
+    """The expert layers through ``moe_grouped_fwd`` and ``moe_combine_fwd``, interpreted, with
+    VMEM cut to what the combine holds, so that an expert of 128 x 4096 is walked in two slabs of
+    its hidden width, as the cell's 7168 x 2048 is in four on the chip: the forward is the
+    ``jnp`` loop's within the file's float32 tolerance, routes and selections equal; the first
+    call takes the kernels in both layers (``fallback.nn.moe`` 0) through one trace of the slab
+    walk."""
+    from heat_tpu.core.kernels import grouped_matmul
+
+    cfg = dict(CFG, hidden_size=128, moe_intermediate_size=4096)
+    model = model_of(jnp.float32, cfg)
+    params = model.params = model.init(jax.random.key(21))
+    tokens = jax.random.randint(jax.random.key(22), (T,), 0, cfg["vocab_size"], jnp.int32)
+    loop = of_program(model(tokens))  # on the CPU the gate declines: the jnp loop
+    need = grouped_matmul._combine_footprint(128, 4, 4)
+    monkeypatch.setattr(grouped_matmul, "_VMEM_CAP", need + grouped_matmul._VMEM_MARGIN)
+    assert grouped_matmul._slab(128, 4096, 16, 4, 4) == 2048
+    monkeypatch.setattr(grouped_matmul, "available", lambda interpret=False: True)
+    for name in ("grouped_gated_silu", "combine"):
+        monkeypatch.setattr(grouped_matmul, name,
+                            functools.partial(getattr(grouped_matmul, name), interpret=True))
+    grouped_matmul._grouped_pallas.clear_cache()  # its traces read the cap
+    model = model_of(jnp.float32, cfg)
+    model.params = params
+    diagnostics.enable()
+    try:
+        diagnostics.reset()
+        kernels = of_program(model(tokens))
+        counters = diagnostics.report()["counters"]
+        assert counters.get("fallback.nn.moe", 0) == 0
+        assert counters["kernels.gmm.fwd.slabs"] == 1 and counters["kernels.gmm.combine"] == 1
+    finally:
+        diagnostics.disable()
+        diagnostics.reset()
+        grouped_matmul._grouped_pallas.clear_cache()
+    assert gap(kernels["logits"], loop["logits"]) < 1e-5
+    for name in ("routes", "selections"):
+        for got, want in zip(kernels[name], loop[name]):
+            assert np.array_equal(np.asarray(got), np.asarray(want))
+
+
 def test_dtypes_are_pinned_under_x64():
     """The framework enables x64 globally; nothing here may widen to float64 / int64."""
     model = model_of(jnp.bfloat16)

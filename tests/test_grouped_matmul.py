@@ -322,7 +322,8 @@ REASONS = {
     "block_rows": (dict(block_rows=8), "block_rows=8"),
     "ragged": (dict(rows=ROWS * 3, block_rows=32), "divide rows"),
     "source_tile": (dict(rows=96 * 4, block_rows=96), "1024"),
-    "vmem": (dict(d=8192, h=4096, block_rows=512, rows=1024), "VMEM"),
+    # the two gathered blocks alone pass the cap, whatever slab of the hidden width a step takes
+    "vmem": (dict(d=16384, h=4096, block_rows=1024, rows=2048), "no slab of its hidden width fits"),
     "top_k": (dict(top_k=256), "combine: top_k=256"),
     "rows": (dict(rows=2**21), "pass 32 bits"),
 }
@@ -339,22 +340,93 @@ def test_the_gate_says_why(case):
     assert said in grouped_matmul.decline_reason(x, rows, w_gate, w_down, block_rows, kw.get("top_k", 2))
 
 
-@pytest.mark.parametrize("d,h,e,k,b", [(2048, 1024, 128, 8, 512), (3584, 1024, 64, 4, 512),
-                                       (2560, 768, 128, 8, 128)], ids=["trinity", "xing4", "ling"])
-def test_the_gate_admits_the_cells(d, h, e, k, b):
-    """Whole experts resident in both pipeline buffers, under the cap, at the three widths the
-    benchmark runs, and the combine's two gathered blocks of 1,024 pairs beside its output;
-    float32 streams of Xing4's width are past it and say so."""
+@pytest.mark.parametrize("d,h,e,k,b,hs,hs32,combine_mib", [
+    (2048, 1024, 128, 8, 512, 1024, 1024, 32), (3584, 1024, 64, 4, 512, 1024, 512, 32),
+    (2560, 768, 128, 8, 128, 768, 768, 32), (7168, 2048, 16, 8, 256, 512, 256, 48)],
+    ids=["trinity", "xing4", "ling", "dsv32"])
+def test_the_gate_admits_the_cells(d, h, e, k, b, hs, hs32, combine_mib):
+    """A slab of every expert resident in both pipeline buffers, under the cap, at the four widths
+    the benchmark runs: the whole expert where it fits (Trinity, Xing4, Ling), a quarter of
+    DeepSeek-V3.2's hidden width at its block rows; and the combine's two gathered blocks of
+    1,024 pairs beside its output. Float32 streams are admitted in slabs too: of Xing4's width in
+    halves (declined before the slab plan), of DeepSeek's in eighths."""
+    from heat_tpu.nn.deepseek_v32 import BLOCK_ROWS
+
+    assert d != 7168 or b == BLOCK_ROWS
     x = jax.ShapeDtypeStruct((32768, d), jnp.bfloat16)
     w_gate, w_down = (jax.ShapeDtypeStruct(s, jnp.bfloat16) for s in ((e, d, h), (e, h, d)))
     assert grouped_matmul.decline_reason(x, 4096, w_gate, w_down, b, k) is None
-    need = grouped_matmul._footprint(d, h, b, 2, 2)
-    assert 2 * 3 * d * h * 2 < need < grouped_matmul._VMEM_CAP - grouped_matmul._VMEM_MARGIN
+    assert grouped_matmul._slab(d, h, b, 2, 2) == hs
+    need = grouped_matmul._footprint(d, h, b, 2, 2, hs)
+    assert 2 * 3 * d * hs * 2 < need < grouped_matmul._VMEM_CAP - grouped_matmul._VMEM_MARGIN
     assert grouped_matmul._combine_blocks(k) == (1024 // k, 128 // k)
     rows_held = 2 * 1024 * grouped_matmul._token_tiles(d, 2)[1] * 512
-    assert rows_held < grouped_matmul._combine_footprint(d, k, 2) < 32 * 2**20
+    assert rows_held < grouped_matmul._combine_footprint(d, k, 2) < combine_mib * 2**20
     x32, g32, d32 = (jax.ShapeDtypeStruct(a.shape, jnp.float32) for a in (x, w_gate, w_down))
-    assert ("VMEM" in (grouped_matmul.decline_reason(x32, 4096, g32, d32, b, k) or "")) == (d == 3584)
+    assert grouped_matmul.decline_reason(x32, 4096, g32, d32, b, k) is None
+    assert grouped_matmul._slab(d, h, b, 4, 4) == hs32
+
+
+@pytest.fixture
+def slabs_forced(monkeypatch):
+    """``slabs(d, h, block_rows, size, n)`` cuts :data:`_VMEM_CAP` to what a step walking ``h`` in
+    ``n`` slabs holds, so that the gate picks that walk at a toy shape. The jitted wrapper's traces
+    read the cap when traced: they are dropped before and after."""
+    grouped_matmul._grouped_pallas.clear_cache()
+
+    def slabs(d, h, block_rows, size, n):
+        need = grouped_matmul._footprint(d, h, block_rows, size, size, h // n)
+        monkeypatch.setattr(grouped_matmul, "_VMEM_CAP", need + grouped_matmul._VMEM_MARGIN)
+        assert grouped_matmul._slab(d, h, block_rows, size, size) == h // n
+
+    yield slabs
+    grouped_matmul._grouped_pallas.clear_cache()
+
+
+SLAB_MAPS = {"expert_change_and_unused_tail": [2, 0, 1, 0], "one_used_block": [0, 1, 0, 0]}
+
+
+@pytest.mark.parametrize("dtype", list(DTYPES))
+@pytest.mark.parametrize("n", [2, 4])
+@pytest.mark.parametrize("case", list(SLAB_MAPS))
+def test_the_slab_walk_equals_gated_silu(slabs_forced, case, n, dtype):
+    """Where a whole expert does not fit, a step walks the hidden width in ``n`` slabs (grid axis
+    1) and sums the down product in float32 slab by slab before its one rounding: every used
+    block equals ``gated_silu`` of its rows within float32's rounding of that sum (bfloat16: the
+    same but for an element on a rounding edge), across an expert change between blocks and for a
+    single used block; the unused blocks past it repeat the last used block's last slab and write
+    nothing that a used block's rows depend on. The trace counts ``kernels.gmm.fwd.slabs``."""
+    h, kind = 4 * H, DTYPES[dtype]
+    slabs_forced(D, h, ROWS, jnp.dtype(kind).itemsize, n)
+    expert, used = grouped_matmul.block_map(jnp.asarray(SLAB_MAPS[case], jnp.int32), 6)
+    k1, k2, k3, k4, k5 = jax.random.split(jax.random.key(n), 5)
+    x = jax.random.normal(k1, (40, D), jnp.float32).astype(kind)
+    source = jax.random.randint(k5, (6 * ROWS,), 0, 40, jnp.int32)
+    w = [(jax.random.normal(k, s, jnp.float32) * 0.1).astype(kind)
+         for k, s in ((k2, (4, D, h)), (k3, (4, D, h)), (k4, (4, h, D)))]
+    was_on = ht.diagnostics.enabled()
+    ht.diagnostics.enable()
+    ht.diagnostics.reset()
+    try:
+        ys = _rows_of(_KERNEL(x, source, *w, expert, used, ROWS, interpret=True), D, kind)
+        assert _counter("kernels.gmm.fwd") == 1 and _counter("kernels.gmm.fwd.slabs") == 1
+    finally:
+        ht.diagnostics.reset()
+        if not was_on:
+            ht.diagnostics.disable()
+    in_use = int(used[0])
+    assert in_use == sum(SLAB_MAPS[case])
+    for j, e in enumerate(np.asarray(expert)[:in_use]):
+        rows = slice(j * ROWS, (j + 1) * ROWS)
+        want = ht.nn.modules.gated_silu(x[source[rows]], w[0][e], w[1][e], w[2][e])
+        if dtype == "float32":
+            assert gap(ys[rows], want) < 1e-6
+        else:
+            assert gap(ys[rows], want) < 1e-3 and np.mean(np.asarray(ys[rows] != want)) < 1e-2
+    moved = _rows_of(_KERNEL(x, source.at[in_use * ROWS:].set(7), *w, expert, used, ROWS,
+                             interpret=True), D, kind)
+    assert np.array_equal(np.asarray(ys[:in_use * ROWS], np.float32),
+                          np.asarray(moved[:in_use * ROWS], np.float32))
 
 
 def test_the_counters_count_traces_not_calls(interpreted):

@@ -519,6 +519,50 @@ def test_mosaic_compiles_the_expert_layer_at_the_ling_cell_shape(one_chip, monke
     _the_buffer_is_words_and_the_combine_a_kernel(text, rows, 2560, 8)
 
 
+@pytest.mark.parametrize("d,h,experts,top_k,block_rows,digest", [
+    (2048, 1024, 128, 8, 512, "06fc07abadceb9df"), (3584, 1024, 64, 4, 512, "5ba58f62f9329acd"),
+    (2560, 768, 128, 8, 128, "407b3c04177124c5")], ids=["trinity", "xing4", "ling"])
+def test_the_grouped_kernel_traces_what_it_traced_before_the_slabs(d, h, experts, top_k, block_rows,
+                                                                   digest):
+    """Where the whole expert fits VMEM a step takes all of its hidden width: the jaxpr of the
+    grouped kernel at each accepted cell's shape (32,768 tokens in bfloat16) is, character for
+    character, what the commit before PR 38 traced (the digests were taken on that commit)."""
+    import hashlib
+
+    rows = 32768 * top_k + experts * block_rows
+    shapes = [((32768, d), jnp.bfloat16), ((rows,), jnp.int32), ((experts, d, h), jnp.bfloat16),
+              ((experts, d, h), jnp.bfloat16), ((experts, h, d), jnp.bfloat16),
+              ((rows // block_rows,), jnp.int32), ((1,), jnp.int32)]
+    assert grouped_matmul._slab(d, h, block_rows, 2, 2) == h
+    text = str(jax.make_jaxpr(functools.partial(grouped_matmul.grouped_gated_silu,
+                                                block_rows=block_rows))(
+        *(jax.ShapeDtypeStruct(*shape) for shape in shapes)))
+    assert hashlib.sha256(text.encode()).hexdigest()[:16] == digest
+
+
+def test_mosaic_compiles_the_expert_layer_at_the_dsv32_cell_shape(one_chip, monkeypatch):
+    """One piece of ``dsv32-score-32k``'s expert layer: 8,192 tokens, 16 of 256 experts of 7168 x
+    2048 held, top-8 in 8 groups of which 4 stay, at the model's block rows: the grouped kernel
+    walks a quarter of the hidden width a step (grid axis 1) under a VMEM limit Mosaic accepts, the
+    buffer is 32-bit words (a row of 7168 is 32 sublane rows, 16 KB) and the combine is the second
+    kernel: the layer takes both kernels and no loop."""
+    from heat_tpu.nn.deepseek_v32 import BLOCK_ROWS
+
+    monkeypatch.setattr(grouped_matmul, "available", lambda interpret=False: True)
+    m = ht.nn.MoE(7168, 2048, 256, 8, 1, 2.5, (0, 16), BLOCK_ROWS, jnp.bfloat16, 8, 4)
+    assert grouped_matmul._slab(7168, 2048, BLOCK_ROWS, 2, 2) == 512
+
+    def placed(a):
+        return jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip)
+
+    params = jax.tree_util.tree_map(placed, jax.eval_shape(m.init, jax.random.key(0)))
+    x = jax.ShapeDtypeStruct((8192, 7168), jnp.bfloat16, sharding=one_chip)
+    text = jax.jit(lambda p, x: m.apply(p, x)).lower(params, x).compile().as_text()
+    assert "tpu_custom_call" in text and "moe_grouped_fwd" in text
+    _the_buffer_is_words_and_the_combine_a_kernel(text, 8192 * 8 + 16 * BLOCK_ROWS, 7168, 8, 8192)
+    assert not [line for line in text.splitlines() if " while(" in line]
+
+
 # ------------------------- the index kernel and the flash forward under its packed mask (PR 37)
 def _index_inputs(h, t, d, case, dtype, seed=0):
     """``q`` (H, T, D), ``k`` (T, D), ``w`` (H, T). ``ties``: small integers and signed powers
