@@ -6,10 +6,15 @@ For one head, with a state ``S`` (d_k, d_v) in float32 that starts at 0::
     S_t = (I - beta_t k_t k_t^T) Diag(exp(g_t)) S_{t-1} + beta_t k_t v_t^T
     o_t = S_t^T q_t
 
-``g_t`` (d_k,) is the log-decay of every channel, ``-5 <= g_t <= 0`` (the bound is
-:data:`LOG_DECAY_BOUND`, ``kda_lower_bound`` of the published configurations), ``beta_t`` a
-scalar in (0, 1), ``|k_t| = 1``. :func:`chunk_step` takes ``k`` and the two products
-``beta k`` and ``beta v``, rounded to the operands' type.
+``g_t`` (d_k,) is the log-decay of every channel, ``g_t <= 0``, ``beta_t`` a scalar in (0, 1),
+``|k_t| = 1``. :func:`chunk_step` takes ``k`` and the two products ``beta k`` and ``beta v``,
+rounded to the operands' type.
+
+**Two kinds of decay.** The *bounded* kind (Ling's ``kda_safe_gate``) is ``g = bound *
+sigmoid(rate * pre)``, ``bound <= g <= 0``; the *softplus* kind (fla's original gate, which
+Kimi-Linear ships) is ``g = -rate * softplus(pre)``, with no bound below. ``pre`` is the
+float32 pre-activation a channel, ``rate`` = ``exp(A_log)`` a head laid over its channels;
+either is computed in float32 inside the step.
 
 **The chunked (WY) form.** Over a chunk of ``C`` = :data:`CHUNK` positions that starts at
 state ``S_0``, with ``G_r = g_1 + .. + g_r`` (float32, inside the chunk)::
@@ -21,17 +26,31 @@ state ``S_0``, with ``G_r = g_1 + .. + g_r`` (float32, inside the chunk)::
     O         = (Q * exp(G)) S_0  +  Aqk U
     S_C       = Diag(exp(G_C)) S_0  +  (K * exp(G_C - G))^T U
 
-``exp(-G)`` **is never taken over more than** :data:`SUB` **positions**: a chunk is cut
-into sub-chunks of 16 rows, a row block ``J`` takes its reference ``b_J`` = ``G`` just
-before the block, its rows carry ``exp(G_r - b_J + 40)`` and the columns
-``exp(min(b_J - G_i - 40, 40))``: a sub-chunk's range of ``exp(16 * 5) = exp(80) < 3.4e38``
-is shared between the two factors, so neither leaves float32 (a row factor lies in
-``exp(+-40)``; a column factor underflows only where the pair's weight is under
-``exp(-47)``); right of the block the clamp holds and the triangle's select drops the
-entry. ``T`` is exact arithmetic on nilpotent float32 matrices: the 16 x 16 diagonal blocks
-by ``(I + X)^-1 = (I - X)(I + X^2)(I + X^4)(I + X^8)`` (powers of a 16-row block stay small:
-no cancellation), then the 4 x 4 block structure the same way, ten products of 64 x 64 in
-all. ``T`` is then rounded to the operands' type, so its products follow that type
+``exp(-G)`` **is never taken over more than one sub-chunk**: a chunk is cut into sub-chunks
+of ``sub`` rows, a row block ``J`` takes its reference ``b_J`` = ``G`` just before the block,
+its rows carry ``exp(G_r - b_J + half)`` and the columns ``exp(min(b_J - G_i - half,
+half))``, where ``2 half`` is the largest log-decay a sub-chunk can span: that range is
+shared between the two factors, so neither leaves float32 (a row factor lies in
+``exp(+-half)``; a column factor underflows only where the pair's weight is under
+``exp(half - 87)``); right of the block the clamp holds and the triangle's select drops the
+entry. Two forms, chosen by the decay (static):
+
+- *narrow*, the bounded kind with ``bound >= ``:data:`LOG_DECAY_BOUND` (-5): sub-chunks of
+  :data:`SUB` = 16 rows, ``half`` = 16 * 5 / 2 = 40; the Pallas call ``kda_chunk_fwd``.
+- *wide*, the softplus kind or a wider bound: every step's log-decay is first floored at
+  :data:`FLOOR` = -17. That is exact to float32: a pair whose positions a floored step lies
+  between weighs less than ``exp(-17) < 2^-24`` before and after, and no other pair's weight
+  moves, so every weight ``exp(G_r - G_i)`` of at least ``2^-24`` is the unfloored one (the
+  state's ``exp(G_r)`` and ``exp(G_C)`` likewise). Sub-chunks of :data:`WIDE_SUB` = 8 rows
+  then span at most 136, ``half`` = 68. Entries of the sub-chunk that lie right of the
+  diagonal may overflow to ``inf`` in this form; the triangle's select drops them, and no
+  product reads them. The Pallas call ``kda_unbounded_fwd``.
+
+``T`` is exact arithmetic on nilpotent float32 matrices: the ``sub`` x ``sub`` diagonal
+blocks by ``(I + X)^-1 = (I - X)(I + X^2)(I + X^4)..`` (powers of a sub-chunk's block stay
+small: no cancellation), then the ``C / sub`` block structure the same way (ten products of
+64 x 64 in all for the narrow form, eight for the wide). ``T`` is then rounded to the
+operands' type, so its products follow that type
 (:func:`_product`): for float32 operands ``Precision.HIGHEST``; for bfloat16 operands each
 factor as two bfloat16 pieces and three products, 2^-17 of the result, at a third of the
 MXU passes and none of the splits and sums that six passes bring. Every other product takes
@@ -46,10 +65,13 @@ Attention puts before and after it, so that q, k, v, g and o never go through HB
 own: from the three projections as stored, the causal depthwise convolution (its taps on
 the :data:`BEFORE` rows before the chunk too), SiLU, the L2 norm of q and k a head and
 ``beta``, and the log-decay from its float32 pre-activation; after it the head's RMS norm
-and its sigmoid gate.
+and its sigmoid gate. **Two widths of gate**: one a head (Ling's ``head_wise``), which rides
+beside ``beta``, or one a channel (fla's ``FusedRMSNormGated``, Kimi-Linear's), whose
+float32 pre-activation comes in as a ``(T, H d)`` operand beside ``pre``, chunk by chunk.
 
-**The call.** ``xq, xk, xv`` and the decay's pre-activation (T, H d) lie as the projections
-leave them, heads side by side on the lanes, so nothing is transposed on the way in or out.
+**The call.** ``xq, xk, xv``, the decay's pre-activation and a channel gate's (T, H d) lie as
+the projections leave them, heads side by side on the lanes, so nothing is transposed on the
+way in or out.
 The grid is (head groups, chunks): a step takes one chunk of :data:`HEADS` heads, which
 ride a leading axis through :func:`chunk_step` so that every product is issued for all of
 them at once: a head's inverse alone is a chain of eight dependent 64 x 64 products, each a
@@ -58,12 +80,12 @@ heads over 32,768 positions on a v5e for four heads a step, 41.9 for one; on a l
 18.7 for four, 15.2 for eight, 14.4 for sixteen: my chip runs, PR 33). Each head's state
 (kept as ``S^T``, so a channel's decay is a lane's) stays in VMEM over the chunk axis, which
 is sequential. The rows before a chunk are a second, 16-row view of the same three arrays.
-The call is named ``kda_chunk_fwd`` in a device trace.
 
 :func:`kda_mix_reference` is the same chunk step on all heads at once under ``lax.scan``
 over the chunks, in plain ``jnp``: the fallback of ``nn/kda.py`` on other backends and
 shapes (it pads a sequence that does not tile), and what the tests hold the interpreted
-kernel to, beside the token-by-token recurrence of ``tests/reference_ling.py``.
+kernel to, beside the token-by-token recurrences of ``tests/reference_ling.py`` and
+``tests/reference_kimi_linear.py``.
 
 No reference counterpart.
 """
@@ -79,8 +101,9 @@ from jax import lax
 
 from .. import diagnostics
 
-__all__ = ["CHUNK", "SUB", "BEFORE", "LOG_DECAY_BOUND", "chunk_step", "short_conv", "head_chunk",
-           "kda_mix", "kda_mix_reference", "available", "decline_reason"]
+__all__ = ["CHUNK", "SUB", "WIDE_SUB", "BEFORE", "LOG_DECAY_BOUND", "FLOOR", "chunk_step",
+           "short_conv", "head_chunk", "kda_mix", "kda_mix_reference", "available",
+           "decline_reason"]
 
 CHUNK = 64
 SUB = 16
@@ -89,6 +112,9 @@ LOG_DECAY_BOUND = -5.0  # SUB * 5 = 80 < 88: exp stays inside float32 over a sub
 HEADS = 8  # heads a grid step takes together
 _LANES = 128
 _HALF = -LOG_DECAY_BOUND * SUB / 2  # 40: half of a sub-chunk's range of log-decay
+FLOOR = -17.0  # the wide form's floor on a step's log-decay: exp(-17) < 2^-24
+WIDE_SUB = 8
+_WIDE_HALF = -FLOOR * WIDE_SUB / 2  # 68: half of a wide sub-chunk's range of log-decay
 _L2_EPS = 1e-6
 _F32 = jnp.float32
 
@@ -143,13 +169,15 @@ def _inverse_of_one_plus(x, nilpotency: int, exact: bool):
     return inv
 
 
-def chunk_step(q, k, kb, vb, g, st, sub: int = SUB):
+def chunk_step(q, k, kb, vb, g, st, sub: int = SUB, half: float = _HALF):
     """One chunk of ``n`` heads, every line a head's own: the heads ride a leading axis so
     that each product is issued for all of them before the next one that waits for it
     (their chains are independent, a chain's products are not). ``q, k, kb`` (n, C, d_k) and
     ``vb`` (n, C, d_v) in the operands' type, ``g`` (n, C, d_k) float32, ``st`` the states
     transposed, (n, d_v, d_k) float32. Returns ``(o (n, C, d_v) float32, st)``. ``C`` is a
-    whole number of sub-chunks of ``sub`` rows."""
+    whole number of sub-chunks of ``sub`` rows, over which ``g`` sums to no less than ``-2
+    half``: the narrow form's defaults, or :data:`WIDE_SUB` and ``_WIDE_HALF`` on log-decays
+    floored at :data:`FLOOR`."""
     n, c, d_k = k.shape
     op = q.dtype
     row = lax.broadcasted_iota(jnp.int32, (c, c), 0)
@@ -164,13 +192,13 @@ def chunk_step(q, k, kb, vb, g, st, sub: int = SUB):
     local, before = sums[:, :c], sums[:, c:]
     total = before + local
     kf = k.astype(_F32)
-    # a row's and a column's factor share the sub-chunk's range of exp(+-80) between them
-    lift = jnp.exp(local + _HALF)
+    # a row's and a column's factor share the sub-chunk's range of exp(+-2 half) between them
+    lift = jnp.exp(local + half)
     rows_k, rows_q = kb.astype(_F32) * lift, q.astype(_F32) * lift
     a_parts, qk_parts = [], []
     for j in range(c // sub):
         lo = j * sub
-        columns = (kf * jnp.exp(jnp.minimum(before[:, lo:lo + 1] - total - _HALF, _HALF))
+        columns = (kf * jnp.exp(jnp.minimum(before[:, lo:lo + 1] - total - half, half))
                    ).astype(op)
         rows = jnp.concatenate([rows_k[:, lo:lo + sub], rows_q[:, lo:lo + sub]], axis=1)
         s = _dot(rows.astype(op), columns, _NT)
@@ -207,24 +235,43 @@ def short_conv(x, rows, w):
     return y * jax.nn.sigmoid(y)
 
 
-def head_chunk(xq, xk, xv, before, taps, pre, rate, side, norm_w, st, bound: float, eps: float):
+def _wide(bound: Optional[float]) -> bool:
+    """Whether a decay of this ``bound`` (None: the softplus kind) takes the wide form."""
+    return bound is None or bound < LOG_DECAY_BOUND
+
+
+def _softplus(x):
+    """``log(1 + exp(x))`` in float32, as Mosaic lowers it: no overflow for large ``x``."""
+    return jnp.maximum(x, 0.0) + jnp.log1p(jnp.exp(-jnp.abs(x)))
+
+
+def head_chunk(xq, xk, xv, before, taps, pre, rate, side, norm_w, st, bound: Optional[float],
+               eps: float, gate=None):
     """One chunk of ``n`` heads from the projections to the gated, normed output. ``xq, xk,
     xv`` (n, C, d) as stored; ``before``: the three arrays' :data:`BEFORE` rows before the
     chunk (zeros at the document's start); ``taps``: three (n, width, d) float32; ``pre`` (n,
-    C, d) and ``rate`` (n, 1, d) float32: the log-decay is ``bound * sigmoid(rate * pre)``;
-    ``side`` (n, C, 2) float32: beta and the gate's sigmoid; ``norm_w`` (1, d) float32;
-    ``st`` (n, d, d) float32. Returns ``(y (n, C, d) float32, st)``."""
+    C, d) and ``rate`` (n, 1, d) float32: the log-decay is ``bound * sigmoid(rate * pre)``,
+    or with ``bound`` None ``-rate * softplus(pre)``; ``side`` (n, C, 2) float32: beta and the
+    head gate's sigmoid, or (n, C, 1), beta alone, where ``gate`` (n, C, d) float32 is the
+    pre-activation of a gate a channel; ``norm_w`` (1, d) float32; ``st`` (n, d, d) float32.
+    Returns ``(y (n, C, d) float32, st)``."""
     d, op = xq.shape[2], xq.dtype
-    g = bound * jax.nn.sigmoid(rate * pre)
+    if bound is None:
+        g = -rate * _softplus(pre)
+    else:
+        g = bound * jax.nn.sigmoid(rate * pre)
+    form = (SUB, _HALF)
+    if _wide(bound):  # a floor where nothing of float32 is lost: see the module's text
+        g, form = jnp.maximum(g, FLOOR), (WIDE_SUB, _WIDE_HALF)
 
     def unit(y):
         return y * lax.rsqrt(jnp.sum(y * y, axis=2, keepdims=True) + _L2_EPS)
 
     q, k, v = (short_conv(x, rows, w) for x, rows, w in zip((xq, xk, xv), before, taps))
     q, k = unit(q) * d ** -0.5, unit(k)
-    beta, gate = side[:, :, 0:1], side[:, :, 1:2]
+    beta, gate = side[:, :, 0:1], side[:, :, 1:2] if gate is None else jax.nn.sigmoid(gate)
     o, st = chunk_step(q.astype(op), k.astype(op), (beta * k).astype(op), (beta * v).astype(op),
-                       g, st)
+                       g, st, *form)
     o = o * lax.rsqrt(jnp.mean(o * o, axis=2, keepdims=True) + eps) * norm_w
     return o * gate, st
 
@@ -234,7 +281,7 @@ def _taps32(taps):
 
 
 def kda_mix_reference(xq, xk, xv, taps, pre, rate, beta, gate, norm_w, heads: int,
-                      bound: float, eps: float):
+                      bound: Optional[float], eps: float):
     """The chunked form in plain ``jnp``: operands as :func:`kda_mix` takes them. A sequence
     that is no whole number of chunks is padded with positions after its end, which nothing
     before them reads, and cut again."""
@@ -249,17 +296,21 @@ def kda_mix_reference(xq, xk, xv, taps, pre, rate, beta, gate, norm_w, heads: in
     # the rows before chunk j are the last of chunk j - 1; zeros before the first
     rows = [jnp.concatenate([jnp.zeros_like(x[:1, :, :BEFORE]), x[:-1, :, CHUNK - BEFORE:]])
             for x in xs]
-    side = by_chunk(jnp.stack([beta, gate], axis=-1).astype(_F32).reshape(t, 2 * heads), 2)
+    if gate.shape[1] == heads:
+        side, channel = by_chunk(jnp.stack([beta, gate], axis=-1).astype(_F32)
+                                 .reshape(t, 2 * heads), 2), ()
+    else:  # one gate a channel: beta alone beside the decay, the gate chunk by chunk too
+        side, channel = by_chunk(beta.astype(_F32), 1), (by_chunk(gate.astype(_F32), d),)
     taps = tuple(jnp.moveaxis(w.reshape(-1, heads, d), 1, 0) for w in _taps32(taps))
     rate, norm_w = rate.astype(_F32).reshape(heads, 1, d), norm_w.astype(_F32).reshape(1, d)
 
     def one(st, chunk):
         y, st = head_chunk(*chunk[:3], chunk[3:6], taps, chunk[6], rate, chunk[7], norm_w, st,
-                           bound, eps)
+                           bound, eps, *chunk[8:])
         return st, y
 
     st0 = jnp.zeros((heads, d, d), _F32)
-    _, y = lax.scan(one, st0, (*xs, *rows, by_chunk(pre.astype(_F32), d), side))
+    _, y = lax.scan(one, st0, (*xs, *rows, by_chunk(pre.astype(_F32), d), side, *channel))
     return jnp.moveaxis(y, 1, 2).reshape(n * CHUNK, heads * d)[:t].astype(xq.dtype)
 
 
@@ -290,10 +341,12 @@ def _heads_a_step(heads: int) -> int:
     return hb
 
 
-def _kernel(xq_ref, xk_ref, xv_ref, rq_ref, rk_ref, rv_ref, wq_ref, wk_ref, wv_ref, pre_ref,
-            rate_ref, side_ref, norm_ref, y_ref, st_ref, *, hb: int, d: int, bound: float,
-            eps: float):
+def _kernel(*refs, hb: int, d: int, bound: Optional[float], eps: float):
     import jax.experimental.pallas as pl
+
+    (xq_ref, xk_ref, xv_ref, rq_ref, rk_ref, rv_ref, wq_ref, wk_ref, wv_ref, pre_ref, rate_ref,
+     side_ref, norm_ref) = refs[:13]
+    channel, (y_ref, st_ref) = refs[13:-2], refs[-2:]  # a channel gate's ref, if any
 
     j = pl.program_id(1)
 
@@ -308,19 +361,23 @@ def _kernel(xq_ref, xk_ref, xv_ref, rq_ref, rk_ref, rv_ref, wq_ref, wk_ref, wv_r
 
     before = tuple(heads_of(r).astype(_F32) * inside for r in (rq_ref, rk_ref, rv_ref))
     taps = tuple(heads_of(w) for w in (wq_ref, wk_ref, wv_ref))
-    side = jnp.stack([jnp.concatenate([side_ref[:, h:h + 1], side_ref[:, hb + h:hb + h + 1]],
-                                      axis=1) for h in range(hb)])
+    if channel:  # beta alone on the side, the gate's pre-activation a chunk of its own
+        side, gate = jnp.stack([side_ref[:, h:h + 1] for h in range(hb)]), heads_of(channel[0])
+    else:
+        side = jnp.stack([jnp.concatenate([side_ref[:, h:h + 1], side_ref[:, hb + h:hb + h + 1]],
+                                          axis=1) for h in range(hb)])
+        gate = None
     y, st = head_chunk(heads_of(xq_ref), heads_of(xk_ref), heads_of(xv_ref), before, taps,
                        heads_of(pre_ref), heads_of(rate_ref), side, norm_ref[...], st_ref[...],
-                       bound, eps)
+                       bound, eps, gate)
     for h in range(hb):
         y_ref[:, h * d:(h + 1) * d] = y[h].astype(y_ref.dtype)
     st_ref[...] = st
 
 
 @functools.partial(jax.jit, static_argnames=("heads", "bound", "eps", "interpret"))
-def _kda_pallas(xq, xk, xv, taps, pre, rate, beta, gate, norm_w, heads: int, bound: float,
-                eps: float, interpret: bool = False):
+def _kda_pallas(xq, xk, xv, taps, pre, rate, beta, gate, norm_w, heads: int,
+                bound: Optional[float], eps: float, interpret: bool = False):
     import jax.experimental.pallas as pl  # deferred so CPU-only processes never pay it
     from jax.experimental.pallas import tpu as pltpu
 
@@ -329,13 +386,19 @@ def _kda_pallas(xq, xk, xv, taps, pre, rate, beta, gate, norm_w, heads: int, bou
         raise ValueError(f"the chunked delta rule takes whole chunks of {CHUNK} positions; "
                          f"got T={t}")
     hb = _heads_a_step(heads)
+    wide = _wide(bound)
     # the framework enables x64 globally; Mosaic only legalizes i32 scalars
     with jax.enable_x64(False):
         if diagnostics._enabled:  # trace time only: a trace of the path that took the kernel
-            diagnostics.counter("kernels.kda.fwd")
-        # beta and the gate of a step's heads side by side: (head groups, T, 2 hb)
-        side = jnp.stack([beta, gate], axis=1).astype(_F32).reshape(t, 2, heads // hb, hb)
-        side = jnp.moveaxis(side, 2, 0).reshape(heads // hb, t, 2 * hb)
+            diagnostics.counter("kernels.kda.fwd.unbounded" if wide else "kernels.kda.fwd")
+        channel = ()
+        if gate.shape[1] != heads:  # a gate a channel: beta alone, (head groups, T, hb)
+            side = jnp.moveaxis(beta.astype(_F32).reshape(t, heads // hb, hb), 1, 0)
+            channel = (gate.astype(_F32),)
+        else:
+            # beta and the gate of a step's heads side by side: (head groups, T, 2 hb)
+            side = jnp.stack([beta, gate], axis=1).astype(_F32).reshape(t, 2, heads // hb, hb)
+            side = jnp.moveaxis(side, 2, 0).reshape(heads // hb, t, 2 * hb)
         rows = CHUNK // BEFORE
         chunk = pl.BlockSpec((CHUNK, hb * d), lambda i, j: (j, i))
         before = pl.BlockSpec((BEFORE, hb * d), lambda i, j: (jnp.maximum(j * rows - 1, 0), i))
@@ -346,8 +409,8 @@ def _kda_pallas(xq, xk, xv, taps, pre, rate, beta, gate, norm_w, heads: int, bou
             grid=(heads // hb, t // CHUNK),
             in_specs=[chunk, chunk, chunk, before, before, before, tap, tap, tap, chunk,
                       pl.BlockSpec((1, hb * d), lambda i, j: (0, i)),
-                      pl.BlockSpec((None, CHUNK, 2 * hb), lambda i, j: (i, j, 0)),
-                      pl.BlockSpec((1, d), lambda i, j: (0, 0))],
+                      pl.BlockSpec((None, CHUNK, side.shape[2]), lambda i, j: (i, j, 0)),
+                      pl.BlockSpec((1, d), lambda i, j: (0, 0))] + [chunk] * len(channel),
             out_specs=chunk,
             out_shape=jax.ShapeDtypeStruct((t, heads * d), xq.dtype),
             scratch_shapes=[pltpu.VMEM((hb, d, d), _F32)],
@@ -355,21 +418,27 @@ def _kda_pallas(xq, xk, xv, taps, pre, rate, beta, gate, norm_w, heads: int, bou
             # a head group's chunks follow one another: the state is carried in VMEM
             compiler_params=None if interpret else pltpu.CompilerParams(
                 dimension_semantics=("parallel", "arbitrary")),
-            name="kda_chunk_fwd",
+            name="kda_unbounded_fwd" if wide else "kda_chunk_fwd",
         )(xq, xk, xv, xq, xk, xv, *_taps32(taps), pre.astype(_F32),
-          rate.astype(_F32).reshape(1, heads * d), side, norm_w.astype(_F32).reshape(1, d))
+          rate.astype(_F32).reshape(1, heads * d), side, norm_w.astype(_F32).reshape(1, d),
+          *channel)
 
 
-def kda_mix(xq, xk, xv, taps, pre, rate, beta, gate, norm_w, heads: int, bound: float,
-            eps: float, interpret: bool = False):
+def kda_mix(xq, xk, xv, taps, pre, rate, beta, gate, norm_w, heads: int,
+            bound: Optional[float], eps: float, interpret: bool = False):
     """Kimi Delta Attention between its projections, over the whole sequence from a zero
     state: ``xq, xk, xv`` (T, heads * d) the three projections as stored, ``taps`` their
     three convolutions (width, heads * d), ``pre`` (T, heads * d) float32 and ``rate``
     (heads * d,) the decay's pre-activation and its rate (the log-decay is ``bound *
-    sigmoid(rate * pre)``, ``LOG_DECAY_BOUND <= bound < 0``), ``beta`` and ``gate`` (T,
-    heads) float32 (both after their sigmoid), ``norm_w`` (d,) the head norm's weight.
-    Returns the gated, normed heads (T, heads * d) in ``xq``'s type, ready for the output
-    projection. ``T`` is a whole number of chunks (``ValueError`` otherwise). Callers ask
-    :func:`decline_reason` first. No gradient is defined on this entry."""
+    sigmoid(rate * pre)`` for a ``bound < 0``, or ``-rate * softplus(pre)`` for ``bound``
+    None), ``beta`` (T, heads) float32 after its sigmoid, ``gate`` either (T, heads) after
+    its sigmoid (a gate a head) or (T, heads * d) float32 before it (a gate a channel),
+    ``norm_w`` (d,) the head norm's weight. Returns the gated, normed heads (T, heads * d) in
+    ``xq``'s type, ready for the output projection. The narrow form runs as
+    ``kda_chunk_fwd``, the wide one (the softplus kind, or a bound below
+    :data:`LOG_DECAY_BOUND`) as ``kda_unbounded_fwd``. ``T`` is a whole number of chunks
+    (``ValueError`` otherwise). Callers ask :func:`decline_reason` first. No gradient is
+    defined on this entry."""
     return _kda_pallas(xq, xk, xv, tuple(taps), pre, rate, beta, gate, norm_w, heads=heads,
-                       bound=float(bound), eps=eps, interpret=interpret)
+                       bound=None if bound is None else float(bound), eps=eps,
+                       interpret=interpret)

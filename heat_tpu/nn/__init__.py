@@ -14,5 +14,6 @@ from .trinity import *
 from .kda import *
 from .ling import *
 from .deepseek_v32 import *
-from . import (attention, data_parallel, deepseek_v32, functional, hyper_connections, kda, ling,
-               modules, moe, recurrent, scoring, trinity, xing4)
+from .kimi_linear import *
+from . import (attention, data_parallel, deepseek_v32, functional, hyper_connections, kda,
+               kimi_linear, ling, modules, moe, recurrent, scoring, trinity, xing4)

@@ -829,7 +829,9 @@ class MultiheadLatentAttention(Module):
     ``c_q = RMSNorm(x W_qa)``; per head ``[q_nope; q_rope] = c_q W_qb``;
     ``[c_kv; k_rope] = x W_kva`` with ``c_kv <- RMSNorm(c_kv)``; per head
     ``[k_nope; v] = c_kv W_kvb``. ``k_rope`` is one vector for all heads; rotary
-    positions (YaRN frequencies) go on the rope parts only. Scores are
+    positions (YaRN frequencies) go on the rope parts only; with ``rope_theta=None`` the layer
+    has no positions at all (a published ``mla_use_nope``) and the rope parts are one more
+    stretch of the query and of the shared key, unrotated. Scores are
     ``q . k * (nope + rope)^-1/2 * m^2`` with YaRN's ``m = 0.1 mscale_all_dim ln(factor) + 1``,
     the heads' outputs (``v_head_dim`` wide, not the query's width) are concatenated into
     ``W_o``. No bias anywhere. Input ``(..., T, dim)``, positions ``0..T-1`` on axis -2.
@@ -858,7 +860,7 @@ class MultiheadLatentAttention(Module):
 
     def __init__(self, dim: int, num_heads: int, q_lora_rank: Optional[int], kv_lora_rank: int,
                  qk_nope_head_dim: int, qk_rope_head_dim: int, v_head_dim: int,
-                 rope_theta: float = 10000.0, rope_scaling: Optional[dict] = None,
+                 rope_theta: Optional[float] = 10000.0, rope_scaling: Optional[dict] = None,
                  eps: float = 1e-6, dtype=jnp.float32, norm_init_std: float = 0.0,
                  head_gate: bool = False, index: Optional[Tuple[int, int, int]] = None,
                  head_groups: int = 1):
@@ -866,12 +868,15 @@ class MultiheadLatentAttention(Module):
             raise ValueError(f"{head_groups} groups do not divide {num_heads} heads")
         if index is not None and q_lora_rank is None:
             raise ValueError("the indexer reads the query latent: q_lora_rank is None")
+        if index is not None and rope_theta is None:
+            raise ValueError("the indexer's keys carry positions: rope_theta is None")
         self.dim = dim
         self.num_heads, self.head_groups = num_heads, head_groups
         self.q_lora_rank = q_lora_rank
         self.kv_lora_rank = kv_lora_rank
         self.nope, self.rope, self.v_dim = qk_nope_head_dim, qk_rope_head_dim, v_head_dim
-        self.inv_freq = yarn_inv_freq(qk_rope_head_dim, rope_theta, rope_scaling)
+        self.inv_freq = (None if rope_theta is None
+                         else yarn_inv_freq(qk_rope_head_dim, rope_theta, rope_scaling))
         # cos and sin carry mscale / mscale_all_dim; the softmax scale carries m^2
         self.rope_magnitude = (_yarn_mscale(rope_scaling, "mscale")
                                / _yarn_mscale(rope_scaling, "mscale_all_dim"))
@@ -939,6 +944,10 @@ class MultiheadLatentAttention(Module):
 
     def _latent(self, params, x):
         """``(c_kv, k_rope)``: the normed key/value latent and the one rotated rope key."""
+        if self.inv_freq is None:  # no positions: the shared key part as projected
+            kv = contract("...td,dr->...tr", x, params["wkv_a"]).astype(x.dtype)
+            return (self.kv_norm.apply(params["kv_norm"], kv[..., :self.kv_lora_rank]),
+                    kv[..., self.kv_lora_rank:])
         kv = contract("...td,dr->...tr", x,
                       even_then_odd(params["wkv_a"], self.kv_lora_rank)).astype(x.dtype)
         c_kv = self.kv_norm.apply(params["kv_norm"], kv[..., :self.kv_lora_rank])
@@ -952,13 +961,16 @@ class MultiheadLatentAttention(Module):
         in one group trace the operations in the order they always had."""
         dn, dr, dv, dt = self.nope, self.rope, self.v_dim, x.dtype
         h = wo.shape[0] // dv
-        # the rope columns of both projections, published as interleaved pairs, are
-        # taken even ones first: rotate_halves then turns the published pairs
-        wq_b = even_then_odd(wq.reshape(wq.shape[0], h, dn + dr), dn)
-        q = contract("...tr,rhe->...hte", c_q, wq_b).astype(dt)
-        q = jnp.concatenate(
-            [q[..., :dn], rotate_halves(q[..., dn:], self.inv_freq, self.rope_magnitude)],
-            axis=-1)
+        if self.inv_freq is None:
+            q = contract("...tr,rhe->...hte", c_q, wq.reshape(wq.shape[0], h, dn + dr)).astype(dt)
+        else:
+            # the rope columns of both projections, published as interleaved pairs, are
+            # taken even ones first: rotate_halves then turns the published pairs
+            wq_b = even_then_odd(wq.reshape(wq.shape[0], h, dn + dr), dn)
+            q = contract("...tr,rhe->...hte", c_q, wq_b).astype(dt)
+            q = jnp.concatenate(
+                [q[..., :dn], rotate_halves(q[..., dn:], self.inv_freq, self.rope_magnitude)],
+                axis=-1)
         c_kv, k_rope = latent()
         kv_h = contract("...tr,rhe->...hte", c_kv,
                         wkv_b.reshape(self.kv_lora_rank, h, dn + dv)).astype(dt)

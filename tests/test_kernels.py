@@ -496,6 +496,94 @@ def test_mosaic_compiles_the_delta_rule_at_the_ling_cell_shape(one_chip):
     assert compiled.memory_analysis().temp_size_in_bytes < 2**28
 
 
+def test_the_bounded_delta_rule_traces_what_it_traced_before_the_softplus_kind():
+    """The chunked KDA forward at ``ling-score-32k``'s shape (bounded decay, one gate a head)
+    traces, character for character, the jaxpr of the commit before the softplus kind and
+    the gate a channel were added (the digest was taken on that commit): the two new static
+    choices leave Ling's kernel as it was."""
+    import hashlib
+
+    from heat_tpu.core.kernels import delta_rule
+
+    x, g = jax.ShapeDtypeStruct((32768, 4096), jnp.bfloat16), jax.ShapeDtypeStruct((32768, 4096), jnp.float32)
+    w, side = jax.ShapeDtypeStruct((4, 4096), jnp.bfloat16), jax.ShapeDtypeStruct((32768, 32), jnp.float32)
+    vec = jax.ShapeDtypeStruct((4096,), jnp.float32), jax.ShapeDtypeStruct((128,), jnp.float32)
+    text = str(jax.make_jaxpr(functools.partial(delta_rule.kda_mix, heads=32, bound=-5.0, eps=1e-6))(
+        x, x, x, (w, w, w), g, vec[0], side, side, vec[1]))
+    assert "name=kda_chunk_fwd" in text
+    assert hashlib.sha256(text.encode()).hexdigest()[:16] == "5b310e58c347d608"
+
+
+@pytest.mark.parametrize("cell,digest", [("xing4", "dcfe7c5a64ba00db"), ("ling", "265c23cce35d619d")])
+def test_latent_attention_with_positions_traces_what_it_traced_before_nope(cell, digest, monkeypatch):
+    """``MultiheadLatentAttention`` with rotary positions at the Xing4 cell's settings (query
+    latent, YaRN) and at the Ling cell's (direct query, head gate), 32,768 tokens in bfloat16 on
+    the TPU path (``mla_flash_fwd``): the jaxpr is, character for character, that of the commit
+    before the switch to no positions was added (the digests were taken on that commit)."""
+    import hashlib
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    yarn = {"beta_fast": 32, "beta_slow": 1, "factor": 64, "mscale": 1, "mscale_all_dim": 1,
+            "original_max_position_embeddings": 4096, "type": "yarn"}
+    if cell == "xing4":
+        m = ht.nn.MultiheadLatentAttention(3584, 32, 768, 512, 128, 64, 128, 10000, yarn, 1e-6,
+                                           jnp.bfloat16, 0.1)
+    else:
+        m = ht.nn.MultiheadLatentAttention(2560, 32, None, 512, 128, 64, 128, 6000000, None, 1e-6,
+                                           jnp.bfloat16, 0.1, head_gate=True)
+    params = jax.eval_shape(m.init, jax.random.key(0))
+    x = jax.ShapeDtypeStruct((32768, m.dim), jnp.bfloat16)
+    text = str(jax.make_jaxpr(lambda p, x: m.apply(p, x))(params, x))
+    assert "name=mla_flash_fwd" in text
+    assert hashlib.sha256(text.encode()).hexdigest()[:16] == digest
+
+
+@pytest.mark.parametrize("t,dtype", [(32768, jnp.bfloat16), (4096, jnp.float32)],
+                         ids=["cell", "float32_check"])
+def test_mosaic_compiles_the_unbounded_delta_rule_at_the_kimi_cell_shape(one_chip, t, dtype):
+    """The chunked KDA forward at ``kimi-score-32k``'s shape, 32 heads of 128 over 32,768
+    positions in bfloat16, with the softplus decay's and the channel gate's pre-activations in
+    float32 (T, 4096) beside the projections: the gate takes it and Mosaic compiles the wide
+    form (sub-chunks of 8, the floor) under its own name, with no temporary beyond beta laid
+    out by head group. Likewise in float32 over the 4,096 positions of the cell's check of its
+    KDA layers (``kda_rms_gap``)."""
+    from heat_tpu.core.kernels import delta_rule
+
+    def shaped(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    x, f = shaped((t, 4096), dtype), shaped((t, 4096), jnp.float32)
+    w, beta = shaped((4, 4096), dtype), shaped((t, 32), jnp.float32)
+    assert delta_rule.decline_reason(x, w, 32) is None
+    compiled = jax.jit(functools.partial(delta_rule.kda_mix, heads=32, bound=None, eps=1e-5)).lower(
+        x, x, x, (w, w, w), f, shaped((4096,), jnp.float32), beta, f,
+        shaped((128,), jnp.float32)).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text and "kda_unbounded_fwd" in text and "kda_chunk_fwd" not in text
+    assert compiled.memory_analysis().temp_size_in_bytes < 2**24
+
+
+def test_mosaic_compiles_the_expert_layer_at_the_kimi_cell_shape(one_chip, monkeypatch):
+    """One expert layer of ``kimi-score-32k``: 128 of 256 experts of 2304 x 1024 held, top-8 with
+    no group limit, blocks of 256 rows: the grouped kernel takes a whole expert a step, the
+    sorted buffer is 32-bit words and the combine is the second kernel; no loop is left."""
+    from heat_tpu.nn.kimi_linear import BLOCK_ROWS
+
+    monkeypatch.setattr(grouped_matmul, "available", lambda interpret=False: True)
+    m = ht.nn.MoE(2304, 1024, 256, 8, 1, 2.446, (0, 128), BLOCK_ROWS, jnp.bfloat16)
+    assert grouped_matmul._slab(2304, 1024, BLOCK_ROWS, 2, 2) == 1024
+
+    def placed(a):
+        return jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip)
+
+    params = jax.tree_util.tree_map(placed, jax.eval_shape(m.init, jax.random.key(0)))
+    x = jax.ShapeDtypeStruct((32768, 2304), jnp.bfloat16, sharding=one_chip)
+    text = jax.jit(lambda p, x: m.apply(p, x)).lower(params, x).compile().as_text()
+    assert "tpu_custom_call" in text and "moe_grouped_fwd" in text
+    _the_buffer_is_words_and_the_combine_a_kernel(text, 32768 * 8 + 128 * BLOCK_ROWS, 2304, 8)
+    assert not [line for line in text.splitlines() if " while(" in line]
+
+
 def test_mosaic_compiles_the_expert_layer_at_the_ling_cell_shape(one_chip, monkeypatch):
     """One expert layer of ``ling-score-32k``: 128 of 512 experts of 2560 x 768 held, top-8 in
     8 groups of which 4 stay, blocks of 128 rows: the grouped kernel is in the program, the

@@ -188,8 +188,9 @@ def test_a_sequence_that_is_no_whole_number_of_chunks():
     assert "bfloat16 or float32" in delta_rule.decline_reason(x[:64].astype(jnp.float16), w, 2)
     assert "reaches past the 16 rows" in delta_rule.decline_reason(x[:64], jnp.ones((18, 256)), 2)
     assert delta_rule.decline_reason(x[:64], w, 2) is None
-    with pytest.raises(ValueError, match=r"log-decays in \[-5.0, 0\)"):
-        ht.nn.KimiDeltaAttention(64, 4, 16, log_decay_bound=-8.0)
+    with pytest.raises(ValueError, match="bound below 0"):
+        ht.nn.KimiDeltaAttention(64, 4, 16, log_decay_bound=0.5)
+    assert ht.nn.KimiDeltaAttention(64, 4, 16, log_decay_bound=-8.0).bound == -8.0  # wide form
 
 
 def test_the_convolution_sees_zeros_left_of_the_document():
