@@ -48,9 +48,10 @@ entry. Two forms, chosen by the decay (static):
 
 ``T`` is exact arithmetic on nilpotent float32 matrices: the ``sub`` x ``sub`` diagonal
 blocks by ``(I + X)^-1 = (I - X)(I + X^2)(I + X^4)..`` (powers of a sub-chunk's block stay
-small: no cancellation), then the ``C / sub`` block structure the same way (ten products of
-64 x 64 in all for the narrow form, eight for the wide). ``T`` is then rounded to the
-operands' type, so its products follow that type
+small: no cancellation), then the ``C / sub`` block structure the same way: ten products in
+all for either form (narrow: six for the blocks of 16, one for the blocks below them, two for
+their structure of 4, one to finish; wide: four, one, four, one). ``T`` is then rounded to
+the operands' type, so its products follow that type
 (:func:`_product`): for float32 operands ``Precision.HIGHEST``; for bfloat16 operands each
 factor as two bfloat16 pieces and three products, 2^-17 of the result, at a third of the
 MXU passes and none of the splits and sums that six passes bring. Every other product takes
@@ -59,6 +60,19 @@ rounded to it; the state is rounded to it for its two products, as published ker
 and accumulates in float32; 16-bit operands are one MXU pass, float32 operands multiply at
 ``Precision.HIGHEST``. The cumulative log-decay is exact in float32: the 0/1 triangle is
 exact in bfloat16 and ``g`` goes in as three bfloat16 pieces (24 bits) side by side.
+
+**Two heads to a lane row.** A C x C matrix of one head fills half of each 128-lane vreg.
+Where a step's head count is even (:func:`_paired`), heads ``2p`` and ``2p + 1`` lie side by
+side, ``(n / 2, C, 2C)``: ``A`` and ``Aqk`` are put together a pair from the sub-chunks'
+products, their masks are applied once a pair, and each product of ``T`` takes the pair's
+left operand against the block diagonal ``[[X_2p, 0], [0, X_2p+1]]`` (2C x 2C) of its right
+one, split into bfloat16 pieces once a pair: one product 128 deep a pair where there were
+two 64 deep a head. The terms added are exact zeros, so each head's result is what it was a
+head at a time. ``T`` and ``Aqk`` are sliced back into heads for their products with the
+d-wide operands (faster on a v5e than block-diagonal right operands there: 12.66 against
+12.77 ms a layer of 32 heads over 32,768 positions, narrow form). An odd count keeps the
+form a head at a time. Eight heads a step, paired, take a layer 12.5 ms (narrow) and 14.0 ms
+(wide) on a v5e, 14.75 and 16.28 a head at a time.
 
 **Round the recurrence** (:func:`head_chunk`) a chunk step also does what Kimi Delta
 Attention puts before and after it, so that q, k, v, g and o never go through HBM on their
@@ -73,11 +87,12 @@ float32 pre-activation comes in as a ``(T, H d)`` operand beside ``pre``, chunk 
 the projections leave them, heads side by side on the lanes, so nothing is transposed on the
 way in or out.
 The grid is (head groups, chunks): a step takes one chunk of :data:`HEADS` heads, which
-ride a leading axis through :func:`chunk_step` so that every product is issued for all of
-them at once: a head's inverse alone is a chain of eight dependent 64 x 64 products, each a
-full MXU latency, and heads looped one after another did not overlap (36.8 ms a layer of 32
-heads over 32,768 positions on a v5e for four heads a step, 41.9 for one; on a leading axis
-18.7 for four, 15.2 for eight, 14.4 for sixteen: my chip runs, PR 33). Each head's state
+ride a leading axis through :func:`chunk_step` (as four pairs for its C x C work) so that
+every product is issued for all of them at once: a head's inverse alone is a chain of ten
+dependent products, each a full MXU latency, and heads looped one after another did not
+overlap (36.8 ms a layer of 32 heads over 32,768 positions on a v5e for four heads a step,
+41.9 for one; on a leading axis 18.7 for four, 15.2 for eight, 14.4 for sixteen, before the
+heads were paired). Each head's state
 (kept as ``S^T``, so a channel's decay is a lane's) stays in VMEM over the chunk axis, which
 is sequential. The rows before a chunk are a second, 16-row view of the same three arrays.
 
@@ -142,25 +157,57 @@ def _pieces(x, n: int):
     return parts
 
 
+def _paired(n: int) -> bool:
+    """Whether a step of ``n`` heads lays its C x C matrices out two heads to a lane row."""
+    return n % 2 == 0
+
+
+def _side_by_side(y):
+    """(n, r, w) a head each as (n / 2, r, 2w): heads 2p and 2p + 1 side by side."""
+    n, r, w = y.shape
+    y = y.reshape(n // 2, 2, r, w)
+    return jnp.concatenate([y[:, 0], y[:, 1]], axis=2)
+
+
+def _by_heads(y):
+    """(n / 2, r, 2w), two heads side by side, as (n, r, w) a head each."""
+    h, r, w = y.shape
+    return jnp.stack([y[:, :, :w // 2], y[:, :, w // 2:]], axis=1).reshape(2 * h, r, w // 2)
+
+
+def _block_diagonal(y):
+    """A pair's two C x C matrices side by side, (n, C, 2C), as the (n, 2C, 2C)
+    ``[[y_2p, 0], [0, y_2p+1]]``; a head's own (n, C, C) as it is."""
+    c, w = y.shape[1:]
+    if w == c:
+        return y
+    zero = jnp.zeros((y.shape[0], c, c), y.dtype)
+    return jnp.concatenate([jnp.concatenate([y[:, :, :c], zero], axis=2),
+                            jnp.concatenate([zero, y[:, :, c:]], axis=2)], axis=1)
+
+
 def _product(a, b, exact: bool):
-    """``a @ b`` of float32 squares, a head each. ``exact``: at ``Precision.HIGHEST`` (six
-    MXU passes with their splits and sums). Otherwise each operand as two bfloat16 pieces and
-    the three products that matter, ``(a_hi + a_lo) b_hi + a_hi b_lo``: 2^-17 of the result,
-    for a result that is rounded to bfloat16's 2^-9 when it is used."""
+    """``a @ b`` of float32 squares, a head each, or of pairs side by side (n, C, 2C), where
+    ``a`` multiplies :func:`_block_diagonal` of ``b``: one product 2C deep a pair where there
+    were two C deep, and exact zeros added. ``exact``: at ``Precision.HIGHEST`` (six MXU
+    passes with their splits and sums). Otherwise each operand as two bfloat16 pieces, split
+    once a pair, and the three products that matter, ``(a_hi + a_lo) b_hi + a_hi b_lo``:
+    2^-17 of the result, for a result that is rounded to bfloat16's 2^-9 when it is used."""
     if exact:
-        return _dot(a, b, _NN)
+        return _dot(a, _block_diagonal(b), _NN)
     n = a.shape[1]
-    (a_hi, a_lo), (b_hi, b_lo) = _pieces(a, 2), _pieces(b, 2)
+    (a_hi, a_lo), (b_hi, b_lo) = _pieces(a, 2), [_block_diagonal(p) for p in _pieces(b, 2)]
     both = _dot(jnp.concatenate([a_hi, a_lo], axis=1), b_hi, _NN)
     return both[:, :n] + both[:, n:] + _dot(a_hi, b_lo, _NN)
 
 
 def _inverse_of_one_plus(x, nilpotency: int, exact: bool):
-    """``(I + x)^-1`` of float32 squares ``x`` (n, c, c) with ``x^nilpotency = 0``:
-    ``(I - x)(I + x^2)(I + x^4)..``, each factor one :func:`_product`."""
-    c = x.shape[1]
-    eye = (lax.broadcasted_iota(jnp.int32, (c, c), 0)
-           == lax.broadcasted_iota(jnp.int32, (c, c), 1)).astype(_F32)
+    """``(I + x)^-1`` of float32 squares ``x`` (n, c, c), or of pairs side by side (n, c, 2c),
+    with ``x^nilpotency = 0``: ``(I - x)(I + x^2)(I + x^4)..``, each factor one
+    :func:`_product`."""
+    c, w = x.shape[1:]
+    eye = (lax.broadcasted_iota(jnp.int32, (c, w), 0)
+           == lax.rem(lax.broadcasted_iota(jnp.int32, (c, w), 1), jnp.int32(c))).astype(_F32)
     inv, power, reach = eye - x, x, 2
     while reach < nilpotency:
         power = _product(power, power, exact)
@@ -172,12 +219,13 @@ def _inverse_of_one_plus(x, nilpotency: int, exact: bool):
 def chunk_step(q, k, kb, vb, g, st, sub: int = SUB, half: float = _HALF):
     """One chunk of ``n`` heads, every line a head's own: the heads ride a leading axis so
     that each product is issued for all of them before the next one that waits for it
-    (their chains are independent, a chain's products are not). ``q, k, kb`` (n, C, d_k) and
-    ``vb`` (n, C, d_v) in the operands' type, ``g`` (n, C, d_k) float32, ``st`` the states
-    transposed, (n, d_v, d_k) float32. Returns ``(o (n, C, d_v) float32, st)``. ``C`` is a
-    whole number of sub-chunks of ``sub`` rows, over which ``g`` sums to no less than ``-2
-    half``: the narrow form's defaults, or :data:`WIDE_SUB` and ``_WIDE_HALF`` on log-decays
-    floored at :data:`FLOOR`."""
+    (their chains are independent, a chain's products are not); where ``n`` is even the C x C
+    matrices take heads 2p and 2p + 1 side by side on the lanes (:func:`_paired`). ``q, k,
+    kb`` (n, C, d_k) and ``vb`` (n, C, d_v) in the operands' type, ``g`` (n, C, d_k) float32,
+    ``st`` the states transposed, (n, d_v, d_k) float32. Returns ``(o (n, C, d_v) float32,
+    st)``. ``C`` is a whole number of sub-chunks of ``sub`` rows, over which ``g`` sums to no
+    less than ``-2 half``: the narrow form's defaults, or :data:`WIDE_SUB` and ``_WIDE_HALF``
+    on log-decays floored at :data:`FLOOR`."""
     n, c, d_k = k.shape
     op = q.dtype
     row = lax.broadcasted_iota(jnp.int32, (c, c), 0)
@@ -195,6 +243,13 @@ def chunk_step(q, k, kb, vb, g, st, sub: int = SUB, half: float = _HALF):
     # a row's and a column's factor share the sub-chunk's range of exp(+-2 half) between them
     lift = jnp.exp(local + half)
     rows_k, rows_q = kb.astype(_F32) * lift, q.astype(_F32) * lift
+    # from here on a C x C matrix is (n, C, C), or (n / 2, C, 2C) with a pair on the lanes:
+    # its masks, pieces and products then fill the vregs
+    paired = _paired(n)
+    if paired:
+        row = lax.broadcasted_iota(jnp.int32, (c, 2 * c), 0)
+        col = lax.rem(lax.broadcasted_iota(jnp.int32, (c, 2 * c), 1), jnp.int32(c))
+        same = (row // sub) == (col // sub)
     a_parts, qk_parts = [], []
     for j in range(c // sub):
         lo = j * sub
@@ -202,6 +257,8 @@ def chunk_step(q, k, kb, vb, g, st, sub: int = SUB, half: float = _HALF):
                    ).astype(op)
         rows = jnp.concatenate([rows_k[:, lo:lo + sub], rows_q[:, lo:lo + sub]], axis=1)
         s = _dot(rows.astype(op), columns, _NT)
+        if paired:
+            s = _side_by_side(s)
         a_parts.append(s[:, :sub])
         qk_parts.append(s[:, sub:])
     a = jnp.where(col < row, jnp.concatenate(a_parts, axis=1), 0.0)
@@ -211,6 +268,8 @@ def chunk_step(q, k, kb, vb, g, st, sub: int = SUB, half: float = _HALF):
     diagonal = _inverse_of_one_plus(jnp.where(same, a, 0.0), sub, exact)
     below = _product(diagonal, jnp.where(same, 0.0, a), exact)
     t = _product(_inverse_of_one_plus(below, c // sub, exact), diagonal, exact).astype(op)
+    if paired:  # a head's own T and Aqk for the products with d-wide operands
+        t, a_qk = _by_heads(t), _by_heads(a_qk)
 
     decay = jnp.exp(total)
     wu = _dot(t, jnp.concatenate([(kb.astype(_F32) * decay).astype(op), vb], axis=2), _NN)
@@ -391,6 +450,8 @@ def _kda_pallas(xq, xk, xv, taps, pre, rate, beta, gate, norm_w, heads: int,
     with jax.enable_x64(False):
         if diagnostics._enabled:  # trace time only: a trace of the path that took the kernel
             diagnostics.counter("kernels.kda.fwd.unbounded" if wide else "kernels.kda.fwd")
+            if _paired(hb):
+                diagnostics.counter("kernels.kda.fwd.paired")
         channel = ()
         if gate.shape[1] != heads:  # a gate a channel: beta alone, (head groups, T, hb)
             side = jnp.moveaxis(beta.astype(_F32).reshape(t, heads // hb, hb), 1, 0)

@@ -496,22 +496,53 @@ def test_mosaic_compiles_the_delta_rule_at_the_ling_cell_shape(one_chip):
     assert compiled.memory_analysis().temp_size_in_bytes < 2**28
 
 
-def test_the_bounded_delta_rule_traces_what_it_traced_before_the_softplus_kind():
-    """The chunked KDA forward at ``ling-score-32k``'s shape (bounded decay, one gate a head)
-    traces, character for character, the jaxpr of the commit before the softplus kind and
-    the gate a channel were added (the digest was taken on that commit): the two new static
-    choices leave Ling's kernel as it was."""
-    import hashlib
-
+def _kda_cell_jaxpr(cell):
+    """The jaxpr of ``kda_mix`` at a cell's shape: 32 heads of 128 over 32,768 positions in
+    bfloat16, Ling's bounded decay with a gate a head or Kimi-Linear's softplus decay with a
+    gate a channel."""
     from heat_tpu.core.kernels import delta_rule
 
-    x, g = jax.ShapeDtypeStruct((32768, 4096), jnp.bfloat16), jax.ShapeDtypeStruct((32768, 4096), jnp.float32)
+    x, f = jax.ShapeDtypeStruct((32768, 4096), jnp.bfloat16), jax.ShapeDtypeStruct((32768, 4096), jnp.float32)
     w, side = jax.ShapeDtypeStruct((4, 4096), jnp.bfloat16), jax.ShapeDtypeStruct((32768, 32), jnp.float32)
     vec = jax.ShapeDtypeStruct((4096,), jnp.float32), jax.ShapeDtypeStruct((128,), jnp.float32)
-    text = str(jax.make_jaxpr(functools.partial(delta_rule.kda_mix, heads=32, bound=-5.0, eps=1e-6))(
-        x, x, x, (w, w, w), g, vec[0], side, side, vec[1]))
+    bound, eps, gate = (-5.0, 1e-6, side) if cell == "ling" else (None, 1e-5, f)
+    return jax.make_jaxpr(functools.partial(delta_rule.kda_mix, heads=32, bound=bound, eps=eps))(
+        x, x, x, (w, w, w), f, vec[0], side, gate, vec[1])
+
+
+def test_the_bounded_delta_rule_traces_what_it_traced_before_the_softplus_kind():
+    """The chunked KDA forward at ``ling-score-32k``'s shape (bounded decay, one gate a head)
+    traces, character for character, a fixed jaxpr: the softplus kind and the gate a channel
+    left Ling's kernel as it was. The digest was taken again when ``chunk_step`` came to lay
+    two heads side by side on the lanes, which changed its layout and nothing else in the
+    narrow form."""
+    import hashlib
+
+    text = str(_kda_cell_jaxpr("ling"))
     assert "name=kda_chunk_fwd" in text
-    assert hashlib.sha256(text.encode()).hexdigest()[:16] == "5b310e58c347d608"
+    assert hashlib.sha256(text.encode()).hexdigest()[:16] == "5674420c0a1906b3"
+
+
+def _dots(jaxpr):
+    """Every ``dot_general`` in a jaxpr, those of its sub-jaxprs (a Pallas body) included."""
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "dot_general":
+            yield eqn
+        for param in eqn.params.values():
+            for sub in param if isinstance(param, (tuple, list)) else (param,):
+                sub = getattr(sub, "jaxpr", sub)
+                if hasattr(sub, "eqns"):
+                    yield from _dots(sub)
+
+
+@pytest.mark.parametrize("cell", ["ling", "kimi"])
+def test_the_delta_rule_inverse_multiplies_pairs_of_heads_128_deep(cell):
+    """At a cell's shape a grid step takes 8 heads as 4 pairs side by side on the lanes: the
+    inverse's ten products are 20 dots against a pair's 128 x 128 block diagonal, and no dot
+    is left whose right operand is one head's 64 x 64 square."""
+    shapes = [(e.invars[0].aval.shape, e.invars[1].aval.shape) for e in _dots(_kda_cell_jaxpr(cell).jaxpr)]
+    assert not [s for s in shapes if s[1][-2:] == (64, 64)], shapes
+    assert sum(rhs == (4, 128, 128) for _, rhs in shapes) == 20
 
 
 @pytest.mark.parametrize("cell,digest", [("xing4", "dcfe7c5a64ba00db"), ("ling", "265c23cce35d619d")])

@@ -193,7 +193,8 @@ def test_a_bound_below_minus_5_takes_the_wide_form(form):
 
 def test_the_kernel_names_the_wide_form_and_counts_its_trace():
     """The softplus kind's Pallas call is ``kda_unbounded_fwd`` (its own device time), the
-    bounded kind's stays ``kda_chunk_fwd``; a trace of each wrapper is counted apart."""
+    bounded kind's stays ``kda_chunk_fwd``; a trace of each wrapper is counted apart, and a
+    trace whose step lays its two heads side by side counts ``kernels.kda.fwd.paired``."""
     inputs = mix_inputs(64, 2, 16, "seeded")
     diagnostics.enable()
     diagnostics.reset()
@@ -201,6 +202,7 @@ def test_the_kernel_names_the_wide_form_and_counts_its_trace():
         mixed("kernel", *inputs, 2)  # the first trace of this shape
         counters = diagnostics.report()["counters"]
         assert counters["kernels.kda.fwd.unbounded"] == 1 and "kernels.kda.fwd" not in counters
+        assert counters["kernels.kda.fwd.paired"] == 1
     finally:
         diagnostics.disable()
         diagnostics.reset()

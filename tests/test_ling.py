@@ -175,6 +175,51 @@ def test_kernel_and_fallback_are_one_chunk_step():
         assert gap(mixed("kernel", *inputs, 4, dtype), mixed("fallback", *inputs, 4, dtype)) < tol
 
 
+def chunk_inputs(n, dtype, wide, seed=11):
+    """``chunk_step``'s operands for ``n`` heads of 128 over one chunk: unit q (scaled) and k,
+    beta in (0, 1), log-decays by the narrow form's bound, or by the softplus kind to below
+    the wide form's floor and then floored, a state of 0.3 N(0, 1)."""
+    c, d = delta_rule.CHUNK, 128
+    ks = jax.random.split(jax.random.key(seed), 6)
+
+    def unit(y):
+        return y / jnp.linalg.norm(y, axis=2, keepdims=True)
+
+    q = unit(jax.random.normal(ks[0], (n, c, d))) * d ** -0.5
+    k = unit(jax.random.normal(ks[1], (n, c, d)))
+    beta = jax.nn.sigmoid(jax.random.normal(ks[2], (n, c, 1)))
+    v = jax.random.normal(ks[3], (n, c, d))
+    pre = 2.0 * jax.random.normal(ks[4], (n, c, d)) - 2.0
+    if wide:
+        g = jnp.maximum(-8.0 * jax.nn.softplus(pre), delta_rule.FLOOR)
+    else:
+        g = delta_rule.LOG_DECAY_BOUND * jax.nn.sigmoid(pre)
+    st = 0.3 * jax.random.normal(ks[5], (n, d, d))
+    return (q.astype(dtype), k.astype(dtype), (beta * k).astype(dtype), (beta * v).astype(dtype),
+            g, st)
+
+
+@pytest.mark.parametrize("n", [8, 3])
+@pytest.mark.parametrize("dtype", list(DTYPES))
+@pytest.mark.parametrize("form", ["narrow", "wide"])
+def test_the_paired_step_is_the_step_a_head_at_a_time(form, dtype, n):
+    """Where the step's head count is even, heads 2p and 2p + 1 lie side by side on the lanes
+    and every product of the inverse adds exact zeros beside each head's own terms: the step
+    agrees with the same step taken one head at a time (one head is never paired) to float32
+    rounding, and within one bfloat16 rounding on bfloat16 operands. An odd count (3) keeps
+    the form a head at a time."""
+    sub, half = ((delta_rule.SUB, delta_rule._HALF) if form == "narrow"
+                 else (delta_rule.WIDE_SUB, delta_rule._WIDE_HALF))
+    args = chunk_inputs(n, DTYPES[dtype], form == "wide")
+    assert delta_rule._paired(n) == (n == 8) and not delta_rule._paired(1)
+    step = jax.jit(lambda *a: delta_rule.chunk_step(*a, sub, half))
+    o, st = step(*args)
+    alone = [step(*(x[h:h + 1] for x in args)) for h in range(n)]
+    tol = 1e-6 if dtype == "float32" else 2.0 ** -8
+    assert gap(o, jnp.concatenate([y[0] for y in alone])) <= tol
+    assert gap(st, jnp.concatenate([y[1] for y in alone])) <= tol
+
+
 def test_a_sequence_that_is_no_whole_number_of_chunks():
     """The kernel refuses it in words; the plain form pads it with positions after its end;
     the module takes the plain form and says why."""
