@@ -39,6 +39,17 @@ chip the call takes the same time as over repeated heads). Measured at d = d_v =
 10.2 ms, 53% by the band's own pairs: two of a sweep's three steps are masked steps,
 whose sub-tiles run in regions of their own.
 
+**The latent operand form (the forward only).** Latent attention's q, its
+``[k_nope | v]`` projection and its one rope key enter as the projections leave them
+(:func:`flash_latent`): k_nope and v are two lane blocks of one array, the rope key one
+block for every head, and a sub-tile's key and query are put together in VMEM for the same
+192-deep product; a row sweep's first step turns the query's rope lanes itself. Nothing is
+concatenated, repeated or sliced in HBM, and no LSE is written. Mosaic's schedule for a
+described v5e puts the plain step at 6,313 bundles against the concatenated call's 6,423
+(under a packed mask; 6,050 against 6,120 without). The turn costs ~2% of the kernel on the
+chip; turned in XLA instead and read as a block of their own, the rope lanes cost more
+there than they saved here (~10 ms a DeepSeek-V3.2 layer against ~4).
+
 Backward: the ``jax.custom_vjp`` backward is also Pallas — the forward saves the
 (O, LSE) residuals, ``_dq_kernel`` streams k/v per query block and ``_dkv_kernel``
 streams q/dO per key block, each recomputing its probability tile from the LSE
@@ -63,7 +74,8 @@ from jax import lax
 from .. import diagnostics
 from .sparse_index import WORD_KEYS, mask_words
 
-__all__ = ["flash_attention", "flash_attention_reference", "flash_forward", "forward_blocks"]
+__all__ = ["flash_attention", "flash_attention_reference", "flash_forward", "flash_latent",
+           "forward_blocks", "latent_blocks"]
 
 _NEG_INF = float(jnp.finfo(jnp.float32).min)
 _LANES = 128
@@ -160,7 +172,7 @@ def _sub_tiles(bq: int, bk: int) -> tuple:
 
 
 def _fwd_footprint(bq: int, bk: int, d: int, dv: int, itemsize: int,
-                   with_bias: bool = False, with_mask: bool = False) -> int:
+                   with_bias: bool = False, with_mask: bool = False, turned: int = 0) -> int:
     """Bytes of VMEM a forward grid step holds at blocks ``(bq, bk)``: the one
     footprint model, which :func:`_fits` and :func:`forward_blocks` both gate on.
     Counted: the q / k / v / out blocks double-buffered (last dimension padded to 128
@@ -169,7 +181,12 @@ def _fwd_footprint(bq: int, bk: int, d: int, dv: int, itemsize: int,
     double-buffered, a streamed tile of mask words (``(bq, 128)`` int32) likewise, and the
     live tiles of the step's sub-tiles: f32 scores, f32
     probabilities and the probabilities in v's type, twice where the step has more
-    than one sub-tile (one under the VPU while the next is under the MXU). Against the
+    than one sub-tile (one under the VPU while the next is under the MXU). ``turned``: the
+    latent form turns that many rope lanes of its query block in the kernel, from a
+    double-buffered f32 ``(2, bq, turned)`` block of cosines and sines into a ``(bq,
+    turned)`` copy in q's type. Its key arrives as two blocks whose lanes add up to ``d``
+    and is put together as a live value like q's; it writes no LSE, which is still counted.
+    Against the
     least ``vmem_limit_bytes`` Mosaic accepts (AOT, v5e, PR 30) the model reads high at
     the preferred blocks (8.0 MiB for 5.0 at (1024, 1024), 192 / 128, bf16; 15.0 for
     14.4 with a bias) and low beyond them (13.5 for 14.9 at (2048, 2048))."""
@@ -183,7 +200,8 @@ def _fwd_footprint(bq: int, bk: int, d: int, dv: int, itemsize: int,
     tiles = (8 + itemsize) * br * bs * (1 if (br, bs) == (bq, bk) else 2)
     bias = 8 * bq * bk if with_bias else 0
     words = 8 * bq * _LANES if with_mask else 0
-    return blocks + state + tiles + bias + words
+    turn = (16 + itemsize) * bq * pad(turned) if turned else 0
+    return blocks + state + tiles + bias + words + turn
 
 
 def _fwd_blocks(dtype, tq: int, tk: int, with_bias: bool = False) -> tuple:
@@ -231,7 +249,7 @@ def _lanes(x, n: int):
 
 def _kernel(im_ref, jm_ref, flags_ref, q_ref, k_ref, v_ref, *refs,
             scale: float, bq: int, bk: int, br: int, bs: int, has_bias: bool = False,
-            window=None, has_mask: bool = False):
+            window=None, has_mask: bool = False, rope: int = 0, turn: bool = False):
     """One (q-block, k-block) pair of the online-softmax recurrence, walked as
     ``(bq / br) x (bk / bs)`` sub-tiles in one straight-line region.
 
@@ -256,6 +274,16 @@ def _kernel(im_ref, jm_ref, flags_ref, q_ref, k_ref, v_ref, *refs,
     under the diagonal), so a diagonal step still skips its sub-tiles above it, and no
     other pair is dropped: none is known to be empty when the call is traced.
 
+    ``rope`` (the latent form): q's last ``rope`` lanes are its rope part, ``k_ref`` holds a
+    key's other lanes and ``kr_ref`` its ``rope`` lanes, one block for every head; a
+    sub-tile's key is the two put side by side in VMEM, so the contraction is the one
+    ``d``-deep product it always was. ``turn``: the query's rope lanes still need their
+    rotary positions; the first step of a row sweep turns them once, 128 rows at a time, in
+    float32 from the ``(2, bq, rope)`` block of ``[cos | cos]`` and ``[-sin | sin]`` with the
+    halves swapped by an exact product on the MXU (no lane rotation), the products and sums
+    of ``rotate_halves``, into a copy the sweep's sub-tiles read beside the block's other
+    lanes. The latent form writes no LSE.
+
     Pallas double-buffers the k/v block DMA against compute because the kv pair
     index advances with the grid. MXU inputs stay in the input dtype (bf16 runs
     at full MXU rate — forcing f32 here quarters throughput); softmax state and
@@ -264,9 +292,14 @@ def _kernel(im_ref, jm_ref, flags_ref, q_ref, k_ref, v_ref, *refs,
     import jax.experimental.pallas as pl
 
     refs = list(refs)
+    kr_ref = refs.pop(0) if rope else None
+    cs_ref = refs.pop(0) if turn else None
     bias_ref = refs.pop(0) if has_bias else None
     mask_ref = refs.pop(0) if has_mask else None
-    o_ref, lse_ref, acc_ref, m_ref, l_ref = refs
+    qr_ref = refs.pop() if turn else None
+    lse_ref = None if rope else refs.pop(1)
+    o_ref, acc_ref, m_ref, l_ref = refs
+    dn = q_ref.shape[2] - rope  # the query's lanes that need no turning
 
     p = pl.program_id(1)
     dv = v_ref.shape[2]
@@ -283,12 +316,37 @@ def _kernel(im_ref, jm_ref, flags_ref, q_ref, k_ref, v_ref, *refs,
         acc_ref[...] = jnp.zeros_like(acc_ref)
         m_ref[...] = jnp.full_like(m_ref, _NEG_INF)
         l_ref[...] = jnp.zeros_like(l_ref)
+        if turn:
+            rows = _LANES if bq % _LANES == 0 else bq
+            # [a | b] -> [b | a] on the MXU, exact (one 1 a column): no lane rotations
+            i = lax.broadcasted_iota(jnp.int32, (rope, rope), 0)
+            j = lax.broadcasted_iota(jnp.int32, (rope, rope), 1)
+            swap = (i == (j + rope // 2) % rope).astype(q_ref.dtype)
+
+            def _turn(n, carry):  # a chunk of rows at a time: its operands stay in registers
+                chunk = pl.ds(pl.multiple_of(n * rows, rows), rows)
+                x = q_ref[0, chunk, dn:]
+                swapped = lax.dot_general(x, swap, (((1,), (0,)), ((), ())),
+                                          preferred_element_type=jnp.float32, precision=precision)
+                # [a cos - b sin | b cos + a sin], rotate_halves' products and sums
+                qr_ref[chunk, :] = (x.astype(jnp.float32) * cs_ref[0, chunk, :]
+                                    + swapped * cs_ref[1, chunk, :]).astype(qr_ref.dtype)
+                return carry
+
+            lax.fori_loop(0, bq // rows, _turn, 0)
 
     def _tile(r: int, c: int, masked: bool):
         rows, cols = pl.ds(r * br, br), pl.ds(c * bs, bs)
         vb = v_ref[0, cols, :]
+        if turn:
+            qt = jnp.concatenate([q_ref[0, rows, :dn], qr_ref[rows, :]], axis=1)
+        else:
+            qt = q_ref[0, rows, :]
+        kt = k_ref[0, cols, :]
+        if rope:
+            kt = jnp.concatenate([kt, kr_ref[0, cols, :]], axis=1)
         s = lax.dot_general(
-            q_ref[0, rows, :], k_ref[0, cols, :], (((1,), (1,)), ((), ())),
+            qt, kt, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32, precision=precision,
         ) * scale  # (br, bs) f32
         if has_bias:
@@ -344,9 +402,10 @@ def _kernel(im_ref, jm_ref, flags_ref, q_ref, k_ref, v_ref, *refs,
     def _finalize():
         l = l_ref[:, :1]
         o_ref[0] = (acc_ref[...] / jnp.maximum(l, 1e-30)).astype(o_ref.dtype)
-        # log-sum-exp residual for the backward pass: L = m + log(l); the clamp
-        # keeps fully-masked rows finite so the backward's exp(s - L) is 0, not NaN
-        lse_ref[0] = jnp.maximum(m_ref[:, :1], _NEG_INF / 2) + jnp.log(jnp.maximum(l, 1e-30))
+        if lse_ref is not None:
+            # log-sum-exp residual for the backward pass: L = m + log(l); the clamp
+            # keeps fully-masked rows finite so the backward's exp(s - L) is 0, not NaN
+            lse_ref[0] = jnp.maximum(m_ref[:, :1], _NEG_INF / 2) + jnp.log(jnp.maximum(l, 1e-30))
 
 
 def _kv_group(q, k, v) -> int:
@@ -400,7 +459,7 @@ def _pair_schedule(nq: int, nk: int, bq: int, bk: int, causal: bool, window=None
 )
 def _flash_pallas(q, k, v, causal: bool, scale: float, bq: int, bk: int,
                   interpret: bool = False, bias=None, sub=None, name=None, window=None,
-                  mask=None):
+                  mask=None, k_rope=None, turns=None):
     """q, k: (..., T, d); v: (..., Tk, dv) with its own width (dv != d is the latent-
     attention case: 192 against 128). k and v may have fewer heads (axis -3) than q,
     ``Hq = rep * Hkv``: query head ``h`` reads key/value head ``h // rep`` through the
@@ -409,18 +468,41 @@ def _flash_pallas(q, k, v, causal: bool, scale: float, bq: int, bk: int,
     ``mask`` (causal only): packed words ``(T, mask_words(Tk))`` int32, shared by all heads,
     of the keys each row sees, all of them at or under the row's own position.
     ``sub`` is the step's sub-tile ``(br, bs)``, by default what :func:`_sub_tiles`
-    reads off the blocks. ``name`` names the Pallas call in a device trace."""
+    reads off the blocks. ``name`` names the Pallas call in a device trace.
+
+    ``k_rope`` (the latent form, see :func:`flash_latent`): ``k`` is the ``[k_nope | v]``
+    projection ``(..., H, Tk, dn + dv)`` that the kernel reads as two lane blocks, ``v`` is
+    None, ``k_rope`` ``(..., Tk, dr)`` is the one rope key of all ``H`` heads and q is
+    ``(..., H, T, dn + dr)``; ``turns`` ``(2, T, dr)`` float32, ``[cos | cos]`` and
+    ``[-sin | sin]``, turns q's rope lanes in the kernel. No LSE is written: the second
+    result is None."""
     import jax.experimental.pallas as pl  # ht: ignore[trace-lazy-import] -- pallas imports deferred so CPU-only processes never pay them; runs once per compile, imports nothing of heat_tpu
     from jax.experimental.pallas import tpu as pltpu  # ht: ignore[trace-lazy-import] -- pallas imports deferred so CPU-only processes never pay them; runs once per compile, imports nothing of heat_tpu
 
     with jax.enable_x64(False):
         *batch, tq, d = q.shape
-        tk, dv = k.shape[-2], v.shape[-1]
         bh = math.prod(batch) if batch else 1
-        rep = _kv_group(q, k, v)
-        qr = q.reshape(bh, tq, d)
-        kr = k.reshape(bh // rep, tk, d)
-        vr = v.reshape(bh // rep, tk, dv)
+        dr = 0 if k_rope is None else k_rope.shape[-1]
+        if dr:
+            tk, heads = k.shape[-2], q.shape[-3]
+            dn = d - dr
+            dv = k.shape[-1] - dn
+            rep = 1
+            if (k.shape[:-1] != q.shape[:-1] or k_rope.shape != q.shape[:-3] + (tk, dr)
+                    or dn % _LANES or dv % _LANES or dn % dv
+                    or (turns is not None and (dr % 2 or turns.shape != (2, tq, dr)))):
+                raise ValueError(f"the latent form takes q (..., H, T, dn + dr), [k_nope | v] "
+                                 f"(..., H, T, dn + dv) and k_rope (..., T, dr), dn and dv "
+                                 f"whole lane tiles; got {q.shape}, {k.shape}, {k_rope.shape}")
+            qr = q.reshape(bh, tq, d)
+            kr = k.reshape(bh, tk, dn + dv)
+            rr = k_rope.reshape(bh // heads, tk, dr)
+        else:
+            tk, dv = k.shape[-2], v.shape[-1]
+            rep = _kv_group(q, k, v)
+            qr = q.reshape(bh, tq, d)
+            kr = k.reshape(bh // rep, tk, d)
+            vr = v.reshape(bh // rep, tk, dv)
         has_bias, has_mask = bias is not None, mask is not None
         br, bs = _sub_tiles(bq, bk) if sub is None else sub
         if has_mask and (not causal or window is not None or WORD_KEYS % bk or bs % _LANES
@@ -433,6 +515,8 @@ def _flash_pallas(q, k, v, causal: bool, scale: float, bq: int, bk: int,
         if diagnostics._enabled:  # trace time only: which schedule this trace's steps take
             if has_mask:
                 diagnostics.counter("kernels.dsa.flash")
+            if dr:
+                diagnostics.counter("kernels.flash.fwd.latent")
             diagnostics.counter(
                 "kernels.flash.fwd." + ("serial" if (br, bs) == (bq, bk) else "overlapped"))
             # block pairs the schedule lists against all of them: what causal and band skip
@@ -442,12 +526,26 @@ def _flash_pallas(q, k, v, causal: bool, scale: float, bq: int, bk: int,
         def kv_map(b, p, im, jm, fl):  # grid row b is (batch, query head)
             return (b if rep == 1 else b // rep), jm[p], 0
 
-        in_specs = [
-            pl.BlockSpec((1, bq, d), lambda b, p, im, jm, fl: (b, im[p], 0)),
-            pl.BlockSpec((1, bk, d), kv_map),
-            pl.BlockSpec((1, bk, dv), kv_map),
-        ]
-        inputs = [qr, kr, vr]
+        if dr:
+            # k_nope and v are lane blocks 0 and dn / dv of the one projection; the rope key
+            # is a block of its own, the same for every head of a batch row
+            in_specs = [
+                pl.BlockSpec((1, bq, d), lambda b, p, im, jm, fl: (b, im[p], 0)),
+                pl.BlockSpec((1, bk, dn), kv_map),
+                pl.BlockSpec((1, bk, dv), lambda b, p, im, jm, fl: (b, jm[p], dn // dv)),
+                pl.BlockSpec((1, bk, dr), lambda b, p, im, jm, fl: (b // heads, jm[p], 0)),
+            ]
+            inputs = [qr, kr, kr, rr]
+            if turns is not None:
+                in_specs.append(pl.BlockSpec((2, bq, dr), lambda b, p, im, jm, fl: (0, im[p], 0)))
+                inputs.append(turns.astype(jnp.float32))
+        else:
+            in_specs = [
+                pl.BlockSpec((1, bq, d), lambda b, p, im, jm, fl: (b, im[p], 0)),
+                pl.BlockSpec((1, bk, d), kv_map),
+                pl.BlockSpec((1, bk, dv), kv_map),
+            ]
+            inputs = [qr, kr, vr]
         if has_bias:
             # (Tq, Tk) additive bias, broadcast over batch/heads: one (bq, bk)
             # block streams per pair, like k/v
@@ -460,33 +558,36 @@ def _flash_pallas(q, k, v, causal: bool, scale: float, bq: int, bk: int,
             in_specs.append(pl.BlockSpec(
                 (bq, _LANES), lambda b, p, im, jm, fl: (im[p], jm[p] // (WORD_KEYS // bk))))
             inputs.append(mask)
+        out_specs = [pl.BlockSpec((1, bq, dv), lambda b, p, im, jm, fl: (b, im[p], 0))]
+        out_shape = [jax.ShapeDtypeStruct((bh, tq, dv), q.dtype)]
+        if not dr:  # the LSE, for a backward; the latent form has none
+            out_specs.append(pl.BlockSpec((1, bq, 1), lambda b, p, im, jm, fl: (b, im[p], 0)))
+            out_shape.append(jax.ShapeDtypeStruct((bh, tq, 1), jnp.float32))
+        scratch = [
+            pltpu.VMEM((bq, dv), jnp.float32),
+            pltpu.VMEM((bq, _LANES), jnp.float32),
+            pltpu.VMEM((bq, _LANES), jnp.float32),
+        ]
+        if turns is not None:  # the sweep's query rope lanes, turned
+            scratch.append(pltpu.VMEM((bq, dr), q.dtype))
         grid_spec = pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=3,
             grid=(bh, len(im)),
             in_specs=in_specs,
-            out_specs=[
-                pl.BlockSpec((1, bq, dv), lambda b, p, im, jm, fl: (b, im[p], 0)),
-                pl.BlockSpec((1, bq, 1), lambda b, p, im, jm, fl: (b, im[p], 0)),
-            ],
-            scratch_shapes=[
-                pltpu.VMEM((bq, dv), jnp.float32),
-                pltpu.VMEM((bq, _LANES), jnp.float32),
-                pltpu.VMEM((bq, _LANES), jnp.float32),
-            ],
+            out_specs=out_specs,
+            scratch_shapes=scratch,
         )
-        out, lse = pl.pallas_call(
+        out, *lse = pl.pallas_call(
             functools.partial(_kernel, scale=scale, bq=bq, bk=bk, br=br, bs=bs,
-                              has_bias=has_bias, window=window, has_mask=has_mask),
+                              has_bias=has_bias, window=window, has_mask=has_mask,
+                              rope=dr, turn=turns is not None),
             grid_spec=grid_spec,
-            out_shape=[
-                jax.ShapeDtypeStruct((bh, tq, dv), q.dtype),
-                jax.ShapeDtypeStruct((bh, tq, 1), jnp.float32),
-            ],
+            out_shape=out_shape,
             interpret=interpret,
             compiler_params=None if interpret else _compiler_params(pltpu),
             name=name,
         )(jnp.asarray(im), jnp.asarray(jm), jnp.asarray(flags), *inputs)
-        return out.reshape(*batch, tq, dv), lse.reshape(*batch, tq)
+        return out.reshape(*batch, tq, dv), (lse[0].reshape(*batch, tq) if lse else None)
 
 
 def _dq_kernel(im_ref, jm_ref, flags_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
@@ -781,15 +882,21 @@ def forward_blocks(q, k, v, with_mask: bool = False):
     PR 31), because a row sweep's masked steps, not its skipped keys, set the time.
     ``with_mask``: the call streams packed mask words; every preferred key block divides
     their tile of 4,096 keys."""
-    tq, d = q.shape[-2], q.shape[-1]
-    tk, dv = k.shape[-2], v.shape[-1]
     if any(t.dtype not in (jnp.float32, jnp.bfloat16, jnp.float16) for t in (q, k, v)):
         return None
-    itemsize = jnp.dtype(q.dtype).itemsize
+    return _preferred_blocks(q.shape[-2], k.shape[-2], q.shape[-1], v.shape[-1],
+                             jnp.dtype(q.dtype).itemsize, with_mask)
+
+
+def _preferred_blocks(tq: int, tk: int, d: int, dv: int, itemsize: int, with_mask: bool,
+                      turned: int = 0):
+    """The first of the preferences for ``itemsize`` that tiles ``(tq, tk)``, keeps the pair
+    list in SMEM and fits the budget by :func:`_fwd_footprint`, or None."""
     for bq, bk in _FWD_BLOCK_PREFS.get(itemsize, ((512, 512),)):
         if tq % bq or tk % bk or (tq // bq) * (tk // bk) > _MAX_PAIRS:
             continue
-        if _fwd_footprint(bq, bk, d, dv, itemsize, with_mask=with_mask) <= _vmem_budget():
+        if _fwd_footprint(bq, bk, d, dv, itemsize, with_mask=with_mask,
+                          turned=turned) <= _vmem_budget():
             return bq, bk
     return None
 
@@ -805,6 +912,48 @@ def flash_forward(q, k, v, causal: bool, scale: float, blocks, name=None,
     gradient is defined on this entry."""
     out, _ = _flash_pallas(q, k, v, causal, float(scale), *blocks, interpret=interpret,
                            name=name, window=window, mask=mask)
+    return out
+
+
+def latent_blocks(q, kv, k_rope, with_mask: bool = False, turned: bool = False):
+    """The largest preferred ``(bq, bk)`` with which :func:`flash_latent` runs these operands
+    (arrays or shapes), or None: q ``(..., H, T, dn + dr)``, ``kv`` ``(..., H, T, dn + dv)``,
+    ``k_rope`` ``(..., T, dr)``, the widths ``dn`` and ``dv`` whole lane tiles with ``dv``
+    dividing ``dn`` (so that k_nope and v are lane blocks of ``kv``), and what
+    :func:`forward_blocks` asks of the concatenated operands, the turned rope lanes' table
+    and copy counted (``turned``)."""
+    dr = k_rope.shape[-1]
+    dn, tk = q.shape[-1] - dr, kv.shape[-2]
+    dv = kv.shape[-1] - dn
+    if (q.ndim < 3 or kv.shape[:-1] != q.shape[:-1] or k_rope.shape != q.shape[:-3] + (tk, dr)
+            or dn <= 0 or dv <= 0 or dn % _LANES or dv % _LANES or dn % dv
+            or (turned and dr % 2)):
+        return None
+    if any(t.dtype not in (jnp.float32, jnp.bfloat16, jnp.float16) or t.dtype != q.dtype
+           for t in (kv, k_rope)):
+        return None
+    return _preferred_blocks(q.shape[-2], tk, dn + dr, dv, jnp.dtype(q.dtype).itemsize,
+                             with_mask, dr if turned else 0)
+
+
+def flash_latent(q, kv, k_rope, scale: float, blocks, turns=None, name=None,
+                 interpret: bool = False, mask=None):
+    """Causal latent attention with its operands as the projections leave them: q
+    ``(..., H, T, dn + dr)``, ``kv`` the ``[k_nope | v]`` projection ``(..., H, T, dn + dv)``
+    read in place as two lane blocks, and ``k_rope`` ``(..., T, dr)``, the one rope key of all
+    ``H`` heads. Head ``h``'s key is ``[k_nope_h | k_rope]`` and its value ``v_h``; nothing is
+    put together or repeated in HBM. ``turns``: ``(cos, sin)``, each ``(T, dr / 2)`` float32,
+    the rotary positions of q's rope lanes as ``rotate_halves`` takes them (the halves
+    layout), applied in the kernel; None where q's rope lanes are used as they are. ``mask``
+    as in :func:`flash_forward`; ``blocks`` is what :func:`latent_blocks` chose. Returns
+    ``(..., H, T, dv)``; no gradient is defined on this entry."""
+    table = None
+    if turns is not None:
+        cos, sin = turns
+        table = jnp.stack([jnp.concatenate([cos, cos], axis=-1),
+                           jnp.concatenate([-sin, sin], axis=-1)])
+    out, _ = _flash_pallas(q, kv, None, True, float(scale), *blocks, interpret=interpret,
+                           name=name, mask=mask, k_rope=k_rope, turns=table)
     return out
 
 

@@ -44,7 +44,9 @@ from ..core.kernels import sparse_index
 from ..core.kernels.flash_attention import (
     flash_attention,
     flash_forward,
+    flash_latent,
     forward_blocks,
+    latent_blocks,
     use_flash,
 )
 
@@ -723,6 +725,15 @@ def _yarn_mscale(scaling: Optional[dict], key: str) -> float:
     return 0.1 * scaling[key] * math.log(scaling["factor"]) + 1.0
 
 
+def rope_turns(t: int, inv_freq, magnitude: float = 1.0):
+    """``(cos, sin)``, each ``(t, len(inv_freq))`` float32: position ``p``'s pair ``i`` turns
+    by ``p * inv_freq[i]``, both scaled by ``magnitude``. What :func:`rotate_halves` applies,
+    and what the latent flash kernel takes to apply the same turns itself."""
+    angle = jnp.arange(t, dtype=jnp.int32).astype(jnp.float32)[:, None] \
+        * jnp.asarray(inv_freq, jnp.float32)[None, :]
+    return jnp.cos(angle) * jnp.float32(magnitude), jnp.sin(angle) * jnp.float32(magnitude)
+
+
 def rotate_halves(x, inv_freq, magnitude: float = 1.0):
     """Rotary positions on ``x`` (..., T, rope_dim) laid out as two halves ``[a | b]``:
     the pair ``(a[i], b[i])`` at position ``t`` (the index on axis -2) turns by
@@ -730,11 +741,8 @@ def rotate_halves(x, inv_freq, magnitude: float = 1.0):
     type. A layout of interleaved pairs ``(x[2i], x[2i+1])`` becomes this one by taking
     the even columns first (:func:`even_then_odd`); on the TPU the halves are two lane
     slices, where interleaved pairs would put a dimension of 2 on the lanes."""
-    t, half = x.shape[-2], x.shape[-1] // 2
-    angle = jnp.arange(t, dtype=jnp.int32).astype(jnp.float32)[:, None] \
-        * jnp.asarray(inv_freq, jnp.float32)[None, :]
-    cos = jnp.cos(angle) * jnp.float32(magnitude)
-    sin = jnp.sin(angle) * jnp.float32(magnitude)
+    half = x.shape[-1] // 2
+    cos, sin = rope_turns(x.shape[-2], inv_freq, magnitude)
     a, b = x[..., :half].astype(jnp.float32), x[..., half:].astype(jnp.float32)
     return jnp.concatenate([a * cos - b * sin, a * sin + b * cos], axis=-1).astype(x.dtype)
 
@@ -852,10 +860,13 @@ class MultiheadLatentAttention(Module):
     This is the whole-sequence forward (scoring, prefill): no key/value cache and no
     absorbed products. On TPU the core runs in the flash Pallas kernel at
     ``d_qk != d_v``, named ``mla_flash_fwd`` in device traces, or ``dsa_flash_fwd`` under a
-    selection, whose packed words it reads; where it does not apply
-    (another backend, a sequence that does not tile) the XLA path runs and
-    ``record_fallback("nn.mla", ...)`` says why. Parameters are stored in ``dtype``
-    (norm weights float32); contractions accumulate in float32.
+    selection, whose packed words it reads. Where the nope and value widths are whole lane
+    tiles it takes the operands as the projections leave them (``flash_latent``): q with
+    its rope lanes unturned (the kernel turns them), ``[k_nope | v]`` read in place and the
+    one rope key for all heads; elsewhere it takes a concatenated q and k and a sliced v.
+    Where neither applies (another backend, a sequence that does not tile) the XLA path runs
+    on the concatenated operands and ``record_fallback("nn.mla", ...)`` says why. Parameters
+    are stored in ``dtype`` (norm weights float32); contractions accumulate in float32.
     """
 
     def __init__(self, dim: int, num_heads: int, q_lora_rank: Optional[int], kv_lora_rank: int,
@@ -942,6 +953,16 @@ class MultiheadLatentAttention(Module):
         p = jax.nn.softmax(s, axis=-1).astype(v.dtype)
         return contract("...qk,...kd->...qd", p, v).astype(q.dtype)
 
+    def _latent_blocks(self, q, selection):
+        """The blocks of the latent operand form for the projected ``q``, or None where it
+        does not apply: another backend, or what ``latent_blocks`` declines."""
+        if jax.default_backend() != "tpu":
+            return None
+        t = q.shape[-2]
+        kv = jax.ShapeDtypeStruct(q.shape[:-1] + (self.nope + self.v_dim,), q.dtype)
+        k_rope = jax.ShapeDtypeStruct(q.shape[:-3] + (t, self.rope), q.dtype)
+        return latent_blocks(q, kv, k_rope, selection is not None, self.inv_freq is not None)
+
     def _latent(self, params, x):
         """``(c_kv, k_rope)``: the normed key/value latent and the one rotated rope key."""
         if self.inv_freq is None:  # no positions: the shared key part as projected
@@ -968,15 +989,24 @@ class MultiheadLatentAttention(Module):
             # taken even ones first: rotate_halves then turns the published pairs
             wq_b = even_then_odd(wq.reshape(wq.shape[0], h, dn + dr), dn)
             q = contract("...tr,rhe->...hte", c_q, wq_b).astype(dt)
+        blocks = self._latent_blocks(q, selection)
+        if blocks is None and self.inv_freq is not None:
             q = jnp.concatenate(
                 [q[..., :dn], rotate_halves(q[..., dn:], self.inv_freq, self.rope_magnitude)],
                 axis=-1)
         c_kv, k_rope = latent()
         kv_h = contract("...tr,rhe->...hte", c_kv,
                         wkv_b.reshape(self.kv_lora_rank, h, dn + dv)).astype(dt)
-        k_rope = jnp.broadcast_to(k_rope[..., None, :, :], kv_h.shape[:-1] + (dr,))
-        k = jnp.concatenate([kv_h[..., :dn], k_rope], axis=-1)
-        o = self._core(q, k, kv_h[..., dn:], selection)
+        if blocks is not None:
+            turns = (None if self.inv_freq is None
+                     else rope_turns(q.shape[-2], self.inv_freq, self.rope_magnitude))
+            o = flash_latent(q, kv_h, k_rope, self.scale, blocks, turns,
+                             name="mla_flash_fwd" if selection is None else "dsa_flash_fwd",
+                             mask=selection)
+        else:
+            k_rope = jnp.broadcast_to(k_rope[..., None, :, :], kv_h.shape[:-1] + (dr,))
+            k = jnp.concatenate([kv_h[..., :dn], k_rope], axis=-1)
+            o = self._core(q, k, kv_h[..., dn:], selection)
         if wg is not None:
             gate = jax.nn.sigmoid(contract("...td,dh->...ht", x, wg))
             o = (o.astype(jnp.float32) * gate[..., None]).astype(dt)

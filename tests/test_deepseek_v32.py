@@ -268,6 +268,7 @@ def test_one_trace_for_repeated_calls_and_the_counters():
         assert counters["fallback.nn.dsa"] == 3 and counters["fallback.nn.mla"] == 3
         assert counters["nn.dsa.selected"] == 3 * 3600 and counters["nn.dsa.causal"] == 3 * 8256
         assert counters["nn.moe.tokens"] == 2 * T * 4
+        assert counters.get("kernels.flash.fwd.latent", 0) == 0  # the XLA path on the CPU
     finally:
         diagnostics.disable()
         diagnostics.reset()
@@ -275,6 +276,39 @@ def test_one_trace_for_repeated_calls_and_the_counters():
         model(tokens[None])
     with pytest.raises(ValueError, match="one document"):
         model.layers[0].attn.apply(params["layers"][0]["attn"], jnp.zeros((2, T, D)))
+
+
+def test_latent_form_through_the_interpreted_kernel(monkeypatch):
+    """The attention layer at the published head widths (128 + 64 / 128), with its indexer's
+    selection, YaRN and two head groups, on the TPU path with the flash kernel interpreted: it
+    takes the latent operand form (``kernels.flash.fwd.latent`` 1 after a first call, no new
+    trace after a second, ``fallback.nn.mla`` 0) and gives what the XLA path on the concatenated
+    operands gives, in float32 to 1e-5."""
+    m = attention.MultiheadLatentAttention(64, 4, 48, 32, 128, 64, 128, CFG["rope_theta"],
+                                           CFG["rope_scaling"], 1e-6, jnp.float32, 0.1,
+                                           index=(8, 128, 64), head_groups=2)
+    params = m.init(jax.random.key(31))
+    x = jax.random.normal(jax.random.key(32), (1024, 64), jnp.float32)
+    want, plain = m.apply(params, x)  # the CPU: the XLA path
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    monkeypatch.setattr(sparse_index, "available", lambda interpret=False: False)
+    monkeypatch.setattr(attention, "flash_latent",
+                        functools.partial(attention.flash_latent, interpret=True))
+    diagnostics.enable()
+    try:
+        diagnostics.reset()
+        got, chosen = m.apply(params, x)
+        counters = diagnostics.report()["counters"]
+        assert counters["kernels.flash.fwd.latent"] == 1
+        assert counters.get("fallback.nn.mla", 0) == 0
+        diagnostics.reset()
+        m.apply(params, x)
+        assert diagnostics.report()["counters"].get("kernels.flash.fwd.latent", 0) == 0
+    finally:
+        diagnostics.disable()
+        diagnostics.reset()
+    assert np.array_equal(np.asarray(chosen["selection"]), np.asarray(plain["selection"]))
+    assert gap(got, want) < 1e-5
 
 
 def test_expert_layers_through_the_interpreted_kernels_walked_in_slabs(monkeypatch):

@@ -545,12 +545,15 @@ def test_the_delta_rule_inverse_multiplies_pairs_of_heads_128_deep(cell):
     assert sum(rhs == (4, 128, 128) for _, rhs in shapes) == 20
 
 
-@pytest.mark.parametrize("cell,digest", [("xing4", "dcfe7c5a64ba00db"), ("ling", "265c23cce35d619d")])
+@pytest.mark.parametrize("cell,digest", [("xing4", "fb0198571288fe28"), ("ling", "fd2a53a07f906462")])
 def test_latent_attention_with_positions_traces_what_it_traced_before_nope(cell, digest, monkeypatch):
     """``MultiheadLatentAttention`` with rotary positions at the Xing4 cell's settings (query
     latent, YaRN) and at the Ling cell's (direct query, head gate), 32,768 tokens in bfloat16 on
-    the TPU path (``mla_flash_fwd``): the jaxpr is, character for character, that of the commit
-    before the switch to no positions was added (the digests were taken on that commit)."""
+    the TPU path (``mla_flash_fwd``): the jaxpr is, character for character, a fixed one. The
+    digests were taken on the commit before the switch to no positions was added, and taken
+    again when the latent operand form came in (q, ``[k_nope | v]`` and the one rope key into
+    the kernel as the projections leave them, the query's rope lanes turned in the kernel, no
+    LSE), which changed that and nothing else."""
     import hashlib
 
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
@@ -817,3 +820,151 @@ def test_mosaic_compiles_the_masked_flash_forward_at_the_dsv32_cell_shape(one_ch
     text = compiled.as_text()
     assert "tpu_custom_call" in text and "dsa_flash_fwd" in text
     assert not [line for line in text.splitlines() if " copy(" in line and "s32[32768,1024]" in line]
+
+
+# ------------------------- the latent operand form of the flash forward
+def _latent_operands(h, t, dtype, seed=0):
+    """q ``(h, t, 128 + 64)``, ``[k_nope | v]`` ``(h, t, 128 + 128)``, the rope key ``(t, 64)``
+    and its YaRN-like turns ``(cos, sin)`` at a magnitude that is not 1."""
+    from heat_tpu.nn.attention import rope_turns, yarn_inv_freq
+
+    kq, kkv, kr = jax.random.split(jax.random.key(seed), 3)
+    q = jax.random.normal(kq, (h, t, 192)).astype(dtype)
+    kv = jax.random.normal(kkv, (h, t, 256)).astype(dtype)
+    k_rope = jax.random.normal(kr, (t, 64)).astype(dtype)
+    inv_freq = yarn_inv_freq(64, 10000.0, {"factor": 40, "original_max_position_embeddings": 256,
+                                            "beta_fast": 32, "beta_slow": 1})
+    return q, kv, k_rope, inv_freq, rope_turns(t, inv_freq, 0.9)
+
+
+@pytest.mark.parametrize("h,t,blocks,dtype,turned,masked", [
+    (2, 1024, (512, 512), jnp.float32, False, False),
+    (2, 1024, (512, 512), jnp.float32, True, False),
+    (2, 1024, (256, 512), jnp.bfloat16, True, True),
+    (16, 512, (256, 256), jnp.bfloat16, True, False),
+    (16, 1024, (512, 512), jnp.bfloat16, False, True),
+    (2, 8192, (1024, 1024), jnp.float32, True, True)], ids=str)
+def test_interpreted_latent_form_is_the_concatenated_call(h, t, blocks, dtype, turned, masked):
+    """``flash_latent`` on the operands as the projections leave them against
+    ``flash_forward`` on the concatenated q and k (the query's rope lanes turned by
+    ``rotate_halves``, the rope key repeated for every head) and the sliced v: with and
+    without rotary positions, with and without a packed mask, 2 and 16 heads, key blocks that
+    share a tile of words (T 1,024) and ones that start the next (T 8,192 = two tiles). In
+    float32 within 1e-5; in bfloat16 within one rounding at the output's scale (half a
+    bfloat16 step at its largest magnitude): a turned query element may round the other way
+    where the interpreter contracts a product and a sum, which moves the outputs near 0."""
+    from heat_tpu.core.kernels import sparse_index
+    from heat_tpu.nn.attention import rotate_halves
+
+    q, kv, k_rope, inv_freq, turns = _latent_operands(h, t, dtype)
+    mask = None
+    if masked:
+        picked = sparse_index.select_plain(jax.random.normal(jax.random.key(3), (t, t)), max(t // 26, 20))
+        mask = sparse_index.pack_mask(picked)
+    got = flash_kernel.flash_latent(q, kv, k_rope, 0.07, blocks, turns if turned else None,
+                                    interpret=True, mask=mask)
+    full_q = jnp.concatenate([q[..., :128], rotate_halves(q[..., 128:], inv_freq, 0.9)], -1) if turned else q
+    k = jnp.concatenate([kv[..., :128], jnp.broadcast_to(k_rope, (h, t, 64))], -1)
+    want = flash_kernel.flash_forward(full_q, k, kv[..., 128:], True, 0.07, blocks,
+                                      interpret=True, mask=mask)
+    assert got.shape == want.shape == (h, t, 128) and got.dtype == dtype
+    got, want = np.asarray(got, np.float32), np.asarray(want, np.float32)
+    if dtype == jnp.float32:
+        assert np.max(np.abs(got - want)) <= 1e-5
+    else:
+        assert np.max(np.abs(got - want)) <= np.max(np.abs(want)) * 2.0 ** -8
+    with pytest.raises(ValueError, match="latent form takes"):
+        flash_kernel.flash_latent(q[..., :160], kv, k_rope, 0.07, blocks, interpret=True)
+
+
+def _dsv32_group_heads_jaxpr(monkeypatch):
+    """The jaxpr of ``MultiheadLatentAttention._heads`` for one group of 16 heads at
+    ``dsv32-score-32k``'s shapes (32,768 tokens, bfloat16, the indexer's packed words) on the
+    TPU path, and the shapes of the group's two projections."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    yarn = {"beta_fast": 32, "beta_slow": 1, "factor": 40, "mscale": 1, "mscale_all_dim": 1,
+            "original_max_position_embeddings": 4096, "type": "yarn"}
+    m = ht.nn.MultiheadLatentAttention(7168, 128, 1536, 512, 128, 64, 128, 10000, yarn, 1e-6,
+                                       jnp.bfloat16, 0.1, index=(64, 128, 2048), head_groups=8)
+    t, h = 32768, 16
+
+    def shaped(*shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype)
+
+    def heads(x, c_q, c_kv, k_rope, words, wq, wkv_b, wo):
+        return m._heads(x, c_q, lambda: (c_kv, k_rope), words, wq, wkv_b, wo)
+
+    jaxpr = jax.make_jaxpr(heads)(
+        shaped(t, 7168), shaped(t, 1536), shaped(t, 512), shaped(t, 64),
+        shaped(t, 1024, dtype=jnp.int32), shaped(1536, h * 192), shaped(512, h * 256),
+        shaped(h * 128, 7168))
+    return jaxpr, (h, t, 192), (h, t, 256)
+
+
+def _eqns(jaxpr):
+    """Every equation of a jaxpr and of its sub-jaxprs, with the jaxpr it lies in."""
+    for eqn in jaxpr.eqns:
+        yield eqn, jaxpr
+        for param in eqn.params.values():
+            for sub in param if isinstance(param, (tuple, list)) else (param,):
+                sub = getattr(sub, "jaxpr", sub)
+                if hasattr(sub, "eqns"):
+                    yield from _eqns(sub)
+
+
+def test_latent_attention_takes_the_projections_as_they_leave(monkeypatch):
+    """At ``dsv32-score-32k``'s group of 16 heads on the TPU path nothing is put together in
+    HBM: no ``broadcast_in_dim`` makes the rope key ``(16, T, 64)``, no ``concatenate`` makes a
+    ``(16, T, 192)`` q or k, and the Pallas call (``dsa_flash_fwd``, one output: no LSE) reads the
+    ``[k_nope | v]`` projection itself, twice, as two lane blocks."""
+    jaxpr, q_shape, kv_shape = _dsv32_group_heads_jaxpr(monkeypatch)
+    h, t = q_shape[:2]
+    eqns = list(_eqns(jaxpr.jaxpr))
+    made = [(e.primitive.name, tuple(v.aval.shape)) for e, _ in eqns for v in e.outvars]
+    assert ("broadcast_in_dim", (h, t, 64)) not in made
+    assert ("concatenate", q_shape) not in made
+    kv_h = [v for e, _ in eqns for v in e.outvars
+            if tuple(v.aval.shape) == kv_shape and v.aval.dtype == jnp.bfloat16]
+    assert len(kv_h) == 1  # the projection, in the layer's type
+    call = [e for e, _ in eqns if e.primitive.name in ("jit", "pjit") and kv_h[0] in e.invars]
+    assert len(call) == 1 and call[0].params["name"] == "_flash_pallas"
+    inner = call[0].params["jaxpr"].jaxpr
+    kv_in = inner.invars[list(call[0].invars).index(kv_h[0])]
+    pallas = [e for e in inner.eqns if e.primitive.name == "pallas_call"]
+    assert len(pallas) == 1 and list(pallas[0].invars).count(kv_in) == 2
+    assert len(pallas[0].outvars) == 1 and "dsa_flash_fwd" in str(pallas[0].params)
+
+
+@pytest.mark.parametrize("h,turned,masked,name", [
+    (32, True, False, "mla_flash_fwd"), (16, True, True, "dsa_flash_fwd"),
+    (32, False, False, "mla_flash_fwd")], ids=["xing4_ling", "dsv32", "kimi"])
+def test_mosaic_compiles_the_latent_form_at_the_cell_shapes(one_chip, h, turned, masked, name):
+    """The latent operand form at the four latent cells' shapes, 32,768 tokens in bfloat16: 32
+    heads with the query's rope lanes turned in the kernel (Xing4, Ling), a group of 16 under
+    the packed words (DeepSeek-V3.2), 32 without positions (Kimi-Linear), at (1024, 1024)
+    blocks, the table and the turned copy inside the footprint's budget, and Mosaic's default
+    VMEM scope: the call has one output and no copy of ``[k_nope | v]`` enters the program."""
+    from heat_tpu.core.kernels import sparse_index
+
+    t = 32768
+
+    def shaped(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    q, kv, k_rope = shaped((h, t, 192)), shaped((h, t, 256)), shaped((t, 64))
+    words = shaped((t, sparse_index.mask_words(t)), jnp.int32)
+    cos = shaped((t, 32), jnp.float32)
+    blocks = flash_kernel.latent_blocks(q, kv, k_rope, masked, turned)
+    assert blocks == (1024, 1024)
+    assert flash_kernel._fwd_footprint(*blocks, 192, 128, 2, with_mask=masked,
+                                       turned=64 if turned else 0) <= flash_kernel._VMEM_BUDGET
+
+    def call(q, kv, k_rope, words, cos, sin):
+        return flash_kernel.flash_latent(q, kv, k_rope, 0.07, blocks, (cos, sin) if turned else None,
+                                         name=name, mask=words if masked else None)
+
+    text = jax.jit(call).lower(q, kv, k_rope, words, cos, cos).compile().as_text()
+    assert "tpu_custom_call" in text and name in text
+    assert not [line for line in text.splitlines() if " copy(" in line and f"bf16[{h},32768,256]" in line]
+    assert not [line for line in text.splitlines() if "custom-call(" in line and f"f32[{h},32768,1]" in line]
+
