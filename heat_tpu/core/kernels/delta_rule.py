@@ -99,8 +99,8 @@ is sequential. The rows before a chunk are a second, 16-row view of the same thr
 :func:`kda_mix_reference` is the same chunk step on all heads at once under ``lax.scan``
 over the chunks, in plain ``jnp``: the fallback of ``nn/kda.py`` on other backends and
 shapes (it pads a sequence that does not tile), and what the tests hold the interpreted
-kernel to, beside the token-by-token recurrences of ``tests/reference_ling.py`` and
-``tests/reference_kimi_linear.py``.
+kernel to, beside the token-by-token recurrences of the benchmark's
+``reference_ling.py`` and ``reference_kimi_linear.py``.
 
 No reference counterpart.
 """

@@ -2,7 +2,7 @@
 learned index picks the keys each query attends to (DeepSeek sparse attention), and
 token-routed experts in groups.
 
-Pre-norm layers (:class:`~.ling.LingBlock`'s form): latent attention with a query latent
+Pre-norm layers (:class:`~.scoring.PreNormBlock`): latent attention with a query latent
 and YaRN frequencies (:class:`~.attention.MultiheadLatentAttention`) whose
 :class:`~.attention.LightningIndexer` scores every earlier token for a query and keeps the
 ``index_topk`` best, one set for all heads; a gated feed-forward, dense in the leading
@@ -12,7 +12,7 @@ layers and token-routed experts after (:class:`~.moe.MoE`: sigmoid scores, a sel
 what is left out (the multi-token-prediction module, the indexer's 8-bit arithmetic and its
 Hadamard rotation, caches, decode).
 
-The request is *scoring* (:mod:`.scoring`, shared with the other three models):
+The request is *scoring* (:mod:`.scoring`, shared with the other four models):
 ``model(tokens)`` runs through :meth:`Module.__call__`, the whole forward is **one compiled
 program a call** (``nn.dsv32.traces`` counts its traces), and only the positions that score
 the continuation go through the head. A sliced vocabulary is a smaller vocabulary.
@@ -39,10 +39,9 @@ from jax import lax
 
 from ..core import diagnostics
 from .attention import MultiheadLatentAttention
-from .ling import NORM_INIT_STD, LingBlock
-from .modules import GatedMLP, Module, RMSNorm, normal_weight
+from .modules import GatedMLP, Module
 from .moe import MoE
-from .scoring import ScoringForward, score
+from .scoring import NORM_INIT_STD, PreNormBlock, ScoringForward
 
 __all__ = ["DeepseekV32", "DeepseekV32Block", "DeepseekV32Config", "DeepseekV32Scores"]
 
@@ -144,73 +143,46 @@ class _InPieces(Module):
                                   "load": jnp.sum(aux["load"], axis=0, dtype=jnp.int32)}
 
 
-class DeepseekV32Block(LingBlock):
-    """One layer on tokens ``(T, d)``, :class:`~.ling.LingBlock`'s two steps: latent attention
-    over the indexer's selection, then a dense or a routed feed-forward. ``apply`` returns
-    ``(x, aux)``; ``aux`` holds the layer's ``"selection"`` and, of an expert layer,
+class DeepseekV32Block(PreNormBlock):
+    """One layer on tokens ``(T, d)``, :class:`~.scoring.PreNormBlock`'s two steps: latent
+    attention over the indexer's selection, then a dense or a routed feed-forward. ``apply``
+    returns ``(x, aux)``; ``aux`` holds the layer's ``"selection"`` and, of an expert layer,
     ``"chosen"`` and ``"load"``."""
 
     def __init__(self, config: DeepseekV32Config, dense: bool,
                  experts_held: Optional[Tuple[int, int]] = None, dtype=jnp.bfloat16,
                  block_rows: int = BLOCK_ROWS, head_groups: int = 1, ffn_pieces: int = 1):
         c = config
-        self.attn_norm = RMSNorm(c.hidden_size, c.rms_norm_eps, NORM_INIT_STD)
-        self.attn = MultiheadLatentAttention(
+        attn = MultiheadLatentAttention(
             c.hidden_size, c.num_attention_heads, c.q_lora_rank, c.kv_lora_rank,
             c.qk_nope_head_dim, c.qk_rope_head_dim, c.v_head_dim, c.rope_theta, c.rope_scaling,
             c.rms_norm_eps, dtype, NORM_INIT_STD,
             index=(c.index_n_heads, c.index_head_dim, c.index_topk), head_groups=head_groups)
-        self.ffn_norm = RMSNorm(c.hidden_size, c.rms_norm_eps, NORM_INIT_STD)
         if dense:
             ffn = GatedMLP(c.hidden_size, c.intermediate_size, dtype)
         else:
             ffn = MoE(c.hidden_size, c.moe_intermediate_size, c.n_routed_experts,
                       c.num_experts_per_tok, c.n_shared_experts, c.routed_scaling_factor,
                       experts_held, block_rows, dtype, c.n_group, c.topk_group)
-        self.ffn = _InPieces(ffn, ffn_pieces)
+        super().__init__(c, attn, _InPieces(ffn, ffn_pieces))
 
 
 class DeepseekV32(ScoringForward):
     """``DeepseekV32(config)(tokens)``: the scoring forward of one document ``tokens`` (T,)
-    int32, returning :class:`DeepseekV32Scores`.
+    int32, returning :class:`DeepseekV32Scores`; the arguments are
+    :class:`~.scoring.ScoringForward`'s (``config`` a :class:`DeepseekV32Config`,
+    ``block_rows`` :data:`BLOCK_ROWS` by default) and ``head_groups`` and ``ffn_pieces``, which
+    cut the attention's heads and the feed-forward's tokens into parts that run one after
+    another (the results are the uncut ones; see the module's text)."""
 
-    ``config`` is a :class:`DeepseekV32Config` or the ``config.json`` dictionary;
-    ``continuation`` is the number of trailing tokens that are scored; ``experts_held =
-    (first, count)`` is the share of every expert layer that lives here (all by default, see
-    :class:`~.moe.MoE`); ``block_rows`` is the block every held expert's group of rows is
-    padded to (:data:`BLOCK_ROWS` by default); ``head_groups`` and ``ffn_pieces`` cut the
-    attention's heads and the feed-forward's tokens into parts that run one after another (the
-    results are the uncut ones; see the module's text). Parameters are stored in ``dtype`` (norms and router
-    float32) and activations follow it.
-    """
-
+    config_type = DeepseekV32Config
     traces = "nn.dsv32.traces"
+    block_rows = BLOCK_ROWS
 
-    def __init__(self, config, continuation: int = 128,
-                 experts_held: Optional[Tuple[int, int]] = None, dtype=jnp.bfloat16,
-                 block_rows: int = BLOCK_ROWS, head_groups: int = 1, ffn_pieces: int = 1):
-        if not isinstance(config, DeepseekV32Config):
-            config = DeepseekV32Config.from_dict(config)
-        self.config = c = config
-        self.continuation = continuation
-        self.dtype = jnp.dtype(dtype)
-        self.layers = [
-            DeepseekV32Block(c, i < c.first_k_dense_replace, experts_held, dtype, block_rows,
-                             head_groups, ffn_pieces)
-            for i in range(c.num_hidden_layers)
-        ]
-        self.norm = RMSNorm(c.hidden_size, c.rms_norm_eps, NORM_INIT_STD)
-
-    def init(self, key):
-        c, dt = self.config, self.dtype
-        d = c.hidden_size
-        k_embed, k_head, k_norm, *k_layers = jax.random.split(key, 3 + len(self.layers))
-        return {
-            "embed": {"weight": normal_weight(k_embed, (c.vocab_size, d), dt, 1.0)},
-            "layers": [layer.init(k) for layer, k in zip(self.layers, k_layers)],
-            "norm": self.norm.init(k_norm),
-            "head": {"weight": normal_weight(k_head, (d, c.vocab_size), dt, d ** -0.5)},
-        }
+    def _layer(self, config, index, experts_held, dtype, block_rows, head_groups: int = 1,
+               ffn_pieces: int = 1):
+        return DeepseekV32Block(config, index < config.first_k_dense_replace, experts_held,
+                                dtype, block_rows, head_groups, ffn_pieces)
 
     def sampled_queries(self, t: int) -> np.ndarray:
         """The positions whose selection a forward of ``t`` tokens returns: every
@@ -222,17 +194,14 @@ class DeepseekV32(ScoringForward):
         t = tokens.shape[0]
         targets = tokens[t - self.continuation:]
         sample = jnp.asarray(self.sampled_queries(t))
-        x = params["embed"]["weight"][tokens]
-        routed, selected, kept = [], [], []
-        for block, p in zip(self.layers, params["layers"]):
-            x, aux = block.apply(p, x)
+        selected, kept = [], []
+
+        def seen(aux):  # every layer's selection, sampled and counted as the layer leaves it
             selected.append(aux["selection"][sample])
             kept.append(jnp.sum(lax.population_count(aux["selection"]), dtype=jnp.int32))
-            if "chosen" in aux:
-                routed.append(aux)
-        logits, loglik = score(self.norm, params["norm"], params["head"], x, targets)
-        return DeepseekV32Scores(logits, loglik, jnp.stack([a["chosen"] for a in routed]),
-                                 jnp.stack([a["load"] for a in routed]), jnp.stack(selected),
+
+        x, routed = self._walk(params, self._embed(params, tokens), seen)
+        return DeepseekV32Scores(*self._scores(params, targets, x, routed), jnp.stack(selected),
                                  jnp.stack(kept))
 
     def readback(self, scores) -> Tuple[float, ...]:

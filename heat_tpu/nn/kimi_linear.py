@@ -2,7 +2,7 @@
 softplus decay, a low-rank decay projection and a gate a channel, latent attention without
 positions, and token-routed experts with no group limit.
 
-Pre-norm layers (:class:`~.ling.LingBlock`'s form) whose token mixing is of two kinds, named
+Pre-norm layers (:class:`~.scoring.PreNormBlock`) whose token mixing is of two kinds, named
 by the published lists ``linear_attn_config.kda_layers`` and ``full_attn_layers`` (1-indexed):
 Kimi Delta Attention (:class:`~.kda.KimiDeltaAttention`, the softplus kind, ``W_f`` and the
 channel gate as rank-``head_dim`` pairs) or latent attention with a direct query and no
@@ -25,19 +25,17 @@ No reference counterpart.
 from __future__ import annotations
 
 import dataclasses
-from typing import NamedTuple, Optional, Tuple
+from typing import Optional, Tuple
 
-import jax
 import jax.numpy as jnp
 
 from .attention import MultiheadLatentAttention
 from .kda import KimiDeltaAttention
-from .ling import NORM_INIT_STD, LingBlock
-from .modules import GatedMLP, RMSNorm, normal_weight
+from .modules import GatedMLP
 from .moe import MoE
-from .scoring import ScoringForward, score
+from .scoring import NORM_INIT_STD, PreNormBlock, ScoringForward
 
-__all__ = ["KimiLinear", "KimiLinearBlock", "KimiLinearConfig", "KimiLinearScores"]
+__all__ = ["KimiLinear", "KimiLinearBlock", "KimiLinearConfig"]
 
 # 1,024 tokens a held expert on average (32,768 x 8 / 256): a group is padded by half a block
 # on average, so the block is a quarter of the mean group
@@ -115,95 +113,43 @@ class KimiLinearConfig:
         return index + 1 in self.full_attn_layers
 
 
-class KimiLinearScores(NamedTuple):
-    """What one scoring forward returns, all on the device. ``logits`` (c, vocab): the head
-    at positions ``T-1-c .. T-2``, which score the last ``c`` tokens; ``loglik``: their
-    log-likelihood (a float32 scalar); ``chosen`` (expert layers, T, k) and ``load`` (expert
-    layers, experts held): every expert layer's routing and the rows each held expert
-    multiplied."""
-
-    logits: jax.Array
-    loglik: jax.Array
-    chosen: jax.Array
-    load: jax.Array
-
-
-class KimiLinearBlock(LingBlock):
-    """One layer on tokens ``(T, d)``, :class:`~.ling.LingBlock`'s two steps: KDA or latent
-    attention, then a dense or a routed feed-forward. ``apply`` returns ``(x, aux)``; ``aux``
-    holds an expert layer's ``"chosen"`` and ``"load"``, or is None."""
+class KimiLinearBlock(PreNormBlock):
+    """One layer on tokens ``(T, d)``, :class:`~.scoring.PreNormBlock`'s two steps: KDA or
+    latent attention, then a dense or a routed feed-forward."""
 
     def __init__(self, config: KimiLinearConfig, latent: bool, dense: bool,
                  experts_held: Optional[Tuple[int, int]] = None, dtype=jnp.bfloat16,
                  block_rows: int = BLOCK_ROWS):
         c = config
-        self.attn_norm = RMSNorm(c.hidden_size, c.rms_norm_eps, NORM_INIT_STD)
         if latent:
-            self.attn = MultiheadLatentAttention(
+            attn = MultiheadLatentAttention(
                 c.hidden_size, c.num_attention_heads, None, c.kv_lora_rank, c.qk_nope_head_dim,
                 c.qk_rope_head_dim, c.v_head_dim, None, None, c.rms_norm_eps, dtype,
                 NORM_INIT_STD)
         else:
-            self.attn = KimiDeltaAttention(
+            attn = KimiDeltaAttention(
                 c.hidden_size, c.kda_heads, c.kda_head_dim, c.kda_conv, None, c.rms_norm_eps,
                 dtype, NORM_INIT_STD, decay_rank=c.kda_head_dim, gate_rank=c.kda_head_dim)
-        self.ffn_norm = RMSNorm(c.hidden_size, c.rms_norm_eps, NORM_INIT_STD)
         if dense:
-            self.ffn = GatedMLP(c.hidden_size, c.intermediate_size, dtype)
+            ffn = GatedMLP(c.hidden_size, c.intermediate_size, dtype)
         else:
-            self.ffn = MoE(c.hidden_size, c.moe_intermediate_size, c.num_experts,
-                           c.num_experts_per_token, c.num_shared_experts,
-                           c.routed_scaling_factor, experts_held, block_rows, dtype)
+            ffn = MoE(c.hidden_size, c.moe_intermediate_size, c.num_experts,
+                      c.num_experts_per_token, c.num_shared_experts, c.routed_scaling_factor,
+                      experts_held, block_rows, dtype)
+        super().__init__(c, attn, ffn)
 
 
 class KimiLinear(ScoringForward):
     """``KimiLinear(config)(tokens)``: the scoring forward of one document ``tokens`` (T,)
-    int32, returning :class:`KimiLinearScores`.
+    int32, returning :class:`~.scoring.Scores`; the arguments are
+    :class:`~.scoring.ScoringForward`'s (``config`` a :class:`KimiLinearConfig`,
+    ``block_rows`` :data:`BLOCK_ROWS` by default; ``A_log`` and ``dt_bias`` are float32)."""
 
-    ``config`` is a :class:`KimiLinearConfig` or the ``config.json`` dictionary;
-    ``continuation`` is the number of trailing tokens that are scored; ``experts_held =
-    (first, count)`` is the share of every expert layer that lives here (all by default, see
-    :class:`~.moe.MoE`); ``block_rows`` is the block every held expert's group of rows is
-    padded to; parameters are stored in ``dtype`` (norms, router, ``A_log`` and ``dt_bias``
-    float32) and activations follow it.
-    """
-
+    config_type = KimiLinearConfig
     traces = "nn.kimi_linear.traces"
+    block_rows = BLOCK_ROWS
 
-    def __init__(self, config, continuation: int = 128,
-                 experts_held: Optional[Tuple[int, int]] = None, dtype=jnp.bfloat16,
-                 block_rows: int = BLOCK_ROWS):
-        if not isinstance(config, KimiLinearConfig):
-            config = KimiLinearConfig.from_dict(config)
-        self.config = c = config
-        self.continuation = continuation
-        self.dtype = jnp.dtype(dtype)
-        self.layers = [
-            KimiLinearBlock(c, c.is_latent(i), i < c.first_k_dense_replace, experts_held, dtype,
-                            block_rows)
-            for i in range(c.num_hidden_layers)
-        ]
-        self.norm = RMSNorm(c.hidden_size, c.rms_norm_eps, NORM_INIT_STD)
-
-    def init(self, key):
-        c, dt = self.config, self.dtype
-        d = c.hidden_size
-        k_embed, k_head, k_norm, *k_layers = jax.random.split(key, 3 + len(self.layers))
-        return {
-            "embed": {"weight": normal_weight(k_embed, (c.vocab_size, d), dt, 1.0)},
-            "layers": [layer.init(k) for layer, k in zip(self.layers, k_layers)],
-            "norm": self.norm.init(k_norm),
-            "head": {"weight": normal_weight(k_head, (d, c.vocab_size), dt, d ** -0.5)},
-        }
-
-    def _document(self, params, tokens):
-        targets = tokens[tokens.shape[0] - self.continuation:]
-        x = params["embed"]["weight"][tokens]
-        routed = []
-        for block, p in zip(self.layers, params["layers"]):
-            x, aux = block.apply(p, x)
-            if aux:
-                routed.append(aux)
-        logits, loglik = score(self.norm, params["norm"], params["head"], x, targets)
-        return KimiLinearScores(logits, loglik, jnp.stack([a["chosen"] for a in routed]),
-                                jnp.stack([a["load"] for a in routed]))
+    def _layer(self, config, index, experts_held, dtype, block_rows):
+        return KimiLinearBlock(config, config.is_latent(index),
+                               index < config.first_k_dense_replace, experts_held, dtype,
+                               block_rows)

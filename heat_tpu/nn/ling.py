@@ -12,12 +12,11 @@ token-routed experts after (:class:`~.moe.MoE`, whose router limits a token to
 published configuration leaves a choice open, and what is left out (the vision tower, the
 multi-token-prediction modules, the clamped activation of the deepest layers).
 
-The request is *scoring* (:mod:`.scoring`, shared with :class:`~.xing4.Xing4` and
-:class:`~.trinity.Trinity`): ``model(tokens)`` runs through :meth:`Module.__call__`, the
-whole forward is **one compiled program a call** (``nn.ling.traces`` counts its traces),
-and only the positions that score the continuation go through the head. A sliced
-vocabulary is a smaller vocabulary: ``vocab_size`` is what is held, ids are the slice's
-own and the logits are over the slice.
+The request is *scoring* (:mod:`.scoring`, shared with the other four models):
+``model(tokens)`` runs through :meth:`Module.__call__`, the whole forward is **one compiled
+program a call** (``nn.ling.traces`` counts its traces), and only the positions that score
+the continuation go through the head. A sliced vocabulary is a smaller vocabulary:
+``vocab_size`` is what is held, ids are the slice's own and the logits are over the slice.
 
 No reference counterpart.
 """
@@ -25,22 +24,18 @@ No reference counterpart.
 from __future__ import annotations
 
 import dataclasses
-from typing import NamedTuple, Optional, Tuple
+from typing import Optional, Tuple
 
-import jax
 import jax.numpy as jnp
 
 from .attention import MultiheadLatentAttention
 from .kda import KimiDeltaAttention
-from .modules import GatedMLP, Module, RMSNorm, normal_weight
+from .modules import GatedMLP
 from .moe import MoE
-from .scoring import ScoringForward, score
+from .scoring import NORM_INIT_STD, PreNormBlock, ScoringForward
 
-__all__ = ["Ling", "LingBlock", "LingConfig", "LingScores"]
+__all__ = ["Ling", "LingBlock", "LingConfig"]
 
-# the model runs on seeded weights here: norm weights are drawn round one, so that a
-# weight in the wrong place of an equation moves the logits
-NORM_INIT_STD = 0.1
 # 512 tokens an expert on average (32,768 x 8 / 512): a group is padded by half a block on
 # average, so the block is a quarter of the mean group and not the whole of it
 BLOCK_ROWS = 128
@@ -110,109 +105,44 @@ class LingConfig:
         return (index + 1) % self.layer_group_size == 0
 
 
-class LingScores(NamedTuple):
-    """What one scoring forward returns, all on the device. ``logits`` (c, vocab): the
-    head at positions ``T-1-c .. T-2``, which score the last ``c`` tokens; ``loglik``:
-    their log-likelihood (a float32 scalar); ``chosen`` (expert layers, T, k): every
-    expert layer's routing over all experts; ``load`` (expert layers, experts held): rows
-    each held expert multiplied."""
-
-    logits: jax.Array
-    loglik: jax.Array
-    chosen: jax.Array
-    load: jax.Array
-
-
-class LingBlock(Module):
-    """One layer on tokens ``(T, d)``: ``x <- x + mix(norm(x))``, then ``x <- x +
-    feed-forward(norm(x))``; ``latent`` chooses the mixing. ``apply`` returns ``(x, aux)``,
-    ``aux`` the expert layer's ``{"chosen", "load"}`` (and what a mixing that returns one adds
-    to it) or None for a dense layer that mixes without one."""
+class LingBlock(PreNormBlock):
+    """One layer on tokens ``(T, d)``, :class:`~.scoring.PreNormBlock`'s two steps; ``latent``
+    chooses the mixing, ``dense`` the feed-forward."""
 
     def __init__(self, config: LingConfig, latent: bool, dense: bool,
                  experts_held: Optional[Tuple[int, int]] = None, dtype=jnp.bfloat16,
                  block_rows: int = BLOCK_ROWS):
         c = config
-        self.attn_norm = RMSNorm(c.hidden_size, c.rms_norm_eps, NORM_INIT_STD)
         if latent:
-            self.attn = MultiheadLatentAttention(
+            attn = MultiheadLatentAttention(
                 c.hidden_size, c.num_attention_heads, None, c.kv_lora_rank, c.qk_nope_head_dim,
                 c.qk_rope_head_dim, c.v_head_dim, c.rope_theta, None, c.rms_norm_eps, dtype,
                 NORM_INIT_STD, head_gate=True)
         else:
-            self.attn = KimiDeltaAttention(
+            attn = KimiDeltaAttention(
                 c.hidden_size, c.num_attention_heads, c.head_dim, c.short_conv_kernel_size,
                 c.kda_lower_bound, c.rms_norm_eps, dtype, NORM_INIT_STD)
-        self.ffn_norm = RMSNorm(c.hidden_size, c.rms_norm_eps, NORM_INIT_STD)
         if dense:
-            self.ffn = GatedMLP(c.hidden_size, c.intermediate_size, dtype)
+            ffn = GatedMLP(c.hidden_size, c.intermediate_size, dtype)
         else:
-            self.ffn = MoE(c.hidden_size, c.moe_intermediate_size, c.num_experts,
-                           c.num_experts_per_tok,
-                           c.moe_shared_expert_intermediate_size // c.moe_intermediate_size,
-                           c.routed_scaling_factor, experts_held, block_rows, dtype,
-                           c.n_group, c.topk_group)
-
-    def apply(self, params, x, *, key=None, train=False):
-        def with_aux(out):  # experts, and attention over a selection, give (y, aux)
-            return out if isinstance(out, tuple) else (out, {})
-
-        a, seen = with_aux(self.attn.apply(params["attn"],
-                                           self.attn_norm.apply(params["attn_norm"], x)))
-        x = x + a
-        f, routed = with_aux(self.ffn.apply(params["ffn"],
-                                            self.ffn_norm.apply(params["ffn_norm"], x)))
-        return x + f, ({**seen, **routed} or None)
+            ffn = MoE(c.hidden_size, c.moe_intermediate_size, c.num_experts,
+                      c.num_experts_per_tok,
+                      c.moe_shared_expert_intermediate_size // c.moe_intermediate_size,
+                      c.routed_scaling_factor, experts_held, block_rows, dtype, c.n_group,
+                      c.topk_group)
+        super().__init__(c, attn, ffn)
 
 
 class Ling(ScoringForward):
     """``Ling(config)(tokens)``: the scoring forward of one document ``tokens`` (T,) int32,
-    returning :class:`LingScores`.
+    returning :class:`~.scoring.Scores`; the arguments are :class:`~.scoring.ScoringForward`'s
+    (``config`` a :class:`LingConfig`, ``block_rows`` :data:`BLOCK_ROWS` by default; ``A_log``
+    and ``dt_bias`` are float32)."""
 
-    ``config`` is a :class:`LingConfig` or the ``config.json`` dictionary; ``continuation``
-    is the number of trailing tokens that are scored; ``experts_held = (first, count)`` is
-    the share of every expert layer that lives here (all by default, see
-    :class:`~.moe.MoE`); ``block_rows`` is the block every held expert's group of rows is
-    padded to; parameters are stored in ``dtype`` (norms, router, ``A_log`` and ``dt_bias``
-    float32) and activations follow it.
-    """
-
+    config_type = LingConfig
     traces = "nn.ling.traces"
+    block_rows = BLOCK_ROWS
 
-    def __init__(self, config, continuation: int = 128,
-                 experts_held: Optional[Tuple[int, int]] = None, dtype=jnp.bfloat16,
-                 block_rows: int = BLOCK_ROWS):
-        if not isinstance(config, LingConfig):
-            config = LingConfig.from_dict(config)
-        self.config = c = config
-        self.continuation = continuation
-        self.dtype = jnp.dtype(dtype)
-        self.layers = [
-            LingBlock(c, c.is_latent(i), i < c.first_k_dense_replace, experts_held, dtype,
-                      block_rows)
-            for i in range(c.num_hidden_layers)
-        ]
-        self.norm = RMSNorm(c.hidden_size, c.rms_norm_eps, NORM_INIT_STD)
-
-    def init(self, key):
-        c, dt = self.config, self.dtype
-        d = c.hidden_size
-        k_embed, k_head, k_norm, *k_layers = jax.random.split(key, 3 + len(self.layers))
-        return {
-            "embed": {"weight": normal_weight(k_embed, (c.vocab_size, d), dt, 1.0)},
-            "layers": [layer.init(k) for layer, k in zip(self.layers, k_layers)],
-            "norm": self.norm.init(k_norm),
-            "head": {"weight": normal_weight(k_head, (d, c.vocab_size), dt, d ** -0.5)},
-        }
-
-    def _document(self, params, tokens):
-        targets = tokens[tokens.shape[0] - self.continuation:]
-        x = params["embed"]["weight"][tokens]
-        routed = []
-        for block, p in zip(self.layers, params["layers"]):
-            x, aux = block.apply(p, x)
-            if aux is not None:
-                routed.append(aux)
-        logits, loglik = score(self.norm, params["norm"], params["head"], x, targets)
-        return LingScores(logits, loglik, jnp.stack([a["chosen"] for a in routed]),
-                          jnp.stack([a["load"] for a in routed]))
+    def _layer(self, config, index, experts_held, dtype, block_rows):
+        return LingBlock(config, config.is_latent(index), index < config.first_k_dense_replace,
+                         experts_held, dtype, block_rows)

@@ -10,7 +10,7 @@ each); an embedding scaled by ``sqrt(hidden_size)`` (muP) and an untied head.
 ``doc/source/trinity.rst`` writes the equations out and lists what is ``assumed`` where
 the published configuration leaves a choice open.
 
-The request is *scoring* (:mod:`.scoring`, shared with :class:`~.xing4.Xing4`):
+The request is *scoring* (:mod:`.scoring`, shared with the other four models):
 ``model(tokens)`` runs through :meth:`Module.__call__`, the whole forward is **one
 compiled program a call** (``nn.trinity.traces`` counts its traces), and only the
 positions that score the continuation go through the head.
@@ -21,22 +21,20 @@ No reference counterpart.
 from __future__ import annotations
 
 import dataclasses
-from typing import NamedTuple, Optional, Tuple
+from typing import Optional, Tuple
 
-import jax
 import jax.numpy as jnp
 
 from .attention import GroupedQueryAttention
-from .modules import GatedMLP, Module, RMSNorm, normal_weight
+from .modules import GatedMLP, Module, RMSNorm
 from .moe import MoE
-from .scoring import ScoringForward, score
+from .scoring import NORM_INIT_STD, ScoringForward
 
-__all__ = ["Trinity", "TrinityBlock", "TrinityConfig", "TrinityScores"]
+__all__ = ["Trinity", "TrinityBlock", "TrinityConfig"]
 
-# the model runs on seeded weights here: norm weights are drawn round one, so that a
-# weight in the wrong place of an equation moves the logits
-NORM_INIT_STD = 0.1
 LAYER_KINDS = ("sliding_attention", "full_attention")
+# rows a block of an expert layer's sorted buffer holds
+BLOCK_ROWS = 512
 
 
 @dataclasses.dataclass(frozen=True)
@@ -83,19 +81,6 @@ class TrinityConfig:
         return cls(**dict({k: v for k, v in config.items() if k in names}, layer_types=kinds))
 
 
-class TrinityScores(NamedTuple):
-    """What one scoring forward returns, all on the device. ``logits`` (c, vocab): the
-    head at positions ``T-1-c .. T-2``, which score the last ``c`` tokens; ``loglik``:
-    their log-likelihood (a float32 scalar); ``chosen`` (expert layers, T, k): every
-    expert layer's routing; ``load`` (expert layers, experts held): rows each held
-    expert multiplied."""
-
-    logits: jax.Array
-    loglik: jax.Array
-    chosen: jax.Array
-    load: jax.Array
-
-
 class TrinityBlock(Module):
     """One layer on tokens ``(T, d)``: ``x <- x + norm(attention(norm(x)))``, then ``x <-
     x + norm(feed-forward(norm(x)))``. ``kind`` is the layer's entry of ``layer_types``.
@@ -104,7 +89,7 @@ class TrinityBlock(Module):
 
     def __init__(self, config: TrinityConfig, kind: str, dense: bool,
                  experts_held: Optional[Tuple[int, int]] = None, dtype=jnp.bfloat16,
-                 block_rows: int = 512):
+                 block_rows: int = BLOCK_ROWS):
         c = config
         windowed = kind == "sliding_attention"
 
@@ -137,55 +122,23 @@ class TrinityBlock(Module):
 
 class Trinity(ScoringForward):
     """``Trinity(config)(tokens)``: the scoring forward of one document ``tokens`` (T,)
-    int32, returning :class:`TrinityScores`.
+    int32, returning :class:`~.scoring.Scores`; the arguments are
+    :class:`~.scoring.ScoringForward`'s (``config`` a :class:`TrinityConfig`, ``block_rows``
+    :data:`BLOCK_ROWS` by default)."""
 
-    ``config`` is a :class:`TrinityConfig` or the ``config.json`` dictionary;
-    ``continuation`` is the number of trailing tokens that are scored; ``experts_held =
-    (first, count)`` is the share of every expert layer that lives here (all by default,
-    see :class:`~.moe.MoE`); parameters are stored in ``dtype`` (norms and router
-    float32) and activations follow it.
-    """
-
+    config_type = TrinityConfig
     traces = "nn.trinity.traces"
+    block_rows = BLOCK_ROWS
+    # muP scales the embedding by sqrt(d) in the forward: drawn at 1 / sqrt(d), the
+    # residual stream starts at unit size, as a trained model's does
+    embed_exponent = -0.5
 
-    def __init__(self, config, continuation: int = 128,
-                 experts_held: Optional[Tuple[int, int]] = None, dtype=jnp.bfloat16,
-                 block_rows: int = 512):
-        if not isinstance(config, TrinityConfig):
-            config = TrinityConfig.from_dict(config)
-        self.config = c = config
-        self.continuation = continuation
-        self.dtype = jnp.dtype(dtype)
-        self.layers = [
-            TrinityBlock(c, kind, i < c.num_dense_layers, experts_held, dtype, block_rows)
-            for i, kind in enumerate(c.layer_types)
-        ]
-        self.norm = RMSNorm(c.hidden_size, c.rms_norm_eps, NORM_INIT_STD)
+    def _layer(self, config, index, experts_held, dtype, block_rows):
+        return TrinityBlock(config, config.layer_types[index], index < config.num_dense_layers,
+                            experts_held, dtype, block_rows)
 
-    def init(self, key):
-        c, dt = self.config, self.dtype
-        d = c.hidden_size
-        k_embed, k_head, k_norm, *k_layers = jax.random.split(key, 3 + len(self.layers))
-        # muP scales the embedding by sqrt(d) in the forward: drawn at 1 / sqrt(d), the
-        # residual stream starts at unit size, as a trained model's does
-        return {
-            "embed": {"weight": normal_weight(k_embed, (c.vocab_size, d), dt, d ** -0.5)},
-            "layers": [layer.init(k) for layer, k in zip(self.layers, k_layers)],
-            "norm": self.norm.init(k_norm),
-            "head": {"weight": normal_weight(k_head, (d, c.vocab_size), dt, d ** -0.5)},
-        }
-
-    def _document(self, params, tokens):
-        c = self.config
-        targets = tokens[tokens.shape[0] - self.continuation:]
-        x = params["embed"]["weight"][tokens]
+    def _embed(self, params, tokens):
+        x, c = super()._embed(params, tokens), self.config
         if c.mup_enabled:
             x = (x.astype(jnp.float32) * jnp.float32(c.hidden_size ** 0.5)).astype(x.dtype)
-        routed = []
-        for block, p in zip(self.layers, params["layers"]):
-            x, aux = block.apply(p, x)
-            if aux is not None:
-                routed.append(aux)
-        logits, loglik = score(self.norm, params["norm"], params["head"], x, targets)
-        return TrinityScores(logits, loglik, jnp.stack([a["chosen"] for a in routed]),
-                             jnp.stack([a["load"] for a in routed]))
+        return x

@@ -8,11 +8,10 @@ multi-token-prediction module that predicts two tokens ahead from the main model
 hidden state. ``doc/source/xing4.rst`` writes the equations out and lists what is
 ``assumed`` where the published configuration leaves a choice open.
 
-The request is *scoring* (:mod:`.scoring`, which this model shares with
-:class:`~.trinity.Trinity`): ``model(tokens)`` runs through :meth:`Module.__call__` like
-every module, the whole forward is **one compiled program a call** (``nn.xing4.traces``
-counts its traces), and only the positions that score the continuation go through the
-head.
+The request is *scoring* (:mod:`.scoring`, which this model shares with the other four):
+``model(tokens)`` runs through :meth:`Module.__call__` like every module, the whole forward
+is **one compiled program a call** (``nn.xing4.traces`` counts its traces), and only the
+positions that score the continuation go through the head.
 
 No reference counterpart.
 """
@@ -29,13 +28,12 @@ from .attention import MultiheadLatentAttention
 from .hyper_connections import HyperConnection
 from .modules import GatedMLP, Module, RMSNorm, contract, normal_weight
 from .moe import MoE
-from .scoring import ScoringForward, score
+from .scoring import NORM_INIT_STD, ScoringForward, _routing, score
 
 __all__ = ["Xing4", "Xing4Block", "Xing4Config", "Xing4Scores"]
 
-# the model runs on seeded weights here: norm weights are drawn round one, so that a
-# weight in the wrong place of an equation moves the logits
-NORM_INIT_STD = 0.1
+# rows a block of an expert layer's sorted buffer holds
+BLOCK_ROWS = 512
 
 
 @dataclasses.dataclass(frozen=True)
@@ -106,7 +104,7 @@ class Xing4Block(Module):
 
     def __init__(self, config: Xing4Config, dense: bool,
                  experts_held: Optional[Tuple[int, int]] = None, dtype=jnp.bfloat16,
-                 block_rows: int = 512):
+                 block_rows: int = BLOCK_ROWS):
         c = config
         hc = dict(dim=c.hidden_size, streams=c.hc_mult, sinkhorn_iters=c.hc_sinkhorn_iters,
                   eps=c.hc_eps, res_clamp=(c.mhc_h_res_clamp_min, c.mhc_h_res_clamp_max),
@@ -141,38 +139,31 @@ class Xing4Block(Module):
 
 class Xing4(ScoringForward):
     """``Xing4(config)(tokens)``: the scoring forward of one document ``tokens`` (T,)
-    int32, returning :class:`Xing4Scores`.
+    int32, returning :class:`Xing4Scores`; the arguments are
+    :class:`~.scoring.ScoringForward`'s (``config`` an :class:`Xing4Config`, ``block_rows``
+    :data:`BLOCK_ROWS` by default; the hyper-connection mappings are float32)."""
 
-    ``config`` is an :class:`Xing4Config` or the ``config.json`` dictionary;
-    ``continuation`` is the number of trailing tokens that are scored; ``experts_held =
-    (first, count)`` is the share of every expert layer that lives here (all by default,
-    see :class:`~.moe.MoE`); parameters are stored in ``dtype`` (norms, router and the
-    hyper-connection mappings float32) and activations follow it.
-    """
-
+    config_type = Xing4Config
     traces = "nn.xing4.traces"
+    block_rows = BLOCK_ROWS
     logliks = ("loglik", "mtp_loglik")
     ahead = 2  # the multi-token-prediction head scores from two back
 
     def __init__(self, config, continuation: int = 128,
                  experts_held: Optional[Tuple[int, int]] = None, dtype=jnp.bfloat16,
-                 block_rows: int = 512):
-        if not isinstance(config, Xing4Config):
-            config = Xing4Config.from_dict(config)
-        self.config = c = config
-        self.continuation = continuation
-        self.dtype = jnp.dtype(dtype)
-        self.layers = [
-            Xing4Block(c, i < c.first_k_dense_replace, experts_held, dtype, block_rows)
-            for i in range(c.num_hidden_layers)
-        ]
-        self.norm = RMSNorm(c.hidden_size, c.rms_norm_eps, NORM_INIT_STD)
+                 block_rows: Optional[int] = None):
+        super().__init__(config, continuation, experts_held, dtype, block_rows)
+        c = self.config
         # the multi-token-prediction module: two norms, a projection of the joined
         # (2d) vector, one expert layer, its own final norm; embedding and head are shared
         self.mtp_enorm = RMSNorm(c.hidden_size, c.rms_norm_eps, NORM_INIT_STD)
         self.mtp_hnorm = RMSNorm(c.hidden_size, c.rms_norm_eps, NORM_INIT_STD)
-        self.mtp_block = Xing4Block(c, False, experts_held, dtype, block_rows)
+        self.mtp_block = Xing4Block(c, False, experts_held, dtype, self.block_rows)
         self.mtp_norm = RMSNorm(c.hidden_size, c.rms_norm_eps, NORM_INIT_STD)
+
+    def _layer(self, config, index, experts_held, dtype, block_rows):
+        return Xing4Block(config, index < config.first_k_dense_replace, experts_held, dtype,
+                          block_rows)
 
     def init(self, key):
         c, dt = self.config, self.dtype
@@ -197,20 +188,17 @@ class Xing4(ScoringForward):
     def _sum_streams(x):
         return sum(x[j].astype(jnp.float32) for j in range(x.shape[0])).astype(x.dtype)
 
+    def _embed(self, params, tokens):
+        x = super()._embed(params, tokens)
+        return jnp.broadcast_to(x[None], (self.config.hc_mult, *x.shape))
+
     def _document(self, params, tokens):
-        t, c = tokens.shape[0], self.continuation
-        embed = params["embed"]["weight"]
-        targets = tokens[t - c:]
-        x = jnp.broadcast_to(embed[tokens][None], (self.config.hc_mult, t, embed.shape[1]))
-        routed = []
-        for block, p in zip(self.layers, params["layers"]):
-            x, aux = block.apply(p, x)
-            if aux is not None:
-                routed.append(aux)
+        targets = tokens[tokens.shape[0] - self.continuation:]
+        x, routed = self._walk(params, self._embed(params, tokens))
         h = self._sum_streams(x)
         logits, loglik = score(self.norm, params["norm"], params["head"], h, targets)
         with jax.named_scope("ht.nn.mtp"):
-            m = params["mtp"]
+            m, embed = params["mtp"], params["embed"]["weight"]
             # position i joins the embedding of token i+1; the last position has none and
             # takes token 0's: it is causal-last, so nothing reads it, and it is dropped
             joined = jnp.concatenate(
@@ -223,6 +211,4 @@ class Xing4(ScoringForward):
             hm = self._sum_streams(xm)
             mtp_logits, mtp_loglik = score(self.mtp_norm, m["norm"], params["head"], hm,
                                            targets, ahead=2)
-        return Xing4Scores(logits, mtp_logits, loglik, mtp_loglik,
-                           jnp.stack([a["chosen"] for a in routed]),
-                           jnp.stack([a["load"] for a in routed]))
+        return Xing4Scores(logits, mtp_logits, loglik, mtp_loglik, *_routing(routed))

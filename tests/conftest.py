@@ -14,7 +14,11 @@ nothing initialises it before this file is imported, so setting them here is eno
 import os
 import sys
 
-sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+_REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+sys.path.insert(0, _REPO)
+# the plain references of the model cells (``reference_<model>.py``) are the benchmark's own;
+# appended, so that nothing in tests/ is shadowed
+sys.path.append(os.path.join(_REPO, "benchmarks", "chip"))
 
 # The suite's call profile is the dispatch executor's worst case: thousands of
 # distinct op signatures, most exercised once or twice, so compile-on-first-miss

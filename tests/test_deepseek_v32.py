@@ -11,7 +11,6 @@ them, that control fails them, and so does every planted fault.
 """
 
 import functools
-import os
 
 import numpy as np
 import pytest
@@ -350,20 +349,3 @@ def test_expert_layers_through_the_interpreted_kernels_walked_in_slabs(monkeypat
     for name in ("routes", "selections"):
         for got, want in zip(kernels[name], loop[name]):
             assert np.array_equal(np.asarray(got), np.asarray(want))
-
-
-def test_dtypes_are_pinned_under_x64():
-    """The framework enables x64 globally; nothing here may widen to float64 / int64."""
-    model = model_of(jnp.bfloat16)
-    params = jax.eval_shape(model.init, jax.random.key(19))
-    assert {str(leaf.dtype) for leaf in jax.tree_util.tree_leaves(params)} == {"bfloat16", "float32"}
-    out = jax.eval_shape(model._forward, params, jax.ShapeDtypeStruct((T,), jnp.int32))
-    assert {str(leaf.dtype) for leaf in out} == {"float32", "int32"}
-
-
-def test_benchmark_copy_of_the_reference_is_byte_equal():
-    here = os.path.dirname(os.path.abspath(__file__))
-    with open(os.path.join(here, "reference_deepseek_v32.py"), "rb") as f:
-        mine = f.read()
-    with open(os.path.join(here, "..", "benchmarks", "chip", "reference_deepseek_v32.py"), "rb") as f:
-        assert f.read() == mine

@@ -15,8 +15,6 @@ of magnitude and more (logits off by 0.11 with every step floored at -5, 0.18 wi
 positions on the latent layer, 0.76 with one gate a head, 1.03 with Ling's sigmoid decay).
 """
 
-import os
-
 import numpy as np
 import pytest
 
@@ -394,21 +392,3 @@ def test_one_trace_for_repeated_calls_and_the_counters():
         diagnostics.reset()
     with pytest.raises(ValueError, match="KimiLinear scores one document"):
         model(a[None])
-
-
-def test_dtypes_are_pinned_under_x64():
-    """The framework enables x64 globally; nothing here may widen to float64 / int64."""
-    model = ht.nn.KimiLinear(CFG, continuation=CONT, dtype=jnp.bfloat16, block_rows=16)
-    params = model.init(jax.random.key(19))
-    kinds = {str(leaf.dtype) for leaf in jax.tree_util.tree_leaves(params)}
-    assert kinds == {"bfloat16", "float32"}
-    out = jax.eval_shape(model._forward, params, jax.ShapeDtypeStruct((T,), jnp.int32))
-    assert {str(leaf.dtype) for leaf in out} == {"float32", "int32"}
-
-
-def test_benchmark_copy_of_the_reference_is_byte_equal():
-    here = os.path.dirname(os.path.abspath(__file__))
-    with open(os.path.join(here, "reference_kimi_linear.py"), "rb") as f:
-        mine = f.read()
-    with open(os.path.join(here, "..", "benchmarks", "chip", "reference_kimi_linear.py"), "rb") as f:
-        assert f.read() == mine

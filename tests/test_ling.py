@@ -12,7 +12,6 @@ precision down: the program passes it, that control fails it.
 """
 
 import hashlib
-import os
 
 import numpy as np
 import pytest
@@ -448,16 +447,6 @@ def test_one_trace_for_repeated_calls():
         model(a[None])
 
 
-def test_dtypes_are_pinned_under_x64():
-    """The framework enables x64 globally; nothing here may widen to float64 / int64."""
-    model = ht.nn.Ling(CFG, continuation=CONT, dtype=jnp.bfloat16, block_rows=16)
-    params = model.init(jax.random.key(19))
-    kinds = {str(leaf.dtype) for leaf in jax.tree_util.tree_leaves(params)}
-    assert kinds == {"bfloat16", "float32"}
-    out = jax.eval_shape(model._forward, params, jax.ShapeDtypeStruct((T,), jnp.int32))
-    assert {str(leaf.dtype) for leaf in out} == {"float32", "int32"}
-
-
 @pytest.mark.parametrize("model", ["xing4", "trinity"])
 def test_the_other_models_are_unchanged_by_what_ling_shares_with_them(model):
     """``MultiheadLatentAttention`` gained the direct query and the head gate, ``MoE.route``
@@ -480,11 +469,3 @@ def test_the_other_models_are_unchanged_by_what_ling_shares_with_them(model):
     params = jax.eval_shape(program.init, jax.random.key(0))
     text = program._program.lower(params, jax.ShapeDtypeStruct((t,), jnp.int32)).as_text()
     assert hashlib.sha256(text.encode()).hexdigest() == want
-
-
-def test_benchmark_copy_of_the_reference_is_byte_equal():
-    here = os.path.dirname(os.path.abspath(__file__))
-    with open(os.path.join(here, "reference_ling.py"), "rb") as f:
-        mine = f.read()
-    with open(os.path.join(here, "..", "benchmarks", "chip", "reference_ling.py"), "rb") as f:
-        assert f.read() == mine

@@ -12,7 +12,6 @@ precision down: the program passes it, that control fails it.
 """
 
 import hashlib
-import os
 
 import numpy as np
 import pytest
@@ -316,16 +315,6 @@ def test_one_trace_for_repeated_calls():
         model(a[None])
 
 
-def test_dtypes_are_pinned_under_x64():
-    """The framework enables x64 globally; nothing here may widen to float64 / int64."""
-    model = ht.nn.Trinity(CFG, continuation=CONT, dtype=jnp.bfloat16, block_rows=16)
-    params = model.init(jax.random.key(19))
-    kinds = {str(leaf.dtype) for leaf in jax.tree_util.tree_leaves(params)}
-    assert kinds == {"bfloat16", "float32"}
-    out = jax.eval_shape(model._forward, params, jax.ShapeDtypeStruct((T,), jnp.int32))
-    assert {str(leaf.dtype) for leaf in out} == {"float32", "int32"}
-
-
 @pytest.mark.parametrize("what", ["program", "kernel"])
 def test_xing4_is_unchanged_by_what_trinity_shares_with_it(what):
     """``nn/scoring.py`` took ``Xing4``'s scoring tail (the head over the continuation's
@@ -356,11 +345,3 @@ def test_xing4_is_unchanged_by_what_trinity_shares_with_it(what):
             q, k, v, True, 0.07, (1024, 1024), name="mla_flash_fwd"))(q, q, v))
         want = "21340080dd9d0121f4545ce9cd14f293881aaffa6fb6f64ba5764322dd5a09d0"
     assert hashlib.sha256(text.encode()).hexdigest() == want
-
-
-def test_benchmark_copy_of_the_reference_is_byte_equal():
-    here = os.path.dirname(os.path.abspath(__file__))
-    with open(os.path.join(here, "reference_trinity.py"), "rb") as f:
-        mine = f.read()
-    with open(os.path.join(here, "..", "benchmarks", "chip", "reference_trinity.py"), "rb") as f:
-        assert f.read() == mine

